@@ -209,12 +209,17 @@ def main() -> None:
                                                str(1 << 16))))
     args = ap.parse_args()
     import jax
+
+    from spark_rapids_tpu.utils.hw import configure_compile_cache
+    configure_compile_cache()
     n = args.devices or len(jax.devices())
     summary = run(n, args.rows)
     records = summary.pop("records", [])
     # full detail first (humans), then the ONE compact machine-read line
     print(json.dumps({"detail": records}, indent=None), flush=True)
     print(json.dumps(summary, separators=(",", ":")), flush=True)
+    if summary.get("errors"):
+        sys.exit(f"multichip: stages failed: {sorted(summary['errors'])}")
 
 
 if __name__ == "__main__":

@@ -2364,9 +2364,13 @@ def q59(s, t):
     ratios = [(F.col(f"{d[:3].lower()}_sales")
                / F.col(f"{d[:3].lower()}_sales2"))
               .alias(f"r_{d[:3].lower()}") for d in days]
+    # (s_store_name, s_id1, wk1) is not unique here (datagen stores share
+    # ids), so the ratios break ties: without a total order LIMIT keeps
+    # whichever tied rows an engine happens to see first
     return (jj.select("s_store_name", F.col("s_id1"), F.col("wk1"),
                       *ratios)
-            .sort("s_store_name", "s_id1", "wk1")
+            .sort("s_store_name", "s_id1", "wk1",
+                  *[f"r_{d[:3].lower()}" for d in days])
             .limit(100))
 
 

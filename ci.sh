@@ -157,7 +157,7 @@ echo "== full suite (+ leak gate) =="
 # (reference: shutdown leak logging treated as a bug, Plugin.scala:581-596).
 # stderr is teed so the ATEXIT shutdown report can be re-checked below: the
 # in-process gate runs at pytest_sessionfinish, before interpreter shutdown,
-# so a leak surfacing only in atexit hooks must also fail CI (VERDICT r4 #4).
+# so a leak surfacing only in atexit hooks must also fail CI.
 STDERR_LOG=$(mktemp)
 trap 'rm -f "$STDERR_LOG"' EXIT
 # plain redirection (NOT a >(tee ...) substitution: bash doesn't wait for

@@ -32,7 +32,7 @@ Public surface for tools/tracelint.py, tools/gen_docs.py and the tests:
 * :func:`execution_modes` — per-expression execution-mode strings for
   docs/supported_ops.md.
 
-See docs/analysis.md for the verdict taxonomy and the baseline workflow.
+See docs/analysis.md for the verdict classes and the baseline workflow.
 """
 
 from .astwalk import (CONDITIONAL_HOST, DEVICE, HOST, UNTRACEABLE, Detection,

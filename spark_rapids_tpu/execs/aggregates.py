@@ -519,18 +519,14 @@ def _sortable_bits(col: TpuColumnVector):
             f"no sortable encoding for {col.dtype.simple_string()} "
             f"(two-limb carrier)")
     if jnp.issubdtype(d.dtype, jnp.floating):
-        from ..utils.hw import sortable_float_dtype
-        d = d.astype(sortable_float_dtype(d.dtype))
         d = jnp.where(d == 0.0, jnp.zeros((), d.dtype), d)
         canon = jnp.asarray(np.array(np.nan, d.dtype))
         d = jnp.where(jnp.isnan(d), canon, d)
-        if d.dtype == jnp.float64:
-            bits = d.view(jnp.int64)
-            flipped = jnp.where(bits < 0, ~bits, bits | jnp.int64(np.int64(-2**63)))
-            return flipped.view(jnp.int64) ^ jnp.int64(np.int64(-2**63))
-        bits = d.view(jnp.int32)
-        flipped = jnp.where(bits < 0, ~bits, bits | jnp.int32(np.int32(-2**31)))
-        return flipped ^ jnp.int32(np.int32(-2**31))
+        # a DOUBLE key stays a DOUBLE on every backend: utils/hw picks the
+        # bit view or, on TPU, the exact f32-pair encoding
+        from ..utils.hw import f32_order_bits, f64_order_bits
+        return f64_order_bits(d) if d.dtype == jnp.float64 \
+            else f32_order_bits(d)
     if d.dtype == jnp.bool_:
         return d.astype(jnp.int32)
     return d
@@ -747,11 +743,12 @@ def _dedup_bits(col_data):
     HashSet merges NaNs) but -0.0 and 0.0 kept distinct (Double.equals)."""
     d = col_data
     if jnp.issubdtype(d.dtype, jnp.floating):
-        from ..utils.hw import sortable_float_dtype
-        d = d.astype(sortable_float_dtype(d.dtype))
         canon = jnp.asarray(np.array(np.nan, d.dtype))
         d = jnp.where(jnp.isnan(d), canon, d)
-        return d.view(jnp.int64 if d.dtype == jnp.float64 else jnp.int32)
+        if d.dtype == jnp.float64:
+            from ..utils.hw import f64_order_bits
+            return f64_order_bits(d)  # injective: -0.0 != 0.0 survives
+        return d.view(jnp.int32)
     if d.dtype == jnp.bool_:
         return d.astype(jnp.int32)
     return d

@@ -6,7 +6,7 @@ produces an iterator of batches per partition; the CPU flavor streams
 pyarrow Tables (standing in for Spark's row/columnar CPU operators and serving as
 the parity oracle), the TPU flavor streams TpuColumnarBatch.
 
-Metrics follow the reference's GpuMetric taxonomy (GpuExec.scala:41-61):
+Metrics follow the reference's GpuMetric scheme (GpuExec.scala:41-61):
 ESSENTIAL/MODERATE/DEBUG levels, standard names (numOutputRows, numOutputBatches,
 opTime, ...).
 """

@@ -228,7 +228,7 @@ def _spark_float_str(v: float, is_float32: bool) -> str:
     round-trip digits; plain decimal form when 1e-3 <= |v| < 1e7, otherwise
     scientific `d.dddEexp` with one digit before the point (reference
     GpuCast castToString float path / castFloatingTypesToString; the 'Ryu
-    quirks' of VERDICT r2 — python repr switches notation at different
+    quirks' — python repr switches notation at different
     thresholds, so the digits are re-laid-out here)."""
     if np.isnan(v):
         return "NaN"

@@ -11,8 +11,11 @@ bandwidth floor for this query.
 Reference analogue: one fused cuDF kernel chain of GpuAggFirstPassIterator;
 here it is literally one kernel.
 
-The caller (`q1_partial_best`) compiles this lazily and falls back to the
-XLA path if the backend rejects it (CPU tests run it under interpret=True).
+An MXU-contraction variant (one [16, E] x [E, 8] matmul per tile) lived here
+until PR 22: Mosaic refuses its [256, 128] -> [32768, 1] reshape for v5e
+("infer-vector-layout: unsupported shape cast"), so it had only ever "run"
+by giving way to this kernel, and it was deleted rather than offered.
+CPU tests run this kernel under interpret=True.
 """
 
 from __future__ import annotations
@@ -115,119 +118,11 @@ def q1_partial_pallas(batch: Q1Inputs, cutoff_days,
     )
 
 
-def _q1_kernel_mxu(cutoff_ref, rf_ref, ls_ref, qty_ref, price_ref, disc_ref,
-                   tax_ref, ship_ref, valid_ref, out_ref):
-    """MXU formulation: the [16, E] one-hot contraction runs as ONE matmul
-    per tile instead of 16×6 masked VPU reductions.
-
-    Roofline: the VPU variant does 16 groups × 6 measures × 2 ops per input
-    element = 192 flops/element; at the measured 9.6 Grows/s that is
-    ~1.8 Tflop/s — the VPU's peak, which is why it plateaus at ~36% of HBM
-    bandwidth (it is COMPUTE-bound, not memory-bound). The same contraction
-    as `onehot[16, E] @ measures[E, 8]` rides the MXU's systolic array,
-    taking the per-element VPU work down to building the one-hot and the
-    measure stack (~20 flops/element) — the kernel becomes memory-bound,
-    which is the roofline cuDF's agg kernels sit on (SURVEY §2.4)."""
-    import jax.experimental.pallas as pl
-
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[:, :] = jnp.zeros_like(out_ref)
-
-    keep = valid_ref[:, :] & (ship_ref[:, :] <= cutoff_ref[0, 0])
-    w = keep.astype(jnp.float32)
-    price_raw = price_ref[:, :]
-    disc_raw = disc_ref[:, :]
-    qty = qty_ref[:, :] * w
-    price = price_raw * w
-    disc_price = price_raw * (1.0 - disc_raw) * w
-    charge = disc_price * (1.0 + tax_ref[:, :])
-    disc = disc_raw * w
-
-    group = rf_ref[:, :] * N_STATUS + ls_ref[:, :]           # [R, 128] int32
-    flat = group.reshape(1, -1)                              # [1, E]
-    gidx = jax.lax.broadcasted_iota(jnp.int32, (N_GROUPS, 1), 0)
-    onehot = (flat == gidx).astype(jnp.float32)              # [16, E]
-    meas = jnp.concatenate(
-        [m.reshape(-1, 1) for m in
-         (qty, price, disc_price, charge, disc, w,
-          w, w)], axis=1)                                    # [E, 8]
-    out_ref[:, :] += jax.lax.dot_general(
-        onehot, meas, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                  # [16, 8]
-
-
-def q1_partial_pallas_mxu(batch: Q1Inputs, cutoff_days,
-                          interpret: bool = False) -> Q1State:
-    """MXU-contraction variant of the single-pass partial aggregation."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n = batch.quantity.shape[0]
-    per_tile = _TILE_ROWS * _LANES
-    padded = -(-n // per_tile) * per_tile
-
-    def shape2d(a, fill):
-        if padded != n:
-            a = jnp.pad(a, (0, padded - n), constant_values=fill)
-        return a.reshape(-1, _LANES)
-
-    rf = shape2d(batch.returnflag, 0)
-    ls = shape2d(batch.linestatus, 0)
-    qty = shape2d(batch.quantity, 0)
-    price = shape2d(batch.extendedprice, 0)
-    disc = shape2d(batch.discount, 0)
-    tax = shape2d(batch.tax, 0)
-    ship = shape2d(batch.shipdate, 0)
-    valid = shape2d(batch.valid, False)
-
-    grid = padded // per_tile
-    col_spec = pl.BlockSpec((_TILE_ROWS, _LANES), lambda i: (i, 0))
-    with jax.enable_x64(False):
-        out = pl.pallas_call(
-            _q1_kernel_mxu,
-            grid=(grid,),
-            in_specs=[
-                pl.BlockSpec((1, 1), lambda i: (0, 0),
-                             memory_space=pltpu.SMEM),
-                col_spec, col_spec, col_spec, col_spec, col_spec, col_spec,
-                col_spec, col_spec,
-            ],
-            out_specs=pl.BlockSpec((N_GROUPS, 8), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((N_GROUPS, 8), jnp.float32),
-            interpret=interpret,
-        )(jnp.asarray([[cutoff_days]], jnp.int32), rf, ls, qty, price, disc,
-          tax, ship, valid)
-
-    return Q1State(
-        sum_qty=out[:, 0], sum_base_price=out[:, 1],
-        sum_disc_price=out[:, 2], sum_charge=out[:, 3],
-        sum_disc=out[:, 4],
-        count=out[:, 5].astype(jnp.int32),
-    )
-
-
-_BEST = {}
-
-
-def q1_step_best(interpret: bool = False):
-    """Jitted full Q1 step using the pallas partial when the backend accepts
-    it, the XLA einsum path otherwise (compile-or-fallback, cached)."""
-    from .q1 import make_example_batch, q1_final, q1_step
-
-    key = (jax.default_backend(), interpret)
-    if key in _BEST:
-        return _BEST[key]
-
-    @jax.jit
-    def pallas_step(batch, cutoff):
-        return q1_final(q1_partial_pallas(batch, cutoff,
-                                          interpret=interpret))
-
-    try:
-        probe, cutoff = make_example_batch(1 << 15)
-        jax.block_until_ready(pallas_step(probe, jnp.int32(cutoff)))
-        _BEST[key] = pallas_step
-    except Exception:  # noqa: BLE001 — backend rejected the kernel
-        _BEST[key] = q1_step
-    return _BEST[key]
+@partial(jax.jit, static_argnames=("interpret",))
+def q1_step_pallas(batch: Q1Inputs, cutoff, interpret: bool = False):
+    """Jitted full Q1 step over the Pallas partial. No fallback: where the
+    backend's compiler refuses the kernel, the caller sees that error
+    (tests/test_tpu_compile.py keeps it compiling for v5e at 2^24 rows;
+    `interpret=True` is for CPU tests only)."""
+    from .q1 import q1_final
+    return q1_final(q1_partial_pallas(batch, cutoff, interpret=interpret))

@@ -16,8 +16,46 @@ from ..config import HBM_ALLOC_FRACTION, RapidsConf, default_conf
 
 log = logging.getLogger("spark_rapids_tpu")
 
-# v5e has 16 GiB HBM per chip; used when the runtime doesn't report memory stats
-_DEFAULT_HBM_BYTES = 16 * 1024 ** 3
+#: Published per-chip peaks, keyed by `device_kind` as JAX reports it. The one
+#: table for the package and the benchmarks; a TPU that is not listed is an
+#: error, not a default. Source: Google Cloud documentation, "TPU v5e"
+#: (197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s
+#: chip-to-chip interconnect).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"hbm_bytes": 16 * 1024 ** 3, "hbm_GBps": 819.0,
+                    "bf16_TFLOPs": 197.0, "int8_TOPs": 393.0,
+                    "ici_Gbps": 1600.0,
+                    "source": "cloud.google.com/tpu/docs/v5e"},
+}
+
+
+def device_peaks(device=None) -> dict:
+    """The published peaks of `device` (default: the first device)."""
+    import jax
+    device = device or jax.devices()[0]
+    try:
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise RuntimeError(
+            f"no published peaks for device_kind {device.device_kind!r}: add "
+            f"it to memory/device.py DEVICE_PEAKS with its source") from None
+
+
+def _hbm_bytes(device) -> int:
+    """Total device memory the budget is a fraction of. A TPU reports it
+    (`memory_stats()["bytes_limit"]`) or the bootstrap fails: guessing the
+    size of a chip that will not say is how a budget overruns HBM. The CPU
+    backend (tests, the oracle) has no device memory to report; its budget
+    is nominal and takes the size of the chip the tests stand in for."""
+    if device.platform != "tpu":
+        return DEVICE_PEAKS["TPU v5 lite"]["hbm_bytes"]
+    device_peaks(device)  # an unknown chip is an error before any sizing
+    stats = device.memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        raise RuntimeError(
+            f"{device} ({device.device_kind}) reports no memory_stats() "
+            f"bytes_limit; cannot size the HBM budget")
+    return int(stats["bytes_limit"])
 
 
 class TpuDeviceManager:
@@ -35,13 +73,7 @@ class TpuDeviceManager:
             import jax
             devices = jax.devices()
             cls._device = devices[0]
-            total = _DEFAULT_HBM_BYTES
-            try:
-                stats = cls._device.memory_stats()
-                if stats and "bytes_limit" in stats:
-                    total = int(stats["bytes_limit"])
-            except Exception:
-                pass
+            total = _hbm_bytes(cls._device)
             frac = conf.get(HBM_ALLOC_FRACTION)
             cls._hbm_budget_bytes = int(total * frac)
             cls._initialized = True

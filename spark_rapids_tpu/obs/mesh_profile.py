@@ -222,7 +222,8 @@ def record_exchange(seq: int, shuffle_id: int, partitioning: str,
                     watchdog_fired: bool = False,
                     compact_fused: bool = False,
                     staging_reuse_hits: int = 0,
-                    overlap_segments: int = 0
+                    overlap_segments: int = 0,
+                    input_devices: int = 0
                     ) -> Optional[Dict[str, Any]]:
     """Record one collective exchange's profile. Every argument is a host
     value the collective already computed (the sizing counters and the
@@ -261,6 +262,9 @@ def record_exchange(seq: int, shuffle_id: int, partitioning: str,
         "compact_fused": bool(compact_fused),
         "staging_reuse_hits": int(staging_reuse_hits),
         "overlap_segments": int(overlap_segments),
+        # distinct devices holding the staged inputs' shards (sharding
+        # metadata, no sync): n_dev when the exchange really spans the mesh
+        "input_devices": int(input_devices),
     }
     # registry histograms (docs/observability.md "Mesh profiling"):
     # imbalance ×100 so the log2 buckets resolve 1.28x from 2.56x from

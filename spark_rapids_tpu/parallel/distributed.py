@@ -31,13 +31,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..columnar.vector import audited_sync
 from ..kernels.q1 import Q1Inputs, Q1State, q1_final, q1_partial
 
-import warnings
-
-with warnings.catch_warnings():
-    # the experimental path keeps the check_rep kwarg this jax version needs
-    warnings.simplefilter("ignore", DeprecationWarning)
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "data") -> Mesh:
     devs = jax.devices()
@@ -67,8 +60,8 @@ def distributed_q1_step(mesh: Mesh, axis: str = "data"):
     spec = P(axis)
     in_specs = (Q1Inputs(*([spec] * 8)), P())
     out_spec = P()  # replicated results
-    sharded = shard_map(step, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_spec, check_rep=False)
+    sharded = jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                        out_specs=out_spec, check_vma=False)
     return jax.jit(sharded)
 
 
@@ -121,9 +114,9 @@ def ici_all_to_all_exchange(mesh: Mesh, axis: str = "data"):
         return a2a(buf_k), a2a(buf_v), a2a(buf_ok)
 
     spec = P(axis)
-    return jax.jit(shard_map(exchange, mesh=mesh,
+    return jax.jit(jax.shard_map(exchange, mesh=mesh,
                              in_specs=(spec, spec, spec),
-                             out_specs=(spec, spec, spec), check_rep=False))
+                             out_specs=(spec, spec, spec), check_vma=False))
 
 
 def dryrun_multichip(n_devices: int) -> None:

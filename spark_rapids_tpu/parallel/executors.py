@@ -179,7 +179,8 @@ def _worker_main(worker_id: int, task_q, result_q) -> None:
     """Worker process entry: heartbeat thread + task loop. Workers run the
     host plan path on CPU — the accelerator belongs to the driver process
     (v1; per-worker device ownership is the multi-host mode's job)."""
-    os.environ["JAX_PLATFORMS"] = "cpu"
+    from ..utils.hw import pin_worker_to_cpu
+    pin_worker_to_cpu()
     stop = threading.Event()
 
     def beat():

@@ -333,14 +333,13 @@ def _build_exchange(mesh: Mesh, n_dev: int, slot_cap: int,
                 outs.append(move(v, False, jnp.bool_))
         return tuple(outs)
 
-    from .distributed import shard_map
     spec = P(_AXIS)
     n_valid = sum(1 for _, has_v in sig if has_v)
     n_lanes = n_cols + n_valid
     n_flat = 2 * n_cols
-    sm = shard_map(exchange, mesh=mesh,
+    sm = jax.shard_map(exchange, mesh=mesh,
                    in_specs=tuple([spec] * (2 + n_flat)),
-                   out_specs=tuple([spec] * n_lanes), check_rep=False)
+                   out_specs=tuple([spec] * n_lanes), check_vma=False)
 
     def whole(dest, counts, *flat):
         outs = sm(dest, counts, *flat)
@@ -449,22 +448,21 @@ def _build_overlap(mesh: Mesh, n_dev: int, slot_cap: int, k_seg: int,
                 blocks.append(acc[r * local:(r + 1) * local])
         return tuple(blocks)
 
-    from .distributed import shard_map
     spec = P(_AXIS)
     rep = NamedSharding(mesh, P())
     prep = jax.jit(
-        shard_map(prepare, mesh=mesh,
+        jax.shard_map(prepare, mesh=mesh,
                   in_specs=tuple([spec] * (1 + n_flat)),
-                  out_specs=tuple([spec] * n_lanes), check_rep=False),
+                  out_specs=tuple([spec] * n_lanes), check_vma=False),
         donate_argnums=_donate(range(1 + n_flat)))
     a2a = jax.jit(
-        shard_map(seg_a2a, mesh=mesh,
+        jax.shard_map(seg_a2a, mesh=mesh,
                   in_specs=(P(),) + tuple([spec] * n_lanes),
-                  out_specs=tuple([spec] * n_lanes), check_rep=False))
+                  out_specs=tuple([spec] * n_lanes), check_vma=False))
     comp = jax.jit(
-        shard_map(seg_compact, mesh=mesh,
+        jax.shard_map(seg_compact, mesh=mesh,
                   in_specs=(P(), spec) + tuple([spec] * (2 * n_lanes)),
-                  out_specs=tuple([spec] * n_lanes), check_rep=False),
+                  out_specs=tuple([spec] * n_lanes), check_vma=False),
         donate_argnums=_donate(range(2, 2 + 2 * n_lanes)))
     fin = jax.jit(finalize, out_shardings=rep,
                   donate_argnums=_donate(range(n_lanes)))
@@ -606,6 +604,9 @@ def mesh_hash_exchange(mesh: Mesh,
                       for r in range(n_dev)])
     flat = [shard(col_data[i]) for i in range(len(dtypes))] + \
            [shard(col_valid[i]) for i in range(len(dtypes))]
+    # read before the launch donates the staged buffers
+    input_devices = len({sh.device for a in (dest_g, *flat)
+                         for sh in a.addressable_shards})
     if overlap_k:
         ovl = _build_overlap(mesh, n_dev, slot_cap, overlap_k, tuple(sig))
     else:
@@ -684,7 +685,8 @@ def mesh_hash_exchange(mesh: Mesh,
             stage_ns=t_launch0 - t_stage0, launch_ns=t_wait0 - t_launch0,
             wait_ns=t_end - t_wait0, compact_ns=t_compact_end - t_end,
             watchdog_fired=wd.fired, compact_fused=True,
-            staging_reuse_hits=reuse_hits, overlap_segments=overlap_k)
+            staging_reuse_hits=reuse_hits, overlap_segments=overlap_k,
+            input_devices=input_devices)
         if profile is not None:
             # the full attribution record as an instant event: the Chrome
             # export derives the per-device tracks + producer→consumer

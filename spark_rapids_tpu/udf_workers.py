@@ -63,7 +63,8 @@ def _ipc_read(blob: bytes):
 def _udf_worker_main(conn) -> None:
     """Worker loop over a dedicated pipe: (fn_blob, args_ipc) ->
     (status, payload). One request in flight at a time, by construction."""
-    os.environ["JAX_PLATFORMS"] = "cpu"
+    from .utils.hw import pin_worker_to_cpu
+    pin_worker_to_cpu()
     while True:
         try:
             item = conn.recv()

@@ -1,5 +1,5 @@
-"""AQE join-input readers: coordinated coalescing + skew splitting
-(VERDICT r1 item 8). Reference: GpuCustomShuffleReaderExec with
+"""AQE join-input readers: coordinated coalescing + skew splitting.
+Reference: GpuCustomShuffleReaderExec with
 CoalescedPartitionSpec AND PartialReducerPartitionSpec, planned by
 CoalesceShufflePartitions / OptimizeSkewedJoin."""
 

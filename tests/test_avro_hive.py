@@ -4,7 +4,6 @@ fastavro is not in the image)."""
 
 import datetime
 import decimal
-import importlib.util
 
 import pyarrow as pa
 import pytest
@@ -47,14 +46,7 @@ def _rows_table():
 
 
 @pytest.mark.parametrize("codec", [
-    "null", "deflate", "snappy", "bzip2", "xz",
-    # environmental: io/avro.py shells out to the python zstandard module
-    # for this codec; installing it un-skips the param
-    pytest.param("zstandard", marks=pytest.mark.skipif(
-        importlib.util.find_spec("zstandard") is None,
-        reason="python zstandard module not installed "
-               "(needed by io/avro.py for the zstandard codec)")),
-])
+    "null", "deflate", "snappy", "bzip2", "xz", "zstandard"])
 def test_avro_roundtrip_codecs(tmp_path, codec):
     t = _rows_table()
     p = str(tmp_path / "t.avro")

@@ -1,4 +1,4 @@
-"""Bucketed writes/reads (VERDICT r3 missing #8; reference
+"""Bucketed writes/reads (reference
 GpuFileFormatWriter bucketing + GpuFileSourceScanExec bucket pruning)."""
 
 import os

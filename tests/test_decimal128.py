@@ -1,5 +1,5 @@
-"""Decimal beyond precision 18: two-int64-limb device arithmetic
-(VERDICT r1 item 6, second half). Reference: spark-rapids-jni DecimalUtils
+"""Decimal beyond precision 18: two-int64-limb device arithmetic.
+Reference: spark-rapids-jni DecimalUtils
 (__int128 CUDA kernels); here the 128-bit value is (hi, lo) int64 limbs and
 every op is explicit-carry int64 math — kernels/decimal128.py.
 """

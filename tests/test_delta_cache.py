@@ -77,7 +77,7 @@ def test_cache_roundtrip():
 
 
 def test_cache_per_batch_serializer(tmp_path):
-    """VERDICT r1 item 10: df.cache() stores per-batch parquet-compressed
+    """df.cache() stores per-batch parquet-compressed
     entries that decode independently and spill whole batches to disk under
     a host budget (reference ParquetCachedBatchSerializer)."""
     import pyarrow as pa

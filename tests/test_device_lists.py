@@ -1,6 +1,6 @@
 """Device list ops: ragged gather / sort / set operations vs the CPU oracle.
 
-Continues VERDICT r1 item 4 (device-resident collections): slice, reverse,
+Device-resident collections, continued: slice, reverse,
 concat, flatten, sequence, repeat run as ragged gathers sharing
 kernels/strings.gather_plan; sort_array/array_distinct/union/intersect/
 except/overlap run as segment sorts + per-row binary search over total-order
@@ -161,7 +161,7 @@ def test_host_assisted_collections_shrunk():
     import spark_rapids_tpu.plan.overrides  # noqa: F401 — trigger registration
     from spark_rapids_tpu.plan.typechecks import all_expr_rules
     ha = [c.__name__ for c, r in all_expr_rules().items() if r.host_assisted]
-    # VERDICT r1 target: <= 40 (was 62). Breadth additions (maps/structs/
+    # target: <= 40 (was 62). Breadth additions (maps/structs/
     # datetime formatting) add NEW host-assisted surface on top of the sweep.
     assert len(ha) <= 40, ha
     for name in ("SortArray", "ArrayDistinct", "ArrayUnion", "ArrayIntersect",

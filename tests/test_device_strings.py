@@ -1,6 +1,6 @@
 """Device-resident string kernels vs the CPU oracle.
 
-Covers VERDICT r1 item 4: the hot string ops must run on device (no
+The hot string ops must run on device (no
 device→arrow→device hop) for ASCII columns, and byte-safe ops for any UTF-8.
 The `_poison_host_hop` fixture makes any host materialization of the input
 column raise, proving the op never left HBM.
@@ -178,7 +178,7 @@ def test_concat_ws_fallback_single_eval(monkeypatch):
 
 
 def test_host_assisted_string_count_shrunk():
-    """VERDICT r1 item 4 exit criterion: host-assisted registry entries ≤ 45
+    """Exit criterion: host-assisted registry entries ≤ 45
     after the device string sweep (was 62)."""
     import spark_rapids_tpu.plan.overrides  # trigger registration
     from spark_rapids_tpu.plan.typechecks import all_expr_rules

@@ -1,4 +1,4 @@
-"""Exec registry completion (VERDICT r1 item 5): cartesian product,
+"""Exec registry completion: cartesian product,
 symmetric shuffled hash join, and the data-writing command exec.
 Reference: GpuCartesianProductExec.scala, GpuShuffledSymmetricHashJoinExec,
 GpuDataWritingCommandExec / GpuFileFormatDataWriter."""
@@ -207,7 +207,7 @@ def test_input_file_name_from_scan(tmp_path):
 
 
 def test_exec_registry_count():
-    """VERDICT r1 item 5 exit criterion: >= 22 real exec rules."""
+    """Exit criterion: >= 22 real exec rules."""
     import os
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     from spark_rapids_tpu.plan.overrides import exec_rules

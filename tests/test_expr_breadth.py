@@ -1,5 +1,5 @@
-"""Expression breadth 2: registry completion toward the reference's 219 rules
-(VERDICT r1 item 5). Parity: eval_tpu vs eval_cpu on mixed corpora.
+"""Expression breadth 2: registry completion toward the reference's 219
+rules. Parity: eval_tpu vs eval_cpu on mixed corpora.
 Reference: mathExpressions.scala, nullExpressions.scala, GpuInSet,
 GpuRandomExpressions, datetimeExpressions.scala, complexTypeExtractors.scala,
 higherOrderFunctions.scala."""
@@ -258,7 +258,7 @@ def test_partition_context_exprs():
 
 
 def test_registry_reaches_reference_scale():
-    """VERDICT r1 item 5 exit criterion: >= 196 expression rules."""
+    """Exit criterion: >= 196 expression rules."""
     import spark_rapids_tpu.plan.overrides  # noqa: F401
     from spark_rapids_tpu.plan.typechecks import all_expr_rules
     rules = all_expr_rules()

@@ -1,4 +1,4 @@
-"""Fuzz tier (VERDICT r1 item 9).
+"""Fuzz tier.
 
 Reference: integration_tests regexp fuzzers (regexp_test.py,
 RegularExpressionFuzzSuite) and json_fuzz_test.py. All generators are

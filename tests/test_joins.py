@@ -234,13 +234,12 @@ def test_outer_bnlj_duplicate_output_names():
     assert_tpu_and_cpu_are_equal_collect(fn, ignore_order=True)
 
 
-def test_int64_keys_distinct_above_32_bits_demoted_backend(monkeypatch):
-    """On a demoting (non-x64-native) backend, 64-bit keys are encoded as two
-    i32 limbs so keys equal mod 2^32 must NOT spuriously join (r3 review
-    finding: a single truncated i32 encoding verified 1 == 2^32+1)."""
+def test_int64_keys_distinct_above_32_bits():
+    """BIGINT join keys are compared at their full width on every backend
+    (the TPU carries s64 exactly as u32 pairs): keys equal mod 2^32 must NOT
+    spuriously join (r3 review finding: a truncated i32 encoding verified
+    1 == 2^32+1)."""
     import pyarrow as pa
-    from spark_rapids_tpu.utils import hw
-    monkeypatch.setattr(hw, "x64_native", lambda: False)
 
     def fn(s):
         l = s.createDataFrame(pa.table(
@@ -248,6 +247,70 @@ def test_int64_keys_distinct_above_32_bits_demoted_backend(monkeypatch):
              "lv": [1, 2, 3]}))
         r = s.createDataFrame(pa.table(
             {"k": pa.array([1, 7, 2**32 + 7], pa.int64()),
+             "rv": [10, 20, 30]}))
+        return l.join(r, on="k")
+    assert_tpu_and_cpu_are_equal_collect(fn, ignore_order=True)
+
+
+# DOUBLE keys on a backend without f64 bit views (the TPU): four doubles that
+# are ONE value in f32 (100000.01f == 100000.0078125) and three values in f64
+_F32_EQUAL_DOUBLES = [100000.011, 100000.009, 100000.01, 100000.011]
+
+
+@pytest.fixture
+def no_f64_bit_views(monkeypatch):
+    """Steer utils/hw the way the v5e compiler answers it: f64 cannot be
+    reinterpreted as integer bits, so DOUBLE keys take the f32-pair
+    encoding. The program caches are keyed without the probe (it is a
+    process constant in production), so they are cleared around the test."""
+    from spark_rapids_tpu.execs import opjit
+    from spark_rapids_tpu.utils import hw
+    opjit.clear_cache()
+    monkeypatch.setattr(hw, "f64_bit_views", lambda: False)
+    yield
+    opjit.clear_cache()
+
+
+def test_double_keys_are_f32_equal_premise():
+    import numpy as np
+    assert len(set(np.float32(_F32_EQUAL_DOUBLES))) == 1
+    assert len(set(_F32_EQUAL_DOUBLES)) == 3
+
+
+def test_double_sort_key_not_narrowed_without_bit_views(no_f64_bit_views):
+    import pyarrow as pa
+    from spark_rapids_tpu.session import TpuSession
+    import spark_rapids_tpu.functions as F
+    s = TpuSession({"spark.rapids.sql.enabled": "true"})
+    df = s.createDataFrame(pa.table(
+        {"k": pa.array(_F32_EQUAL_DOUBLES, pa.float64()),
+         "i": [0, 1, 2, 3]}))
+    q = df.orderBy(F.col("k").desc())
+    assert "TpuSort" in q.explain()
+    assert [r["k"] for r in q.collect()] == sorted(_F32_EQUAL_DOUBLES,
+                                                   reverse=True)
+
+
+def test_double_group_key_not_narrowed_without_bit_views(no_f64_bit_views):
+    import pyarrow as pa
+    from spark_rapids_tpu.session import TpuSession
+    s = TpuSession({"spark.rapids.sql.enabled": "true"})
+    df = s.createDataFrame(pa.table(
+        {"k": pa.array(_F32_EQUAL_DOUBLES, pa.float64())}))
+    got = {r["k"]: r["count"] for r in df.groupBy("k").count().collect()}
+    assert got == {100000.011: 2, 100000.009: 1, 100000.01: 1}
+
+
+def test_double_join_key_not_narrowed_without_bit_views(no_f64_bit_views):
+    import pyarrow as pa
+
+    def fn(s):
+        l = s.createDataFrame(pa.table(
+            {"k": pa.array(_F32_EQUAL_DOUBLES, pa.float64()),
+             "lv": [1, 2, 3, 4]}))
+        r = s.createDataFrame(pa.table(
+            {"k": pa.array([100000.01, 100000.009, 100000.0078125],
+                           pa.float64()),
              "rv": [10, 20, 30]}))
         return l.join(r, on="k")
     assert_tpu_and_cpu_are_equal_collect(fn, ignore_order=True)

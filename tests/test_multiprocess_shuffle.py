@@ -1,5 +1,5 @@
 """Multi-process executors: real worker processes, file shuffle, heartbeat
-liveness, and kill-recovery (VERDICT r2 missing #1 / directive 3).
+liveness, and kill-recovery.
 
 The kill test SIGKILLs a worker mid-query and the job must still return
 oracle-equal results — no hand-driven registry mutation anywhere; the pool
@@ -205,3 +205,28 @@ def test_dead_worker_detected_by_liveness(pool):
     while victim in pool.live_workers() and time.time() < deadline:
         time.sleep(0.05)
     assert victim not in pool.live_workers()
+
+
+def test_spawned_worker_is_pinned_to_cpu_after_jax_import():
+    """A spawned worker has already imported jax (spawn imports the worker's
+    module, hence the package) when its entry function runs, so setting
+    JAX_PLATFORMS there is too late: utils/hw.pin_worker_to_cpu must also
+    set jax's config, or the worker reaches for the chip its parent holds."""
+    import os
+    import subprocess
+    import sys
+    code = (
+        "import os, jax, spark_rapids_tpu.parallel.executors\n"
+        "assert jax.config.jax_platforms is None, jax.config.jax_platforms\n"
+        "os.environ['JAX_PLATFORMS'] = 'cpu'   # what the workers used to do\n"
+        "assert jax.config.jax_platforms is None\n"
+        "from spark_rapids_tpu.utils.hw import pin_worker_to_cpu\n"
+        "pin_worker_to_cpu()\n"
+        "assert jax.config.jax_platforms == 'cpu'\n"
+        "assert jax.devices()[0].platform == 'cpu'\n")
+    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]

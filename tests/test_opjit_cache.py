@@ -227,7 +227,7 @@ def test_dispatch_accounting_segments_not_operators():
 
 
 def test_metrics_registered_on_tpu_execs():
-    """Every TpuExec carries the opjit metric taxonomy (execs/base.py)."""
+    """Every TpuExec carries the opjit metric set (execs/base.py)."""
     from spark_rapids_tpu.execs.base import TpuExec
     from spark_rapids_tpu.plan.overrides import TpuOverrides
     from spark_rapids_tpu.plan.planner import plan_physical

@@ -1,5 +1,5 @@
 """Parquet parity hardening: legacy-calendar rebase, INT96 timestamps, and
-bounded-memory chunked decode (VERDICT r3 missing #2/#9; reference
+bounded-memory chunked decode (reference
 datetimeRebaseUtils.scala + GpuParquetScan.scala:446 + chunked reader)."""
 
 import datetime as dt

@@ -1,5 +1,5 @@
 """Flagship Q1 kernel tests: XLA path vs numpy oracle vs pallas fused kernel
-(interpret mode on CPU; the real-TPU lowering is exercised by bench.py)."""
+(interpret mode on CPU; tests/test_tpu_compile.py compiles it for v5e)."""
 
 import jax
 import jax.numpy as jnp
@@ -9,18 +9,7 @@ import pytest
 from spark_rapids_tpu.kernels.q1 import (make_example_batch, q1_final,
                                          q1_reference_numpy, q1_step)
 from spark_rapids_tpu.kernels.q1_pallas import (q1_partial_pallas,
-                                                q1_step_best)
-
-# q1_partial_pallas traces inside `with jax.enable_x64(False)` (Mosaic
-# rejects 64-bit index types); jax builds that finished the enable_x64
-# deprecation no longer expose the context manager, so interpret-mode runs
-# are impossible until the kernel gains a replacement scope.  Environmental:
-# a jax with the manager restored (or the kernel ported) un-skips these.
-requires_enable_x64_scope = pytest.mark.skipif(
-    not hasattr(jax, "enable_x64"),
-    reason="jax.enable_x64 context manager missing in this jax build "
-           "(needed by kernels/q1_pallas.py to trace the pallas call)")
-
+                                                q1_step_pallas)
 
 def _assert_close(a, b):
     for k in a:
@@ -38,7 +27,6 @@ def test_xla_matches_numpy_oracle():
                                    ref[k], rtol=1e-4)
 
 
-@requires_enable_x64_scope
 @pytest.mark.parametrize("n", [1 << 15, 12345, 100])
 def test_pallas_matches_xla(n):
     batch, cutoff = make_example_batch(n, seed=7)
@@ -48,7 +36,6 @@ def test_pallas_matches_xla(n):
     _assert_close(ref, got)
 
 
-@requires_enable_x64_scope
 def test_pallas_respects_validity_mask():
     batch, cutoff = make_example_batch(1 << 12, seed=1)
     valid = np.ones(batch.valid.shape[0], bool)
@@ -60,9 +47,12 @@ def test_pallas_respects_validity_mask():
     _assert_close(ref, got)
 
 
-def test_best_step_falls_back_cleanly():
-    """q1_step_best must return a working step on any backend."""
-    step = q1_step_best()
+def test_pallas_step_raises_what_the_compiler_raised():
+    """No compile-or-fallback: offered to a backend that cannot lower the
+    kernel (the CPU backend outside interpret mode), the step raises the
+    lowering's own error instead of quietly running something else."""
     batch, cutoff = make_example_batch(1 << 12)
-    out = step(batch, jnp.int32(cutoff))
+    with pytest.raises(ValueError, match="interpret mode"):
+        q1_step_pallas(batch, jnp.int32(cutoff))
+    out = q1_step_pallas(batch, jnp.int32(cutoff), interpret=True)
     assert int(np.asarray(out["count_order"]).sum()) > 0

@@ -54,6 +54,12 @@ def _compile_snapshot():
             "caches": _program_cache_sizes()}
 
 
+def _cache_hits():
+    from spark_rapids_tpu.serving.scheduler import QueryScheduler
+    return {"plan": QueryScheduler.get().plan_cache.stats()["hits"],
+            "opjit": opjit.cache_stats()["hits"]}
+
+
 def _assert_no_recompiles(before, after, what):
     assert after["misses"] == before["misses"], (
         f"{what} recompiled: opjit misses {before['misses']} -> "
@@ -84,13 +90,19 @@ def warm_session():
 def test_repeat_submission_zero_recompiles(warm_session):
     s, t = warm_session
     before = _compile_snapshot()
-    hits_before = opjit.cache_stats()["hits"]
+    hits_before = _cache_hits()
     for _ in range(2):
         _run(s, t)
     after = _compile_snapshot()
     _assert_no_recompiles(before, after, "repeated q6/q3/q1/q18 submission")
-    # the repeats must actually have exercised the cache, not bypassed it
-    assert opjit.cache_stats()["hits"] > hits_before
+    # the repeats must have been SERVED from a cache, not bypassed it: the
+    # scheduler's plan cache hands back bound plans whose stage programs
+    # are already resolved (so opjit's hit counter may stay flat), a plan
+    # miss re-resolves them through opjit — either counter must rise
+    hits_after = _cache_hits()
+    assert (hits_after["plan"] > hits_before["plan"]
+            or hits_after["opjit"] > hits_before["opjit"]), (
+        f"repeat submissions hit no cache: {hits_before} -> {hits_after}")
 
 
 def test_second_session_shares_process_wide_programs(warm_session):

@@ -1,4 +1,4 @@
-"""Spark-golden parity fixtures (VERDICT r2 directive 4).
+"""Spark-golden parity fixtures.
 
 No Apache Spark exists in this environment, so these expectations are
 VENDORED, hand-derived from the exact JVM semantics Spark's Cast delegates

@@ -1,4 +1,4 @@
-"""Device struct columns (VERDICT r3 missing #7 / next #8): structs are
+"""Device struct columns: structs are
 child-column tuples in HBM (cuDF STRUCT ColumnView analogue), field access
 is zero-copy child selection, and the structural ops (gather/filter/concat)
 recurse through children."""

@@ -1,5 +1,5 @@
-"""Mergeable t-digest approx_percentile (VERDICT r3 missing #5 / next #7;
-reference GpuApproximatePercentile.scala): error bounds vs the exact
+"""Mergeable t-digest approx_percentile (reference
+GpuApproximatePercentile.scala): error bounds vs the exact
 percentile, partial/final merge, and engine parity across partitions."""
 
 import numpy as np

@@ -1,4 +1,4 @@
-"""Non-UTC session timezone support (VERDICT r1 item 6, first half).
+"""Non-UTC session timezone support.
 
 The device path localizes timestamp micros through tzdb.TimeZoneDB (TZif
 transition tables, searchsorted + gather — reference GpuTimeZoneDB); the CPU

@@ -1,5 +1,5 @@
 """Python UDF worker pool: process isolation, Arrow-IPC exchange, and the
-device-admission semaphore bound (VERDICT r2 directive 9; reference
+device-admission semaphore bound (reference
 GpuArrowEvalPythonExec + PythonWorkerSemaphore.scala:98)."""
 
 import threading

@@ -102,7 +102,7 @@ def test_window_string_partition():
 
 
 def test_bounded_minmax_frames():
-    """VERDICT r1 item 7: bounded min/max frames run on device via the
+    """Bounded min/max frames run on device via the
     sparse-table range reduce (reference batched-bounded strategy,
     GpuWindowExecMeta.scala:262-299) — previously tagged unsupported."""
     import random
