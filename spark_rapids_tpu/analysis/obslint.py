@@ -7,8 +7,9 @@ follows two rules, checked statically here over ``execs/``, ``shuffle/``,
 ``memory/`` and ``parallel/`` (the mesh.exchange spans):
 
 1. **Route through the obs API.** Emission sites must use the public
-   helpers (``obs.span`` / ``obs.event`` / ``obs.dispatch_event`` /
-   ``obs.sync_event`` / ``obs.current_span``; ``metrics.counter_inc`` /
+   helpers (``obs.span`` / ``obs.phase`` / ``obs.phase_add`` /
+   ``obs.event`` / ``obs.dispatch_event`` / ``obs.sync_event`` /
+   ``obs.current_span``; ``metrics.counter_inc`` /
    ``gauge_set`` / ``gauge_max`` / ``histogram_observe``;
    ``flight.note``) — not the tracer internals (``QueryTracer``,
    ``_Span``, the ring-buffer ``_append``), not the registry internals
@@ -66,8 +67,8 @@ OBS_MODULES: Tuple[str, ...] = ("obs/mesh_profile.py", "io/device_decode.py")
 #: package (rule 2 scans their call arguments): tracer spans/events,
 #: per-query counter events, metrics-registry increments, flight notes,
 #: mesh-profiler records
-_EMIT_NAMES = ("span", "event", "dispatch_event", "sync_event",
-               "counter_inc", "gauge_set", "gauge_max",
+_EMIT_NAMES = ("span", "phase", "phase_add", "event", "dispatch_event",
+               "sync_event", "counter_inc", "gauge_set", "gauge_max",
                "histogram_observe", "note", "record_exchange",
                "record_fallback")
 

@@ -55,6 +55,7 @@ from ..expressions.aggregates import (AggregateFunction, Average, Count, Max,
                                       Min, Sum)
 from ..expressions.base import (Alias, AttributeReference, Expression,
                                 Literal, to_column)
+from ..obs import tracer as _obs
 from ..types import (BooleanType, DataType, DateType, DecimalType,
                      FloatType, DoubleType, IntegralType, StringType,
                      is_fixed_width)
@@ -432,151 +433,158 @@ def _build_stage_fn(spec: _StageSpec, cap: int,
                     jnp.zeros((cap,), jnp.bool_), cap)
         batch = TpuColumnarBatch(cols, cap)
         mask = rowmask
-        for layer in layers:
-            if layer[0] == "filter":
-                c = to_column(layer[1].eval_tpu(batch, tctx), batch)
-                m = c.data.astype(jnp.bool_)
-                if c.validity is not None:
-                    m = m & c.validity
-                mask = mask & m
-            else:
-                exprs, outs = layer[1], layer[2]
-                new_cols = []
-                for e, a in zip(exprs, outs):
-                    src = e.children[0] if isinstance(e, Alias) else e
-                    if isinstance(src, AttributeReference) \
-                            and src.ordinal is not None:
-                        new_cols.append(batch.columns[src.ordinal])
-                    else:
-                        new_cols.append(to_column(
-                            e.eval_tpu(batch, tctx), batch, a.dtype))
-                batch = TpuColumnarBatch(new_cols, cap)
+        # stable names in the device trace (metadata only)
+        with jax.named_scope("filter"):
+            for layer in layers:
+                if layer[0] == "filter":
+                    c = to_column(layer[1].eval_tpu(batch, tctx), batch)
+                    m = c.data.astype(jnp.bool_)
+                    if c.validity is not None:
+                        m = m & c.validity
+                    mask = mask & m
+                else:
+                    exprs, outs = layer[1], layer[2]
+                    new_cols = []
+                    for e, a in zip(exprs, outs):
+                        src = e.children[0] if isinstance(e, Alias) else e
+                        if isinstance(src, AttributeReference) \
+                                and src.ordinal is not None:
+                            new_cols.append(batch.columns[src.ordinal])
+                        else:
+                            new_cols.append(to_column(
+                                e.eval_tpu(batch, tctx), batch, a.dtype))
+                    batch = TpuColumnarBatch(new_cols, cap)
 
         # combined group code + out-of-domain detection
-        code = jnp.zeros((cap,), jnp.int32)
-        oob = jnp.zeros((), jnp.bool_)
-        for k, (d_size, d_lo, stride) in enumerate(zip(sizes, los, strides)):
-            kc = key_cols[k]
-            kv = kc.validity if kc.validity is not None else rowmask
-            dt = domains[k].dtype
-            if isinstance(dt, StringType):
-                raw = kc.data  # global codes; -1 == null
-                ci = jnp.where(raw >= 0, raw, d_size - 1)
-            elif isinstance(dt, BooleanType):
-                ci = jnp.where(kv, kc.data.astype(jnp.int32), 2)
-            else:
-                lo = d_lo if d_lo is not None else 0
-                raw = (kc.data.astype(jnp.int64) - lo).astype(jnp.int32)
-                oob = oob | jnp.any(mask & kv
-                                    & ((raw < 0) | (raw >= d_size - 1)))
-                ci = jnp.where(kv, jnp.clip(raw, 0, d_size - 2), d_size - 1)
-            code = code + ci * stride
-        code = jnp.clip(code, 0, G - 1)
+        with jax.named_scope("key_index"):
+            code = jnp.zeros((cap,), jnp.int32)
+            oob = jnp.zeros((), jnp.bool_)
+            for k, (d_size, d_lo, stride) in enumerate(
+                    zip(sizes, los, strides)):
+                kc = key_cols[k]
+                kv = kc.validity if kc.validity is not None else rowmask
+                dt = domains[k].dtype
+                if isinstance(dt, StringType):
+                    raw = kc.data  # global codes; -1 == null
+                    ci = jnp.where(raw >= 0, raw, d_size - 1)
+                elif isinstance(dt, BooleanType):
+                    ci = jnp.where(kv, kc.data.astype(jnp.int32), 2)
+                else:
+                    lo = d_lo if d_lo is not None else 0
+                    raw = (kc.data.astype(jnp.int64) - lo).astype(jnp.int32)
+                    oob = oob | jnp.any(mask & kv
+                                        & ((raw < 0) | (raw >= d_size - 1)))
+                    ci = jnp.where(kv, jnp.clip(raw, 0, d_size - 2),
+                                   d_size - 1)
+                code = code + ci * stride
+            code = jnp.clip(code, 0, G - 1)
 
-        # measure inputs (evaluated once over the full batch; the scan below
-        # only re-slices them)
-        meas = []
-        for fn_ in agg_fns:
-            if fn_.children:
-                c = to_column(fn_.children[0].eval_tpu(batch, tctx),
-                              batch, fn_.children[0].dtype)
-                v = c.validity if c.validity is not None else rowmask
-                meas.append((c.data, v & mask))
-            else:
-                meas.append((None, mask))
+        with jax.named_scope("accumulate"):
+            # measure inputs (evaluated once over the full batch; the scan
+            # below only re-slices them)
+            meas = []
+            for fn_ in agg_fns:
+                if fn_.children:
+                    c = to_column(fn_.children[0].eval_tpu(batch, tctx),
+                                  batch, fn_.children[0].dtype)
+                    v = c.validity if c.validity is not None else rowmask
+                    meas.append((c.data, v & mask))
+                else:
+                    meas.append((None, mask))
 
-        gidx = jnp.arange(G, dtype=jnp.int32)
+            gidx = jnp.arange(G, dtype=jnp.int32)
 
-        def scan_body(carry, xs):
-            code_c = xs[0]
-            onehot = code_c[:, None] == gidx[None, :]
-            pos = 2  # xs[0] = codes, xs[1] = row mask
-            out = [carry[0] + jnp.sum(onehot & xs[1][:, None], axis=0,
-                                      dtype=jnp.int64)]
-            ci = 1
-            for fn_, (x0, _v0) in zip(agg_fns, meas):
-                op = fn_.update_op
-                if x0 is None:  # count(*)
-                    v = xs[pos]
-                    pos += 1
-                    out.append(carry[ci] + jnp.sum(
-                        onehot & v[:, None], axis=0, dtype=jnp.int64))
-                    ci += 1
-                    continue
-                x, v = xs[pos], xs[pos + 1]
-                pos += 2
-                ohv = onehot & v[:, None]
-                nn = jnp.sum(ohv, axis=0, dtype=jnp.int64)
-                if op == "count":
-                    out.append(carry[ci] + nn)
-                    ci += 1
-                elif op in ("sum", "avg"):
-                    acc = carry[ci].dtype
-                    contrib = jnp.where(ohv, x[:, None],
-                                        jnp.zeros((), x.dtype)).astype(acc)
-                    out.append(carry[ci] + jnp.sum(contrib, axis=0))
-                    out.append(carry[ci + 1] + nn)
-                    ci += 2
-                elif op in ("min", "max"):
-                    if jnp.issubdtype(x.dtype, jnp.floating):
-                        neutral = jnp.asarray(
-                            np.inf if op == "min" else -np.inf, x.dtype)
-                        nan_x = jnp.isnan(x)
-                        clean = jnp.where(ohv & ~nan_x[:, None],
-                                          x[:, None], neutral)
-                        red = clean.min(0) if op == "min" else clean.max(0)
-                        comb = jnp.minimum if op == "min" else jnp.maximum
-                        out.append(comb(carry[ci], red))
-                        out.append(carry[ci + 1]
-                                   | jnp.any(ohv & nan_x[:, None], axis=0))
-                        out.append(carry[ci + 2] + jnp.sum(
-                            ohv & ~nan_x[:, None], axis=0, dtype=jnp.int64))
-                        out.append(carry[ci + 3] + nn)
-                        ci += 4
-                    else:
-                        info = jnp.iinfo(x.dtype)
-                        neutral = jnp.asarray(
-                            info.max if op == "min" else info.min, x.dtype)
-                        red = jnp.where(ohv, x[:, None], neutral)
-                        red = red.min(0) if op == "min" else red.max(0)
-                        comb = jnp.minimum if op == "min" else jnp.maximum
-                        out.append(comb(carry[ci], red))
+            def scan_body(carry, xs):
+                code_c = xs[0]
+                onehot = code_c[:, None] == gidx[None, :]
+                pos = 2  # xs[0] = codes, xs[1] = row mask
+                out = [carry[0] + jnp.sum(onehot & xs[1][:, None], axis=0,
+                                          dtype=jnp.int64)]
+                ci = 1
+                for fn_, (x0, _v0) in zip(agg_fns, meas):
+                    op = fn_.update_op
+                    if x0 is None:  # count(*)
+                        v = xs[pos]
+                        pos += 1
+                        out.append(carry[ci] + jnp.sum(
+                            onehot & v[:, None], axis=0, dtype=jnp.int64))
+                        ci += 1
+                        continue
+                    x, v = xs[pos], xs[pos + 1]
+                    pos += 2
+                    ohv = onehot & v[:, None]
+                    nn = jnp.sum(ohv, axis=0, dtype=jnp.int64)
+                    if op == "count":
+                        out.append(carry[ci] + nn)
+                        ci += 1
+                    elif op in ("sum", "avg"):
+                        acc = carry[ci].dtype
+                        contrib = jnp.where(ohv, x[:, None],
+                                            jnp.zeros((), x.dtype)).astype(acc)
+                        out.append(carry[ci] + jnp.sum(contrib, axis=0))
                         out.append(carry[ci + 1] + nn)
                         ci += 2
-            return tuple(out), None
+                    elif op in ("min", "max"):
+                        if jnp.issubdtype(x.dtype, jnp.floating):
+                            neutral = jnp.asarray(
+                                np.inf if op == "min" else -np.inf, x.dtype)
+                            nan_x = jnp.isnan(x)
+                            clean = jnp.where(ohv & ~nan_x[:, None],
+                                              x[:, None], neutral)
+                            red = clean.min(0) if op == "min" else clean.max(0)
+                            comb = jnp.minimum if op == "min" else jnp.maximum
+                            out.append(comb(carry[ci], red))
+                            out.append(carry[ci + 1]
+                                       | jnp.any(ohv & nan_x[:, None], axis=0))
+                            out.append(carry[ci + 2] + jnp.sum(
+                                ohv & ~nan_x[:, None], axis=0,
+                                dtype=jnp.int64))
+                            out.append(carry[ci + 3] + nn)
+                            ci += 4
+                        else:
+                            info = jnp.iinfo(x.dtype)
+                            neutral = jnp.asarray(
+                                info.max if op == "min" else info.min, x.dtype)
+                            red = jnp.where(ohv, x[:, None], neutral)
+                            red = red.min(0) if op == "min" else red.max(0)
+                            comb = jnp.minimum if op == "min" else jnp.maximum
+                            out.append(comb(carry[ci], red))
+                            out.append(carry[ci + 1] + nn)
+                            ci += 2
+                return tuple(out), None
 
-        # initial carries
-        init = [jnp.zeros((G,), jnp.int64)]  # rowcount
-        for fn_, (x0, _v0) in zip(agg_fns, meas):
-            op = fn_.update_op
-            if op == "count":
-                init.append(jnp.zeros((G,), jnp.int64))
-            elif op in ("sum", "avg"):
-                acc = jnp.float64 if op == "avg" else \
-                    np.dtype(fn_.dtype.np_dtype)
-                init.append(jnp.zeros((G,), acc))
-                init.append(jnp.zeros((G,), jnp.int64))
-            else:  # min/max
-                if jnp.issubdtype(x0.dtype, jnp.floating):
-                    neutral = jnp.asarray(
-                        np.inf if op == "min" else -np.inf, x0.dtype)
-                    init.extend([jnp.full((G,), neutral, x0.dtype),
-                                 jnp.zeros((G,), jnp.bool_),
-                                 jnp.zeros((G,), jnp.int64),
-                                 jnp.zeros((G,), jnp.int64)])
-                else:
-                    info = jnp.iinfo(x0.dtype)
-                    neutral = jnp.asarray(
-                        info.max if op == "min" else info.min, x0.dtype)
-                    init.extend([jnp.full((G,), neutral, x0.dtype),
-                                 jnp.zeros((G,), jnp.int64)])
+            # initial carries
+            init = [jnp.zeros((G,), jnp.int64)]  # rowcount
+            for fn_, (x0, _v0) in zip(agg_fns, meas):
+                op = fn_.update_op
+                if op == "count":
+                    init.append(jnp.zeros((G,), jnp.int64))
+                elif op in ("sum", "avg"):
+                    acc = jnp.float64 if op == "avg" else \
+                        np.dtype(fn_.dtype.np_dtype)
+                    init.append(jnp.zeros((G,), acc))
+                    init.append(jnp.zeros((G,), jnp.int64))
+                else:  # min/max
+                    if jnp.issubdtype(x0.dtype, jnp.floating):
+                        neutral = jnp.asarray(
+                            np.inf if op == "min" else -np.inf, x0.dtype)
+                        init.extend([jnp.full((G,), neutral, x0.dtype),
+                                     jnp.zeros((G,), jnp.bool_),
+                                     jnp.zeros((G,), jnp.int64),
+                                     jnp.zeros((G,), jnp.int64)])
+                    else:
+                        info = jnp.iinfo(x0.dtype)
+                        neutral = jnp.asarray(
+                            info.max if op == "min" else info.min, x0.dtype)
+                        init.extend([jnp.full((G,), neutral, x0.dtype),
+                                     jnp.zeros((G,), jnp.int64)])
 
-        xs = [code.reshape(n_chunks, -1), mask.reshape(n_chunks, -1)]
-        for x, v in meas:
-            if x is not None:
-                xs.append(x.reshape(n_chunks, -1))
-            xs.append(v.reshape(n_chunks, -1))
-        carry, _ = jax.lax.scan(scan_body, tuple(init), tuple(xs))
+            xs = [code.reshape(n_chunks, -1), mask.reshape(n_chunks, -1)]
+            for x, v in meas:
+                if x is not None:
+                    xs.append(x.reshape(n_chunks, -1))
+                xs.append(v.reshape(n_chunks, -1))
+            carry, _ = jax.lax.scan(scan_body, tuple(init), tuple(xs))
         return (oob,) + carry
 
     fn = jax.jit(stage)
@@ -751,15 +759,16 @@ class TpuCompiledAggStageExec(TpuExec):
         try:
             # pass 1: collect batches (spillable) + key statistics; stats are
             # memoized on the column objects so cached relations pay once
-            for p in range(src.num_partitions()):
-                pctx = TaskContext(p, ctx.conf)
-                try:
-                    for b in src.execute_partition(p, pctx):
-                        if b.num_rows:
-                            self._update_domains(b, domains)
-                            held.append(SpillableColumnarBatch(b))
-                finally:
-                    pctx.complete()
+            with _obs.phase("stage.collect"):
+                for p in range(src.num_partitions()):
+                    pctx = TaskContext(p, ctx.conf)
+                    try:
+                        for b in src.execute_partition(p, pctx):
+                            if b.num_rows:
+                                self._update_domains(b, domains)
+                                held.append(SpillableColumnarBatch(b))
+                    finally:
+                        pctx.complete()
             G = 1
             for d in domains:
                 G *= d.size
@@ -770,20 +779,25 @@ class TpuCompiledAggStageExec(TpuExec):
             # the oob flags at the end (high-latency links pay one round
             # trip per query, like the hand-fused kernel)
             with self.metrics["stageTime"].timed():
+                laps = _obs.PhaseLaps()  # per batch: clock reads only
                 for sb in held:
-                    b = sb.get_batch()
-                    out = self._run_batch(b, domains, ctx)
-                    oob_flags.append(out[0])
-                    carries.append(out[1:])
+                    with laps.lap("stage.launch"):
+                        b = sb.get_batch()
+                        out = self._run_batch(b, domains, ctx)
+                        oob_flags.append(out[0])
+                        carries.append(out[1:])
+                laps.flush()
                 from ..columnar.vector import audited_device_get
-                host = audited_device_get((oob_flags, carries), "stage")
+                with _obs.phase("stage.fetch", cat="wait"):
+                    host = audited_device_get((oob_flags, carries), "stage")
                 oob_np, carries_np = host
                 if oob_np and bool(np.any(np.stack(oob_np))):
                     raise _StageFallback()
         finally:
             for sb in held:
                 sb.close()
-        return self._assemble(domains, carries_np, ctx)
+        with _obs.phase("stage.assemble"):
+            return self._assemble(domains, carries_np, ctx)
 
     def _update_domains(self, b: TpuColumnarBatch,
                         domains: List[_KeyDomain]) -> None:
@@ -830,11 +844,9 @@ class TpuCompiledAggStageExec(TpuExec):
         # transient retry (the stage fn is pure over its device inputs)
         from ..chaos import inject
         from ..failure import with_device_retry
-        from ..obs import tracer as _obs
+        from . import opjit
 
-        if _obs._ACTIVE:
-            _obs.event("dispatch", cat="dispatch", kind="compiledagg",
-                       source="compiled")
+        opjit.record_external_dispatch("compiledagg")
 
         def dispatch():
             inject("device.dispatch", detail="compiled_stage")

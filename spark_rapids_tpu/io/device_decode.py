@@ -1037,7 +1037,18 @@ def _build_program(specs: Tuple[Tuple, ...]):
 
     from ..kernels import parquet_decode as K
 
-    def fn(num_rows, *bufs):
+    def levels(it, nullable, cap, num_rows):
+        with jax.named_scope("levels"):
+            if nullable:
+                lv_runs = next(it)
+                lv_bytes = next(it)
+                defs = K.expand_runs(lv_runs, lv_bytes, cap)
+                return K.validity_from_defs(defs, 1, num_rows)
+            return jnp.arange(cap, dtype=jnp.int64) < num_rows
+
+    # the steps carry stable names into the device trace (jax.named_scope:
+    # metadata only); the program itself reads jit_parquet_decode there
+    def parquet_decode(num_rows, *bufs):
         it = iter(bufs)
         outs = []
         for spec in specs:
@@ -1049,72 +1060,73 @@ def _build_program(specs: Tuple[Tuple, ...]):
                 nullable = spec[1]
                 cap = spec[7] if kind == "str_dict" else spec[6]
                 char_cap = spec[8] if kind == "str_dict" else spec[7]
-                if nullable:
-                    lv_runs = next(it)
-                    lv_bytes = next(it)
-                    defs = K.expand_runs(lv_runs, lv_bytes, cap)
-                    valid = K.validity_from_defs(defs, 1, num_rows)
-                else:
-                    valid = jnp.arange(cap, dtype=jnp.int64) < num_rows
+                valid = levels(it, nullable, cap, num_rows)
                 if kind == "str_dict":
                     vr, vb = next(it), next(it)
                     dsrc, dlen, db = next(it), next(it), next(it)
-                    idx = K.expand_runs(vr, vb, cap)
-                    src_dense = K.dictionary_gather(dsrc, idx)
-                    len_dense = K.dictionary_gather(dlen, idx)
+                    with jax.named_scope("rle_expand"):
+                        idx = K.expand_runs(vr, vb, cap)
+                    with jax.named_scope("dict_gather"):
+                        src_dense = K.dictionary_gather(dsrc, idx)
+                        len_dense = K.dictionary_gather(dlen, idx)
                 else:
                     src_dense, len_dense = next(it), next(it)
                     db = next(it)
-                row_len = K.expand_dense(len_dense, valid)
-                row_src = K.expand_dense(src_dense, valid)
-                offs = K.string_offsets(row_len)
-                chars = K.gather_string_bytes(db, row_src, offs, char_cap)
+                with jax.named_scope("string_gather"):
+                    row_len = K.expand_dense(len_dense, valid)
+                    row_src = K.expand_dense(src_dense, valid)
+                    offs = K.string_offsets(row_len)
+                    chars = K.gather_string_bytes(db, row_src, offs,
+                                                  char_cap)
                 outs.append(offs)
                 outs.append(chars)
                 outs.append(valid if nullable else None)
                 if kind == "str_dict" and spec[9]:
                     # the parquet dictionary codes ride along as the
                     # column's device dict_encoding (null lanes zeroed)
-                    outs.append(K.expand_dense(idx, valid)
-                                .astype(jnp.int32))
+                    with jax.named_scope("levels"):
+                        outs.append(K.expand_dense(idx, valid)
+                                    .astype(jnp.int32))
                 continue
             cap = spec[-1]
             nullable = spec[4] if kind != "bool" else spec[2]
             out_np = spec[3] if kind != "bool" else spec[1]
-            if nullable:
-                lv_runs = next(it)
-                lv_bytes = next(it)
-                defs = K.expand_runs(lv_runs, lv_bytes, cap)
-                valid = K.validity_from_defs(defs, 1, num_rows)
-            else:
-                valid = jnp.arange(cap, dtype=jnp.int64) < num_rows
+            valid = levels(it, nullable, cap, num_rows)
             if kind == "bool":
                 vr, vb = next(it), next(it)
-                dense = K.decode_bool_runs(vr, vb, cap)
+                with jax.named_scope("rle_expand"):
+                    dense = K.decode_bool_runs(vr, vb, cap)
             elif kind == "dict":
                 isz, vkind = spec[1], spec[2]
                 vr, vb, db = next(it), next(it), next(it)
-                idx = K.expand_runs(vr, vb, cap)
-                dvals = K.plain_fixed_width(db, isz, vkind)
-                dense = K.dictionary_gather(dvals, idx)
+                with jax.named_scope("rle_expand"):
+                    idx = K.expand_runs(vr, vb, cap)
+                with jax.named_scope("dict_gather"):
+                    dvals = K.plain_fixed_width(db, isz, vkind)
+                    dense = K.dictionary_gather(dvals, idx)
                 if spec[8] is not None:  # mid-chunk dictionary fallback
                     seg, pb = next(it), next(it)
-                    pvals = K.plain_fixed_width(pb, isz, vkind)
-                    dense = K.merge_plain_segments(seg, pvals, dense, cap)
+                    with jax.named_scope("plain"):
+                        pvals = K.plain_fixed_width(pb, isz, vkind)
+                        dense = K.merge_plain_segments(seg, pvals, dense,
+                                                       cap)
             else:  # plain
                 isz, vkind = spec[1], spec[2]
                 vb = next(it)
-                dense = K.plain_fixed_width(vb, isz, vkind)
-            if nullable:
-                data = K.expand_dense(dense, valid)
-            else:
-                data = jnp.where(valid, dense, jnp.zeros((), dense.dtype))
-            data = data.astype(jnp.dtype(out_np))
+                with jax.named_scope("plain"):
+                    dense = K.plain_fixed_width(vb, isz, vkind)
+            with jax.named_scope("levels"):
+                if nullable:
+                    data = K.expand_dense(dense, valid)
+                else:
+                    data = jnp.where(valid, dense,
+                                     jnp.zeros((), dense.dtype))
+                data = data.astype(jnp.dtype(out_np))
             outs.append(data)
             outs.append(valid if nullable else None)
         return tuple(o for o in outs if o is not None)
 
-    return jax.jit(fn)
+    return jax.jit(parquet_decode)
 
 
 def _program(specs: Tuple[Tuple, ...]):
@@ -1258,6 +1270,9 @@ class DeviceFileDecoder:
             # ONE resolved handle for all chunk-range reads of this file
             # (a wide scan reads columns × row-groups ranges)
             self.reader = FileCache.get(conf).range_reader(path, conf)
+            # the scan.* phases of every row group of this file, summed
+            # and emitted once by close() (per-batch rule)
+            self._laps = _obs.PhaseLaps()
         except BaseException:
             # validation raised after pf opened: the caller gets no
             # decoder object to close, so the footer fd must not ride
@@ -1272,6 +1287,7 @@ class DeviceFileDecoder:
         """Release the byte-range handle (and the footer reader): one open
         fd per scanned file must not ride until GC (TL020 — the scan loop
         closes each decoder in a finally)."""
+        self._laps.flush()
         self.reader.close()
         try:
             self.pf.close()
@@ -1345,35 +1361,39 @@ class DeviceFileDecoder:
             raise DeviceDecodeError(
                 f"{path}: no device-decodable columns in row group {rgi}")
 
+        laps = self._laps
         with _obs.span("scan.decode", cat="io", file=path, row_group=rgi,
                        device=True, rows=num_rows, device_cols=len(plans),
                        host_cols=len(host_names)):
             staged: List[_Staged] = []
             kept: List[_ColPlan] = []
             with timed("decodeTime"):
-                for plan in plans:
-                    cc = rg.column(plan.leaf)
-                    start, length = _chunk_range(cc)
-                    try:
-                        chunk = self.reader.read(start, length)
-                        staged.append(_stage_column(chunk, cc, plan,
-                                                    num_rows, cap))
-                        kept.append(plan)
-                    except (DeviceDecodeError, OSError) as e:
-                        # per-column demotion (bad bytes, failed range
-                        # read): host decodes just this column
-                        demote(plan.name, e)
-                if not kept:
-                    raise DeviceDecodeError(
-                        f"{path}: all columns demoted to host in row "
-                        f"group {rgi}")
+                with laps.lap("scan.page_walk"):
+                    for plan in plans:
+                        cc = rg.column(plan.leaf)
+                        start, length = _chunk_range(cc)
+                        try:
+                            chunk = self.reader.read(start, length)
+                            staged.append(_stage_column(chunk, cc, plan,
+                                                        num_rows, cap))
+                            kept.append(plan)
+                        except (DeviceDecodeError, OSError) as e:
+                            # per-column demotion (bad bytes, failed range
+                            # read): host decodes just this column
+                            demote(plan.name, e)
+                    if not kept:
+                        raise DeviceDecodeError(
+                            f"{path}: all columns demoted to host in row "
+                            f"group {rgi}")
 
                 # admission control only now: host page walking above
                 # overlapped other tasks' device work (reference: stage on
                 # host, THEN semaphore, then device decode)
                 if ctx is not None:
                     from ..memory.semaphore import TpuSemaphore
-                    TpuSemaphore.get(self.conf).acquire_if_necessary(ctx)
+                    with laps.lap("scan.admit", cat="wait"):
+                        TpuSemaphore.get(self.conf).acquire_if_necessary(
+                            ctx)
 
                 # stage → HBM: ONE device_put for every buffer of every
                 # column
@@ -1381,43 +1401,45 @@ class DeviceFileDecoder:
                 for st in staged:
                     leaves.extend(st.arrays)
                 _bump("bytes_staged", sum(a.nbytes for a in leaves))
-                uploaded = jax.device_put(leaves)
+                with timed("uploadTime"), laps.lap("scan.upload"):
+                    uploaded = jax.device_put(leaves)
 
-                specs = tuple(st.spec for st in staged)
-                fn = _program(specs)
-                _bump("dispatches")
-                _bump("row_groups")
-                _bump("rows", num_rows)
-                _bump("device_columns", len(kept))
-                opjit.record_external_dispatch("parquet_decode")
-                outs = fn(np.int64(num_rows), *uploaded)
+                with laps.lap("scan.launch"):
+                    specs = tuple(st.spec for st in staged)
+                    fn = _program(specs)
+                    _bump("dispatches")
+                    _bump("row_groups")
+                    _bump("rows", num_rows)
+                    _bump("device_columns", len(kept))
+                    opjit.record_external_dispatch("parquet_decode")
+                    outs = fn(np.int64(num_rows), *uploaded)
 
-                # assemble columns in attrs order (device + host zipped)
-                out_it = iter(outs)
-                dev_cols: Dict[str, TpuColumnVector] = {}
-                for st, plan in zip(staged, kept):
-                    kind = st.spec[0]
-                    if kind in ("str_plain", "str_dict"):
-                        offs = next(out_it)
-                        chars = next(out_it)
-                        valid = next(out_it) if st.spec[1] else None
-                        col = TpuColumnVector(plan.out_dtype, chars, valid,
-                                              num_rows, offsets=offs)
-                        if kind == "str_dict" and st.spec[9]:
-                            codes = next(out_it)
-                            col.dict_encoding = (
-                                codes,
-                                TpuColumnVector.from_strings(
-                                    plan.out_dtype, st.dict_offsets,
-                                    st.dict_chars))
-                        dev_cols[plan.name] = col
-                        continue
-                    data = next(out_it)
-                    nullable = st.spec[4] if kind != "bool" \
-                        else st.spec[2]
-                    valid = next(out_it) if nullable else None
-                    dev_cols[plan.name] = TpuColumnVector(
-                        plan.out_dtype, data, valid, num_rows)
+                    # assemble columns in attrs order (device + host zipped)
+                    out_it = iter(outs)
+                    dev_cols: Dict[str, TpuColumnVector] = {}
+                    for st, plan in zip(staged, kept):
+                        kind = st.spec[0]
+                        if kind in ("str_plain", "str_dict"):
+                            offs = next(out_it)
+                            chars = next(out_it)
+                            valid = next(out_it) if st.spec[1] else None
+                            col = TpuColumnVector(plan.out_dtype, chars, valid,
+                                                  num_rows, offsets=offs)
+                            if kind == "str_dict" and st.spec[9]:
+                                codes = next(out_it)
+                                col.dict_encoding = (
+                                    codes,
+                                    TpuColumnVector.from_strings(
+                                        plan.out_dtype, st.dict_offsets,
+                                        st.dict_chars))
+                            dev_cols[plan.name] = col
+                            continue
+                        data = next(out_it)
+                        nullable = st.spec[4] if kind != "bool" \
+                            else st.spec[2]
+                        valid = next(out_it) if nullable else None
+                        dev_cols[plan.name] = TpuColumnVector(
+                            plan.out_dtype, data, valid, num_rows)
             if host_names:
                 # per-column fallback decodes are HOST pyarrow work: they
                 # count under hostDecodeTime, not decodeTime, so the bench

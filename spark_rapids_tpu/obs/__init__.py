@@ -1,6 +1,6 @@
 """obs: the production observability plane (docs/observability.md).
 
-Three connected layers over dispatch, sync, memory, shuffle, retry and
+Connected layers over dispatch, sync, memory, shuffle, retry and
 chaos:
 
 * **Concurrent per-query tracing** (:mod:`.tracer`, near-zero-cost when
@@ -13,6 +13,12 @@ chaos:
   and the diagnostics bundle (``session.last_query_profile()``) whose
   per-operator dispatch+sync counts reconcile against its OWN query's
   ``calls_by_kind``/SyncLedger deltas.
+* **Phases** (:func:`phase` / :func:`phase_add`, :mod:`.tracer`): the
+  boundary spans of the served path (session → scheduler → plan → scan →
+  compiled stage → result). One call site feeds the always-on per-query
+  phase table (``session.last_query_phases()``,
+  ``metrics.recent_queries()``), the ring span of a traced query, and a
+  ``TraceAnnotation("srt.<name>")`` on the profiler's own clock.
 * **Always-on metrics registry** (:mod:`.metrics`): process-wide
   counters, gauges and log2-bucket histograms (query latency p50/p95/p99,
   rows/s, HBM high-water, spill bytes, cache hit rates, retry/chaos
@@ -38,14 +44,15 @@ an emission argument.
 
 from .explain import render_explain_metrics
 from .export import build_bundle, chrome_trace, span_tree, write_artifacts
-from .tracer import (QueryTracer, SpanRef, begin_query, current_span,
-                     end_query, event, inherit, is_active, span,
-                     thread_traced)
+from .tracer import (PhaseLaps, QueryTracer, SpanRef, begin_query,
+                     current_span, end_query, event, inherit, is_active,
+                     phase, phase_add, span, thread_traced)
 from . import flight, mesh_profile, metrics
 
 __all__ = [
-    "QueryTracer", "SpanRef", "begin_query", "build_bundle", "chrome_trace",
-    "current_span", "end_query", "event", "flight", "inherit", "is_active",
-    "mesh_profile", "metrics", "render_explain_metrics", "span",
-    "span_tree", "thread_traced", "write_artifacts",
+    "PhaseLaps", "QueryTracer", "SpanRef", "begin_query", "build_bundle",
+    "chrome_trace", "current_span", "end_query", "event", "flight",
+    "inherit", "is_active", "mesh_profile", "metrics", "phase", "phase_add",
+    "render_explain_metrics", "span", "span_tree", "thread_traced",
+    "write_artifacts",
 ]

@@ -199,7 +199,10 @@ def chrome_trace(profile: Dict[str, Any],
     return {"traceEvents": meta + evs + mesh_evs,
             "displayTimeUnit": "ms",
             "otherData": {"query": profile.get("name"),
-                          "dropped_events": profile.get("dropped", 0)}}
+                          "dropped_events": profile.get("dropped", 0),
+                          # time.time_ns() at ts 0: the shift onto the
+                          # realtime clock of a device trace
+                          "t0_unix_ns": profile.get("t0_unix_ns")}}
 
 
 def _counts(profile: Dict[str, Any]):
@@ -290,6 +293,7 @@ def build_bundle(profile: Dict[str, Any],
         "schema": "spark-rapids-tpu/query-profile/1",
         "query": profile.get("name"),
         "duration_ms": round(profile.get("duration_ns", 0) / 1e6, 3),
+        "t0_unix_ns": profile.get("t0_unix_ns"),
         "dropped_events": dropped,
         "event_counts": by_cat,
         "spans": span_tree(profile),

@@ -48,7 +48,10 @@ from __future__ import annotations
 
 import threading
 import time
+from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
+
+from .. import profiling as _profiling
 
 #: global off-switch (spark.rapids.tpu.obs.metrics.enabled; session init
 #: applies it) — read unlocked on every emission
@@ -219,10 +222,15 @@ def histogram_observe(name: str, value, **labels) -> None:
 # tracer's exclusivity check reads all come from this one place.
 
 _QL_LOCK = threading.Lock()
-# token -> (name, t0_ns, priority class or None)
-_ACTIVE_QUERIES: Dict[int, Tuple[str, int, Optional[str]]] = {}
+# token -> (name, t0_ns, priority class or None, t0 unix ns, profiler
+# annotations on at begin)
+_ACTIVE_QUERIES: Dict[int, Tuple] = {}
 _EPOCH = 0
 _NEXT_TOKEN = 1
+#: per-query summaries, newest last (recent_queries): what the phase table
+#: of each finished query folded into
+_RECENT: deque = deque(maxlen=4096)
+_COMPILE_LISTENERS = False
 
 
 def _set_active_gauges_locked() -> None:
@@ -233,7 +241,8 @@ def _set_active_gauges_locked() -> None:
     begin/end pair must not overwrite a gauge with a stale count."""
     gauge_set("queries.active", len(_ACTIVE_QUERIES))
     by_cls: Dict[str, int] = {}
-    for _name, _t0, cls in _ACTIVE_QUERIES.values():
+    for entry in _ACTIVE_QUERIES.values():
+        cls = entry[2]
         if cls is not None:
             by_cls[cls] = by_cls.get(cls, 0) + 1
     from ..serving.query_context import PRIORITIES
@@ -247,11 +256,14 @@ def query_begin(name: str, session: str = "default",
     `cls` is the SLO priority class (None for lifecycle paths that
     predate classes — counted in the total, not any per-class cell)."""
     global _EPOCH, _NEXT_TOKEN
+    _install_compile_listeners()
     with _QL_LOCK:
         _EPOCH += 1
         token = _NEXT_TOKEN
         _NEXT_TOKEN += 1
-        _ACTIVE_QUERIES[token] = (name, time.perf_counter_ns(), cls)
+        _ACTIVE_QUERIES[token] = (
+            name, time.perf_counter_ns(), cls, time.time_ns(),
+            _profiling._PROFILING_ACTIVE)
         _set_active_gauges_locked()
     from . import flight as _flight
     _flight.note("query.begin", query=name, session=session)
@@ -259,16 +271,39 @@ def query_begin(name: str, session: str = "default",
 
 
 def query_end(token: int, rows: Optional[int] = None,
-              failed: bool = False, session: str = "default") -> None:
-    """Close a query: latency/rows-per-s histograms + completion counters.
-    Idempotent on an unknown token."""
+              failed: bool = False, session: str = "default",
+              qctx=None) -> Optional[Dict[str, Any]]:
+    """Close a query: latency/rows-per-s histograms + completion counters,
+    and the per-query summary — the phase table of `qctx` (the query's
+    QueryContext; obs.phase) folded beside the query's own clocks —
+    appended to the recent-queries ring and returned. ``cpu_ns`` is the
+    root phase's (``query``): the query thread's CPU time. Idempotent on an
+    unknown token (returns None)."""
     with _QL_LOCK:
         entry = _ACTIVE_QUERIES.pop(token, None)
         _set_active_gauges_locked()
     if entry is None:
-        return
-    name, t0, _cls = entry
-    latency_ms = (time.perf_counter_ns() - t0) / 1e6
+        return None
+    name, t0, cls, t0_unix, annotated = entry
+    t1 = time.perf_counter_ns()
+    latency_ms = (t1 - t0) / 1e6
+    phases = {} if qctx is None else qctx.phase_table()
+    compiled = phases.get("xla.compile", {})
+    wait_ms = None if qctx is None else qctx.admit_wait_ms
+    summary = {
+        "name": name,
+        "session": session if qctx is None else qctx.session_id,
+        "cls": cls, "failed": bool(failed),
+        "t_begin_ns": t0, "t_end_ns": t1, "t_begin_unix_ns": t0_unix,
+        "admit_wait_ns": int((wait_ms or 0.0) * 1e6),
+        "wall_ns": t1 - t0,
+        "cpu_ns": phases.get("query", {}).get("cpu_ns"),
+        "annotated": bool(annotated or _profiling._PROFILING_ACTIVE),
+        "phases": phases,
+        "compiles": compiled.get("count", 0),
+        "compile_ns": compiled.get("wall_ns", 0)}
+    with _QL_LOCK:
+        _RECENT.append(summary)
     counter_inc("queries.failed" if failed else "queries.completed",
                 session=session)
     histogram_observe("query.latency_ms", latency_ms, session=session)
@@ -278,11 +313,74 @@ def query_end(token: int, rows: Optional[int] = None,
     from . import flight as _flight
     _flight.note("query.end", query=name, session=session,
                  latency_ms=round(latency_ms, 3), rows=rows, failed=failed)
+    return summary
+
+
+def recent_queries(n: Optional[int] = None) -> List[Dict[str, Any]]:
+    """The last `n` (default: all kept, at most 4096) per-query summaries,
+    oldest first: ``{name, session, cls, failed, t_begin_ns, t_end_ns``
+    (``time.perf_counter_ns()``, absolute)``, t_begin_unix_ns``
+    (``time.time_ns()``)``, admit_wait_ns, wall_ns, cpu_ns, annotated,
+    phases: {name: {count, wall_ns, cpu_ns (None: not sampled),
+    child_wall_ns, cat}}, compiles, compile_ns}`` — docs/observability.md
+    "Span model"."""
+    with _QL_LOCK:
+        out = list(_RECENT)
+    return out if n is None else out[-n:] if n > 0 else []
+
+
+def phase_totals() -> Dict[str, Dict[str, Any]]:
+    """Per-phase totals over the kept summaries (the last 4096 queries at
+    most), folded at read time like the other external counters."""
+    totals: Dict[str, Dict[str, Any]] = {}
+    for summary in recent_queries():
+        for n, ph in summary["phases"].items():
+            tot = totals.get(n)
+            if tot is None:
+                tot = totals[n] = {
+                    "queries": 0, "count": 0, "wall_ns": 0, "cpu_ns": 0,
+                    "child_wall_ns": 0, "cat": ph["cat"]}
+            tot["queries"] += 1
+            for k in ("count", "wall_ns", "child_wall_ns"):
+                tot[k] += ph[k]
+            tot["cpu_ns"] = None if None in (tot["cpu_ns"], ph["cpu_ns"]) \
+                else tot["cpu_ns"] + ph["cpu_ns"]
+    return totals
+
+
+def _install_compile_listeners() -> None:
+    """Once per process: attribute every XLA backend compile (a persistent-
+    cache load fires the same event, with its load time) to the query bound
+    to the compiling thread as phase ``xla.compile``, and count compiles,
+    their ms and the persistent cache's hits in the registry."""
+    global _COMPILE_LISTENERS
+    if _COMPILE_LISTENERS:
+        return
+    with _QL_LOCK:
+        if _COMPILE_LISTENERS:
+            return
+        _COMPILE_LISTENERS = True
+    import jax.monitoring
+
+    from .tracer import phase_add
+
+    def on_duration(event: str, secs: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            counter_inc("xla.compiles")
+            counter_inc("xla.compile_ms", secs * 1e3)
+            phase_add("xla.compile", 1, int(secs * 1e9), None)
+
+    def on_event(event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            counter_inc("xla.cache_hits")
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
 
 
 def active_queries() -> List[str]:
     with _QL_LOCK:
-        return [name for name, _t0, _cls in _ACTIVE_QUERIES.values()]
+        return [entry[0] for entry in _ACTIVE_QUERIES.values()]
 
 
 def active_query_count() -> int:
@@ -301,6 +399,7 @@ def reset_query_state_for_tests() -> None:
     global _EPOCH, _NEXT_TOKEN
     with _QL_LOCK:
         _ACTIVE_QUERIES.clear()
+        _RECENT.clear()
         _EPOCH = 0
         _NEXT_TOKEN = 1
 
@@ -331,6 +430,7 @@ def full_snapshot() -> Dict[str, Any]:
     out = MetricsRegistry.get().snapshot()
     out["schema"] = "spark-rapids-tpu/metrics/1"
     out["queries"] = {"active": active_queries(), "epoch": query_epoch()}
+    out["phases"] = phase_totals()
     ext: Dict[str, Any] = {}
 
     def fold(key, fn):

@@ -66,7 +66,9 @@ import time
 from collections import deque
 from typing import Any, Dict, Optional, Tuple
 
+from .. import profiling as _profiling
 from ..profiling import current_sync_scope
+from ..serving.query_context import current as _current_query
 
 #: record layout (tuples, not objects: the tracer may absorb hundreds of
 #: thousands of records per query):
@@ -141,6 +143,11 @@ class QueryTracer:
         self._appended = 0
         self._next_span = 1
         self._t0_ns = time.perf_counter_ns()
+        # the same instant on the realtime clock, which the profiler's host
+        # events are on: the exports carry it so a query's Chrome JSON can
+        # be shifted onto an xprof trace (docs/observability.md "Laying a
+        # query over a device trace")
+        self.t0_unix_ns = time.time_ns()
         self._cats: Optional[frozenset] = frozenset(categories) or None
         self._closed = False
         self.name = name
@@ -192,7 +199,7 @@ class QueryTracer:
             _tls.tracer = None
             _tls.stack = ()
         return {"name": self.name, "root": self.root, "events": events,
-                "dropped": dropped,
+                "dropped": dropped, "t0_unix_ns": self.t0_unix_ns,
                 "duration_ns": events[-1][REC_TS] if events else 0,
                 "dispatch_counts": disp, "sync_counts": syncs,
                 "exclusive": exclusive}
@@ -456,6 +463,187 @@ def current_query_name() -> Optional[str]:
     recorder notes tag themselves with it)."""
     tr = _thread_tracer() if _ACTIVE else None
     return tr.name if tr is not None else None
+
+
+# ---------------------------------------------------------------------------
+# phases: the boundary spans of the served path (docs/observability.md "Span
+# model"). One call site feeds three sinks: the phase table of the
+# QueryContext bound to the thread (always), this thread's ring (when its
+# query is traced) and the profiler's own timeline (when annotations are on).
+
+#: prefix of a phase's TraceAnnotation: what selects the program's spans in
+#: a device trace
+ANNOTATION_PREFIX = "srt."
+
+
+class _PhaseTls(threading.local):
+    """Innermost open phase (or lap) of the thread: the parent whose child
+    wall a closing phase adds to."""
+    open: Optional[Any] = None
+
+
+_ptls = _PhaseTls()
+
+
+class _Phase:
+    """Open phase context manager (only constructed when a QueryContext is
+    bound or annotations are on). ``__enter__`` returns the phase.
+
+    The CPU clock is a system call, and in a serving process on the chip's
+    virtualised host one read costs tens of µs (PERF.md section 6, PR 24),
+    where ``perf_counter_ns`` costs 0.1 µs. So always-on it is read only
+    where a number depends on it — a phase with no parent (the root) and a
+    ``wait`` phase — and for every phase on the already-slow path (a ring
+    span or an annotation is open). Elsewhere ``cpu_ns`` is None."""
+
+    __slots__ = ("_q", "_name", "_cat", "_args", "_span", "_ann", "_parent",
+                 "_t0", "_c0", "child_ns")
+
+    def __init__(self, q, name: str, cat: str, args: Dict[str, Any]):
+        self._q = q
+        self._name = name
+        self._cat = cat
+        self._args = args
+        self._span = None
+        self._ann = None
+        self.child_ns = 0
+
+    def annotate(self, **args) -> None:
+        """Add arguments known only inside the phase (the ring span's
+        begin record holds this same dict)."""
+        self._args.update(args)
+
+    def __enter__(self) -> "_Phase":
+        self._parent = _ptls.open
+        _ptls.open = self
+        if _ACTIVE:
+            sp = span(self._name, self._cat)
+            if sp is not _NULL_SPAN:
+                sp._args = self._args
+                sp.__enter__()
+                self._span = sp
+        if _profiling._PROFILING_ACTIVE:
+            import jax.profiler
+            self._ann = jax.profiler.TraceAnnotation(
+                ANNOTATION_PREFIX + self._name)
+            self._ann.__enter__()
+        sampled = (self._cat == "wait" or self._parent is None
+                   or self._span is not None or self._ann is not None)
+        self._c0 = time.thread_time_ns() if sampled else None
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        wall = time.perf_counter_ns() - self._t0
+        cpu = None if self._c0 is None \
+            else time.thread_time_ns() - self._c0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        parent = self._parent
+        _ptls.open = parent
+        if parent is not None:
+            parent.child_ns += wall
+        if self._q is not None:
+            self._q.add_phase(self._name, self._cat, 1, wall, cpu,
+                              self.child_ns)
+        return False
+
+
+def phase(name: str, cat: str = "phase", **args):
+    """Context manager for one layer boundary of the served path. Always
+    adds ``{count, wall_ns, cpu_ns, child_wall_ns}`` to the phase table of
+    the QueryContext bound to this thread: ``cpu_ns`` is
+    ``time.thread_time_ns()`` where it is sampled (see :class:`_Phase`;
+    None elsewhere), so wall - cpu is the time the thread was off the CPU;
+    ``child_wall_ns`` is the wall of directly nested phases, so self time
+    is wall - child. Opens a ring span when the thread's query is traced,
+    and a ``TraceAnnotation("srt.<name>")`` when profiler annotations are
+    on. ``cat="wait"`` marks time the thread is MEANT to be blocked
+    (admission, semaphore, the carry fetch); any other category is time it
+    is meant to run. No bound context and annotations off: the shared null
+    context manager."""
+    q = _current_query()
+    if q is None and not _profiling._PROFILING_ACTIVE:
+        return _NULL_SPAN
+    return _Phase(q, name, cat, args)
+
+
+def phase_add(name: str, count: int, wall_ns: int, cpu_ns: Optional[int],
+              cat: str = "phase", child_wall_ns: int = 0) -> None:
+    """Add a phase measured by the caller (`cpu_ns` None: not sampled): a
+    per-batch loop reads the clock around its body and emits the sums once
+    per query (see :class:`PhaseLaps`). The wall also counts as child wall
+    of the phase open on this thread."""
+    q = _current_query()
+    if q is None:
+        return
+    q.add_phase(name, cat, count, wall_ns, cpu_ns, child_wall_ns)
+    parent = _ptls.open
+    if parent is not None:
+        parent.child_ns += wall_ns
+
+
+class _Lap:
+    """One phase's running sums inside a :class:`PhaseLaps`; re-entered
+    once per batch (laps of one name do not nest). While open it is the
+    thread's innermost phase, so what a nested ``phase_add`` reports (an
+    XLA compile inside a launch) becomes its child wall."""
+
+    __slots__ = ("cat", "count", "wall_ns", "cpu_ns", "child_ns", "_parent",
+                 "_t0", "_c0")
+
+    def __init__(self, cat: str):
+        self.cat = cat
+        self.count = self.wall_ns = self.child_ns = 0
+        # per batch the CPU clock is read for a wait only (see _Phase)
+        self.cpu_ns = 0 if cat == "wait" else None
+
+    def __enter__(self) -> None:
+        self._parent = _ptls.open
+        _ptls.open = self
+        if self.cpu_ns is not None:
+            self._c0 = time.thread_time_ns()
+        self._t0 = time.perf_counter_ns()
+
+    def __exit__(self, *exc) -> bool:
+        self.wall_ns += time.perf_counter_ns() - self._t0
+        if self.cpu_ns is not None:
+            self.cpu_ns += time.thread_time_ns() - self._c0
+        self.count += 1
+        _ptls.open = self._parent
+        return False
+
+
+class PhaseLaps:
+    """The per-batch discipline (docs/observability.md "Overhead") in one
+    place: a loop times each step of its body with ``lap(name)`` — two
+    ``perf_counter_ns`` reads — and ``flush()`` emits each sum ONCE through
+    :func:`phase_add`. On the already-slow path — this thread's query is
+    traced, or profiler annotations are on — a lap is a real
+    :func:`phase`: one span / annotation per batch. Owned by one thread at
+    a time."""
+
+    __slots__ = ("_laps",)
+
+    def __init__(self):
+        self._laps: Dict[str, _Lap] = {}
+
+    def lap(self, name: str, cat: str = "phase"):
+        if _profiling._PROFILING_ACTIVE or thread_traced():
+            return phase(name, cat)
+        lap = self._laps.get(name)
+        if lap is None:
+            lap = self._laps[name] = _Lap(cat)
+        return lap
+
+    def flush(self) -> None:
+        for name, lap in self._laps.items():
+            if lap.count:
+                phase_add(name, lap.count, lap.wall_ns, lap.cpu_ns, lap.cat,
+                          lap.child_ns)
+        self._laps.clear()
 
 
 def begin_query(name: str, buffer_events: int = 262144, categories=(),

@@ -168,6 +168,21 @@ def record_sync(kind: str, op: Optional[str] = None) -> None:
 _PROFILING_ACTIVE = False
 
 
+def set_trace_annotations(on: bool) -> None:
+    """The public switch for the engine's `TraceAnnotation`s — the
+    per-operator `trace_scope`s and the `srt.<phase>` spans of `obs.phase`
+    — for a caller that runs its own `jax.profiler` session
+    (`TpuProfiler.start/stop` call it themselves). It stores to
+    `_PROFILING_ACTIVE`, the one flag every site reads."""
+    global _PROFILING_ACTIVE
+    _PROFILING_ACTIVE = bool(on)
+
+
+#: the event `TpuProfiler.start` writes first: its `unix_ns` stat is
+#: time.time_ns() at its own start
+ANCHOR_SPAN = "srt.anchor"
+
+
 @contextlib.contextmanager
 def trace_scope(name: str):
     """NVTX-range analogue: a named scope in the xprof timeline. Free when no
@@ -192,23 +207,32 @@ class TpuProfiler:
         self.path = os.path.join(path_prefix,
                                  f"rapids-tpu-profile-{int(time.time())}")
         self._active = False
+        #: time.time_ns() as start_trace was called. The trace's event
+        #: times count from the profiler session's start, which lies inside
+        #: start_trace (docs/observability.md "Laying a query over a device
+        #: trace"); the ANCHOR_SPAN event gives the shift exactly
+        self.t0_unix_ns: Optional[int] = None
 
     def start(self) -> None:
-        global _PROFILING_ACTIVE
         import jax.profiler
         os.makedirs(self.path, exist_ok=True)
+        self.t0_unix_ns = time.time_ns()
         jax.profiler.start_trace(self.path)
         self._active = True
-        _PROFILING_ACTIVE = True
+        set_trace_annotations(True)
+        # one event that carries the realtime instant of its own start:
+        # unix_ns - start_ns is the realtime instant of the trace's zero
+        with jax.profiler.TraceAnnotation(ANCHOR_SPAN,
+                                          unix_ns=time.time_ns()):
+            pass
 
     def stop(self) -> None:
-        global _PROFILING_ACTIVE
         if not self._active:
             return
         import jax.profiler
+        set_trace_annotations(False)
         jax.profiler.stop_trace()
         self._active = False
-        _PROFILING_ACTIVE = False
 
     def __enter__(self) -> "TpuProfiler":
         self.start()

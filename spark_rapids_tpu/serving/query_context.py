@@ -53,7 +53,7 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
-from typing import Iterator, Optional
+from typing import Dict, Iterator, Optional
 
 #: lifecycle states (docs/robustness.md "Query lifecycle")
 QUEUED = "QUEUED"
@@ -160,6 +160,13 @@ class QueryContext:
         #: standard monitoring tradeoff). The scheduler sums a tenant's
         #: live contexts against its quota at admission time.
         self.hbm_bytes = 0
+        #: phase table (obs.phase / obs.phase_add; docs/observability.md
+        #: "Span model"): name -> [count, wall_ns, cpu_ns or None (not
+        #: sampled), child_wall_ns, cat], folded into the query's summary
+        #: at obs.metrics.query_end.
+        #: Written under _mu: pipeline workers bound to this context add
+        #: beside the query's own thread.
+        self.phases: Dict[str, list] = {}
         self._cancel = threading.Event()
         self._mu = threading.Lock()
         self._retry_budget = int(retry_budget)
@@ -244,6 +251,27 @@ class QueryContext:
                 return False
             self._retry_budget -= 1
             return True
+
+    # --- phase table --------------------------------------------------------
+    def add_phase(self, name: str, cat: str, count: int, wall_ns: int,
+                  cpu_ns: Optional[int], child_wall_ns: int = 0) -> None:
+        with self._mu:
+            cell = self.phases.get(name)
+            if cell is None:
+                cell = self.phases[name] = [0, 0, 0, 0, cat]
+            cell[0] += count
+            cell[1] += wall_ns
+            # one unsampled occurrence leaves the phase's CPU time unknown
+            cell[2] = None if cpu_ns is None or cell[2] is None \
+                else cell[2] + cpu_ns
+            cell[3] += child_wall_ns
+
+    def phase_table(self) -> Dict[str, Dict]:
+        """Snapshot of the phase table, as the query's summary carries it."""
+        with self._mu:
+            return {n: {"count": c[0], "wall_ns": c[1], "cpu_ns": c[2],
+                        "child_wall_ns": c[3], "cat": c[4]}
+                    for n, c in self.phases.items()}
 
     # --- state machine ------------------------------------------------------
     def mark_running(self) -> None:

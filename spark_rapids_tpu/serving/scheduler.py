@@ -608,8 +608,13 @@ class QueryScheduler:
             # work started, no resource acquired
             from ..chaos import inject
             inject("sched.admit", detail=qctx.name)
-            wait_ms = (time.perf_counter_ns() - ticket.enq_ns) / 1e6
+            wait_ns = time.perf_counter_ns() - ticket.enq_ns
+            wait_ms = wait_ns / 1e6
             qctx.admit_wait_ms = wait_ms
+            # the same interval as the query's phase `sched.admit_wait`
+            # (cat "wait": the thread is meant to be blocked), added after
+            # the fact so that nothing of the phase lies inside it
+            qctx.add_phase("sched.admit_wait", "wait", 1, wait_ns, None)
             _metrics.histogram_observe("sched.admit_wait_ms", wait_ms)
             _metrics.histogram_observe("sched.class_admit_wait_ms",
                                        wait_ms, cls=qctx.priority)
@@ -815,11 +820,36 @@ def execute_plan(session, plan, timeout: Optional[float] = None,
 
 def _run_admitted(session, plan_fn, conf, qctx: QueryContext, stem: str,
                   qname: str) -> List:
-    """One admitted query's execution window: planning (via the scheduler-
-    owned plan cache), partition loop(s), failure handling, and the
-    per-query observability snapshotting. Runs on the submitting thread
-    with the QueryContext bound; planning runs AFTER the tracer arms so
-    the plan.build span is part of the query's bundle."""
+    """One admitted query's execution window, on the submitting thread
+    with the QueryContext bound: the always-on lifecycle registration
+    around the root phase `query` (:func:`_run_query`)."""
+    from .. import obs
+    # always-on metrics registry (docs/observability.md): EVERY query
+    # (traced or not) registers its lifecycle — the queries.active
+    # gauge/list, the latency + rows/s histograms, the epoch the tracer's
+    # exclusivity check reads, and the per-query phase summary. Registered
+    # BEFORE planning so planning wall counts into the query latency
+    # window.
+    qtok = obs.metrics.query_begin(qname, session=stem,
+                                   cls=qctx.priority)
+    tables: List = []
+    failed = True  # cleared once every partition completed
+    try:
+        with obs.phase("query"):
+            _run_query(session, plan_fn, conf, qname, tables)
+        failed = False
+    finally:
+        session._last_query_phases = obs.metrics.query_end(
+            qtok, rows=sum(t.num_rows for t in tables), failed=failed,
+            session=stem, qctx=qctx)
+    return tables
+
+
+def _run_query(session, plan_fn, conf, qname: str, tables: List) -> None:
+    """The root phase's body: planning (via the scheduler-owned plan
+    cache), partition loop(s) appending to `tables`, failure handling, and
+    the per-query observability snapshotting. Planning runs AFTER the
+    tracer arms so the plan.build span is part of the query's bundle."""
     from .. import obs
     from ..config import (TRACE_BUFFER_EVENTS, TRACE_CATEGORIES,
                           TRACE_ENABLED)
@@ -828,22 +858,13 @@ def _run_admitted(session, plan_fn, conf, qctx: QueryContext, stem: str,
                              snapshot_plan_metrics)
     task_metrics_before = TaskMetricsRegistry.get().snapshot()
     syncs_before = SyncLedger.get().snapshot()
-    # always-on metrics registry (docs/observability.md): EVERY query
-    # (traced or not) registers its lifecycle — the queries.active
-    # gauge/list, the latency + rows/s histograms, and the epoch the
-    # tracer's exclusivity check reads. Registered BEFORE planning so
-    # planning wall counts into the query latency window.
-    qtok = obs.metrics.query_begin(qname, session=stem,
-                                   cls=qctx.priority)
     qroot = None
     opjit_before = None
     final = None
-    tables: List = []
     # window for this query's collective-exchange profiles (mesh
     # efficiency profiler): profiles are tagged with the traced query
     # name when one is bound; the seq window covers untraced queries
     mesh_seq0 = obs.mesh_profile.current_seq()
-    failed = True  # cleared by the last statement of the try body
     try:
         if conf.get(TRACE_ENABLED):
             from ..config import TRACE_MAX_CONCURRENT
@@ -864,11 +885,14 @@ def _run_admitted(session, plan_fn, conf, qctx: QueryContext, stem: str,
         # planning: plan-cache fetch (literal re-bind) or full logical
         # optimize → physical plan → override pass — one span, one
         # histogram, so planning share is measurable from the bundle
-        t_plan0 = time.perf_counter_ns()
-        with obs.span("plan.build", cat="plan"):
+        with obs.phase("plan.build", cat="plan") as ph:
+            # the histogram's clock inside the phase: what the phase costs
+            # to open and close when it is traced is not planning
+            t_plan0 = time.perf_counter_ns()
             final = plan_fn()
-        obs.metrics.histogram_observe(
-            "plan.build_ms", (time.perf_counter_ns() - t_plan0) / 1e6)
+            plan_ns = time.perf_counter_ns() - t_plan0
+            ph.annotate(cache=session._last_plan_cache)
+        obs.metrics.histogram_observe("plan.build_ms", plan_ns / 1e6)
         # mesh session (docs/distributed.md): the root pull drives ALL
         # partitions through the multi-partition entry point in one group,
         # so the top whole-stage segment (between the last exchange and the
@@ -877,50 +901,27 @@ def _run_admitted(session, plan_fn, conf, qctx: QueryContext, stem: str,
         n_parts = final.num_partitions()
         names = [a.name for a in final.output]
         group_pull = n_parts > 1 and mesh_session_active(conf) is not None
-        if group_pull:
-            ids = list(range(n_parts))
-            ctxs: Dict[int, TaskContext] = {}
+        # what runs after planning: every operator pull (the stage's own
+        # phases nest inside), the final sort, device→host, Arrow tables
+        with obs.phase("result.drain"):
+            if group_pull:
+                ids = list(range(n_parts))
+                ctxs: Dict[int, TaskContext] = {}
 
-            def ctx_of(i):
-                c = ctxs.get(i)
-                if c is None:
-                    c = ctxs[i] = TaskContext(i, conf)
-                return c
+                def ctx_of(i):
+                    c = ctxs.get(i)
+                    if c is None:
+                        c = ctxs[i] = TaskContext(i, conf)
+                    return c
 
-            try:
-                checkpoint(f"task.group 0-{ids[-1]}")
-                with obs.span(f"partition group 0-{ids[-1]}", cat="task",
-                              partitions=n_parts):
-                    for _p, t in final.execute_partitions(ids, ctx_of):
-                        if t.num_rows:
-                            tables.append(t.rename_columns(names))
-            except BaseException as exc:
-                from ..config import FATAL_ERROR_EXIT
-                from ..failure import handle_task_failure
-                handle_task_failure(
-                    exc, conf,
-                    exit_on_fatal=conf.get(FATAL_ERROR_EXIT))
-                raise
-            finally:
-                for c in ctxs.values():
-                    c.complete()
-        else:
-            for p in range(n_parts):
-                # cooperative cancellation at partition-task start: a
-                # cancelled/timed-out query stops scheduling new tasks
-                # before any of this partition's resources are acquired
-                checkpoint(f"task.start p{p}")
-                ctx = TaskContext(p, conf)
                 try:
-                    with obs.span(f"partition {p}", cat="task",
-                                  partition=p):
-                        for t in final.execute_partition(p, ctx):
+                    checkpoint(f"task.group 0-{ids[-1]}")
+                    with obs.span(f"partition group 0-{ids[-1]}", cat="task",
+                                  partitions=n_parts):
+                        for _p, t in final.execute_partitions(ids, ctx_of):
                             if t.num_rows:
                                 tables.append(t.rename_columns(names))
                 except BaseException as exc:
-                    # fatal device errors capture diagnostics and
-                    # (outside tests) exit so the cluster manager
-                    # reschedules (RapidsExecutorPlugin.onTaskFailed)
                     from ..config import FATAL_ERROR_EXIT
                     from ..failure import handle_task_failure
                     handle_task_failure(
@@ -928,8 +929,33 @@ def _run_admitted(session, plan_fn, conf, qctx: QueryContext, stem: str,
                         exit_on_fatal=conf.get(FATAL_ERROR_EXIT))
                     raise
                 finally:
-                    ctx.complete()
-        failed = False  # reached only when every partition completed
+                    for c in ctxs.values():
+                        c.complete()
+            else:
+                for p in range(n_parts):
+                    # cooperative cancellation at partition-task start: a
+                    # cancelled/timed-out query stops scheduling new tasks
+                    # before any of this partition's resources are acquired
+                    checkpoint(f"task.start p{p}")
+                    ctx = TaskContext(p, conf)
+                    try:
+                        with obs.span(f"partition {p}", cat="task",
+                                      partition=p):
+                            for t in final.execute_partition(p, ctx):
+                                if t.num_rows:
+                                    tables.append(t.rename_columns(names))
+                    except BaseException as exc:
+                        # fatal device errors capture diagnostics and
+                        # (outside tests) exit so the cluster manager
+                        # reschedules (RapidsExecutorPlugin.onTaskFailed)
+                        from ..config import FATAL_ERROR_EXIT
+                        from ..failure import handle_task_failure
+                        handle_task_failure(
+                            exc, conf,
+                            exit_on_fatal=conf.get(FATAL_ERROR_EXIT))
+                        raise
+                    finally:
+                        ctx.complete()
     finally:
         # snapshot metrics into plain dicts so the plan (and any device
         # buffers it references) is not pinned past the query; a planning
@@ -980,10 +1006,6 @@ def _run_admitted(session, plan_fn, conf, qctx: QueryContext, stem: str,
             for node in final.collect_nodes():
                 if hasattr(node, "cleanup_shuffle"):
                     node.cleanup_shuffle(conf)
-        obs.metrics.query_end(
-            qtok, rows=sum(t.num_rows for t in tables),
-            failed=failed, session=stem)
-    return tables
 
 
 def _finish_query_profile(session, qroot, conf, opjit_before) -> None:

@@ -1156,6 +1156,18 @@ class TpuSession:
         sched.class_admit_wait_ms histogram."""
         return getattr(self, "_last_admit_wait_ms", None)
 
+    def last_query_phases(self):
+        """The always-on phase summary of this session's last executed
+        query (docs/observability.md "Span model"): its clocks
+        (``t_begin_ns``/``t_end_ns`` on ``time.perf_counter_ns()``,
+        ``t_begin_unix_ns`` on the realtime clock a device trace can be
+        laid over), wall and CPU time of the query thread, XLA compiles,
+        and ``phases``: per layer boundary ``{count, wall_ns, cpu_ns,
+        child_wall_ns, cat}`` — self time is wall - child, wall - cpu the
+        time the thread was off the CPU. Needs no tracing. None before
+        any query."""
+        return getattr(self, "_last_query_phases", None)
+
     def last_query_metrics(self, level: Optional[str] = None):
         """Per-operator metrics of the last executed query (the reference
         surfaces these as SQLMetrics in the Spark SQL UI)."""
@@ -1273,7 +1285,7 @@ class TpuSession:
                      "_last_metrics_snapshot", "_last_sync_ledger",
                      "_last_task_metrics", "_last_mesh_profiles",
                      "_last_mesh_fallbacks", "_last_plan_cache",
-                     "_last_opt_rules"):
+                     "_last_opt_rules", "_last_query_phases"):
             if hasattr(self, attr):
                 setattr(self, attr, None)
         _flight.note("session.stop", session=self._session_id,

@@ -16,7 +16,8 @@ Usage:
 
 The snapshot is ``spark_rapids_tpu.obs.metrics.full_snapshot()`` — the same
 payload ``session.metrics_snapshot()`` serves: registry counters/gauges/
-histograms (with p50/p95/p99 readouts) plus the engine's other process-wide
+histograms (with p50/p95/p99 readouts), the per-phase totals of the served
+path (``obs.phase``) plus the engine's other process-wide
 counters folded in (opjit cache stats, mesh collective_stats, SyncLedger,
 task metrics, chaos, shuffle, HBM). Schema: docs/observability.md.
 """
@@ -100,6 +101,18 @@ def _render(snap: dict) -> str:
                     f"  {name}{tag}: count={h['count']} sum={h['sum']:.1f} "
                     f"p50={h['p50']:.0f} p95={h['p95']:.0f} "
                     f"p99={h['p99']:.0f}")
+    phases = snap.get("phases", {})
+    if phases:
+        lines += ["", "## phases (totals over the kept query summaries; self = "
+                  "wall - nested phases, off-CPU = wall - thread CPU)"]
+        for name, t in sorted(phases.items(),
+                              key=lambda kv: -kv[1]["wall_ns"]):
+            lines.append(
+                f"  {name} [{t['cat']}]: queries={t['queries']} "
+                f"count={t['count']} wall_ms={t['wall_ns'] / 1e6:.3f} "
+                f"self_ms={(t['wall_ns'] - t['child_wall_ns']) / 1e6:.3f} "
+                + ("cpu_ms=not-sampled" if t["cpu_ns"] is None
+                   else f"cpu_ms={t['cpu_ns'] / 1e6:.3f}"))
     pc = (snap.get("external", {}).get("scheduler", {}) or {}) \
         .get("plan_cache")
     if pc and "error" not in pc:
