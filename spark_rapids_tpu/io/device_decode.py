@@ -19,6 +19,14 @@ classes:
   type, bit layout) spec plus bucketed buffer shapes, and each dispatch is
   recorded under the ``parquet_decode`` kind in the process-wide dispatch
   accounting (`opjit.cache_stats()["calls_by_kind"]`);
+* the walk also records what layout it saw, and the spec carries it as
+  static structure: an index stream of bit-packed literal runs only is
+  staged without its run headers and unpacked with static shifts, PLAIN
+  values go over as uint32 words, and "dictionary pages, then PLAIN pages"
+  is placed by position — the program looks a run or a byte up per element
+  only where the layout is irregular (RLE runs, definition levels,
+  interleaved pages), and never because of a conf or a column name
+  (`decode_stats()["dense_values"]` of `["values"]` says how often);
 * BYTE_ARRAY string/binary columns decode into the engine's own
   offsets+bytes device layout (`columnar/vector.py`): PLAIN pages walk
   their 4-byte length prefixes host-side into per-value (start, length)
@@ -87,6 +95,10 @@ _STATS: Dict[str, int] = {
     "programs": 0,          # distinct compiled decode programs
     "row_groups": 0,
     "rows": 0,
+    "values": 0,            # decoded values: rows x device columns
+    "dense_values": 0,      # ... of those, decoded with no per-element run
+                            # lookup (dense index streams, PLAIN values of
+                            # REQUIRED columns)
     "bytes_staged": 0,      # raw page bytes shipped to HBM
     "device_columns": 0,
     "fallback_columns": 0,     # per-column host demotions
@@ -259,7 +271,13 @@ def encrypted_message(path: str, reason: str) -> str:
 # RLE / bit-packed hybrid run-header walk (host: O(runs), tiny)
 # ---------------------------------------------------------------------------
 
-from ..kernels.parquet_decode import RUN_COLS, RUN_PAD_START, RUN_START
+from ..kernels.parquet_decode import (RUN_BITOFF, RUN_COLS, RUN_LITERAL,
+                                      RUN_PAD_START, RUN_START, RUN_WIDTH)
+
+#: host-only sixth field of a walked run: the slots (values, padding of the
+#: last group included) its payload holds; 0 marks a literal run whose payload
+#: the page end cuts short
+RUN_SLOTS = RUN_COLS
 
 
 def _walk_runs(data, start: int, end: int, bw: int, n: int,
@@ -267,7 +285,8 @@ def _walk_runs(data, start: int, end: int, bw: int, n: int,
     """Walk hybrid run headers in data[start:end) covering `n` values.
     Returns run-table rows [out_start, abs_bitoff, value, literal, width]
     with output positions offset by `out_base` and literal bit offsets by
-    `bit_base` (both in the staged, concatenated buffers)."""
+    `bit_base` (both in the staged, concatenated buffers), each followed by
+    what the header said of the run's extent (RUN_SLOTS)."""
     runs: List[List[int]] = []
     out = 0
     vbytes = (bw + 7) // 8
@@ -278,9 +297,10 @@ def _walk_runs(data, start: int, end: int, bw: int, n: int,
             cnt = (h >> 1) * 8
             if cnt <= 0:
                 raise ValueError("zero-length literal run")
+            nbytes = (cnt * bw + 7) // 8
             runs.append([out_base + out, bit_base + (pos - start) * 8,
-                         0, 1, bw])
-            pos += (cnt * bw + 7) // 8
+                         0, 1, bw, cnt if pos + nbytes <= end else 0])
+            pos += nbytes
         else:
             cnt = h >> 1
             if cnt <= 0:
@@ -290,7 +310,7 @@ def _walk_runs(data, start: int, end: int, bw: int, n: int,
             v = int.from_bytes(data[pos:pos + vbytes], "little") \
                 if vbytes else 0
             pos += vbytes
-            runs.append([out_base + out, 0, v, 0, 0])
+            runs.append([out_base + out, 0, v, 0, 0, cnt])
         out += cnt
     if out < n:
         raise ValueError(f"runs cover {out} of {n} values")
@@ -511,6 +531,7 @@ class _Staged:
     assembled column can surface a device `dict_encoding`."""
     spec: Tuple
     arrays: List[np.ndarray]
+    dense_values: int = 0   # values decoded with no per-element run lookup
     dict_offsets: Optional[np.ndarray] = None
     dict_chars: Optional[np.ndarray] = None
 
@@ -533,8 +554,103 @@ def _pad_runs(rows: List[List[int]]) -> np.ndarray:
     out = np.full((cap, RUN_COLS), 0, np.int64)
     out[:, RUN_START] = RUN_PAD_START  # searchsorted never lands on padding
     for i, r in enumerate(rows):
-        out[i] = r
+        out[i] = r[:RUN_COLS]
     return out
+
+
+#: a dense index stream is staged as segments padded to one common size, so
+#: that the program's key stays coarse; where that takes more than this many
+#: slots per slot of the output bucket (bytes staged and uploaded, more than
+#: device time), the run table is the better description
+_DENSE_PAD_LIMIT = 4
+
+
+def _literal_segments(runs: List[List[int]], parts: List[bytes], n: int,
+                      out_cap: int):
+    """An index stream of `n` values made of bit-packed literal runs only,
+    as the header-free bit streams the device unpacks with static shifts
+    (`unpack_dense_segments`). Returns what the program's key takes —
+    ((width, segment bucket), ...) with widths ascending and the slot
+    bucket all segments are padded to — and, as data, each segment slot's
+    dense start and value count and the staged uint32 words. None where the
+    layout is anything else (an RLE run, a run the page end cuts short,
+    segments so uneven that padding them alike takes more than
+    _DENSE_PAD_LIMIT x out_cap slots): the run table then drives the
+    general expansion.
+
+    A segment is a stretch of runs of one bit width, each starting at the
+    slot where the one before ends, so its value i sits at bit i * width.
+    A literal run holds a multiple of 8 slots: only a page that ends inside
+    a group leaves padding slots, and the next page then starts a segment.
+    The key holds no per-page count: the widths that occur, how many
+    segments of each as a bucket, one slot bucket for all.
+    O(runs) Python + one O(bytes) copy."""
+    if not runs:
+        return None
+    table = np.array(runs, np.int64)
+    width, start, slots = (table[:, c] for c in
+                           (RUN_WIDTH, RUN_START, RUN_SLOTS))
+    if not (table[:, RUN_LITERAL].all() and slots.all()
+            and width.min() >= 1 and width.max() <= 32):
+        return None
+    first = np.ones(len(runs), bool)     # runs that open a segment
+    first[1:] = (width[1:] != width[:-1]) \
+        | (start[1:] != start[:-1] + slots[:-1])
+    opens = np.flatnonzero(first)
+    seg_slots = max(bucket_capacity(int(np.add.reduceat(slots, opens).max())),
+                    32)
+    widths, group, per_width = np.unique(
+        width[opens], return_inverse=True, return_counts=True)
+    groups = tuple((int(w), bucket_capacity(int(k), minimum=1))
+                   for w, k in zip(widths, per_width))
+    if sum(k for _, k in groups) * seg_slots > _DENSE_PAD_LIMIT * out_cap:
+        return None
+    # segments in the order they are staged: by width, then as they came
+    order = np.argsort(group, kind="stable")
+    first_slot = np.cumsum([0] + [k for _, k in groups])    # of each group
+    first_seg = np.cumsum(np.append(0, per_width))
+    slot = first_slot[group[order]] + np.arange(len(order)) \
+        - first_seg[group[order]]
+    starts = np.zeros(first_slot[-1], np.int32)
+    counts = np.zeros(first_slot[-1], np.int32)
+    starts[slot] = start[opens][order]
+    counts[slot] = np.diff(np.append(start[opens], n))[order]
+    # every run's payload goes from its part to its place in one copy
+    views = [memoryview(p) for p in parts]
+    part_at = np.cumsum([0] + [len(p) for p in parts[:-1]])
+    src = table[:, RUN_BITOFF] >> 3
+    part = np.searchsorted(part_at, src, side="right") - 1
+    lo = src - part_at[part]
+    nbytes = slots * width // 8
+    payload = [views[p][a:b] for p, a, b in zip(
+        part.tolist(), lo.tolist(), (lo + nbytes).tolist())]
+    used = np.add.reduceat(nbytes, opens).tolist()
+    bounds = opens.tolist() + [len(runs)]
+    zeros = memoryview(bytes(seg_slots * 4))
+    pieces = []
+    for g, (w, k) in enumerate(groups):
+        seg_bytes = seg_slots // 8 * w
+        for s in order[first_seg[g]:first_seg[g + 1]].tolist():
+            pieces += payload[bounds[s]:bounds[s + 1]]
+            pieces.append(zeros[:seg_bytes - used[s]])
+        pieces += [zeros[:seg_bytes]] * (k - int(per_width[g]))
+    words = np.frombuffer(b"".join(pieces), np.uint32)
+    return (groups, seg_slots), starts, counts, words
+
+
+def _place_plain(parts: List[bytes], first: int, cap: int,
+                 itemsize: int) -> np.ndarray:
+    """PLAIN value regions copied to their dense positions (values from
+    `first` on) of a zeroed `cap`-value buffer, handed over as the uint32
+    words `plain_fixed_width` pairs up."""
+    out = np.empty(cap * itemsize, np.uint8)
+    pos = first * itemsize
+    out[:pos] = 0
+    for p in parts:
+        out[pos:pos + len(p)] = np.frombuffer(p, np.uint8)
+        pos += len(p)
+    out[pos:] = 0
+    return out.view(np.uint32)
 
 
 def _decompress(codec: Optional[str], body, usize: int) -> bytes:
@@ -973,6 +1089,7 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
 
     out_np = str(np.dtype(plan.out_dtype.np_dtype))
     arrays: List[np.ndarray] = []
+    dense_values = 0
     if plan.nullable:
         lvr = _pad_runs(lv_runs)
         lvb = _pad_bytes(lv_parts)
@@ -993,36 +1110,54 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
         spec = ("bool", out_np, plan.nullable, lv_shape,
                 (vr.shape[0], vb.shape[0]), cap)
     elif saw_dict_data:
-        vr = _pad_runs(val_runs)
-        vb = _pad_bytes(val_parts)
-        db = _pad_bytes([dict_bytes], min_len=plan.itemsize)
-        # dictionary buffer must reshape exactly: trim padding to a
-        # multiple of the item size
-        db = db[: (db.shape[0] // plan.itemsize) * plan.itemsize]
-        arrays += [vr, vb, db]
-        if saw_plain_data:
-            # mid-chunk dictionary fallback: later pages carry PLAIN
-            # values merged back into the dense stream by segment table
-            seg = _pad_runs(segs)
-            pb = _pad_bytes(plain_parts, min_len=plan.itemsize)
-            pb = pb[: (pb.shape[0] // plan.itemsize) * plan.itemsize]
-            arrays += [seg, pb]
-            plain_shape = (seg.shape[0], pb.shape[0])
+        # "dictionary pages, then PLAIN pages" (one switch point, what a
+        # writer's dictionary overflow produces) is a concatenation: only
+        # the dictionary-coded prefix expands and gathers, in its own bucket
+        flags = [sg[RUN_LITERAL] for sg in segs]
+        tail = saw_plain_data and flags == sorted(flags)
+        n_dict = dense_seen - plain_seen
+        idx_cap = bucket_capacity(max(n_dict, 1)) if tail else cap
+        # (interleaved dictionary and PLAIN pages keep the general path whole)
+        lit = None if plan.nullable or (saw_plain_data and not tail) \
+            else _literal_segments(val_runs, val_parts, n_dict, idx_cap)
+        if lit is not None:
+            (groups, seg_slots), starts, counts, words = lit
+            arrays += [starts, counts, words]
+            idx_spec = ("dense", groups, seg_slots, idx_cap)
         else:
-            plain_shape = None
+            vr = _pad_runs(val_runs)
+            vb = _pad_bytes(val_parts)
+            arrays += [vr, vb]
+            idx_spec = ("runs", vr.shape[0], vb.shape[0], idx_cap)
+        db = _pad_bytes([dict_bytes], min_len=plan.itemsize).view(np.uint32)
+        arrays += [db]
+        if tail:
+            # PLAIN values staged at their dense positions, n_dict a
+            # run-time operand: the program selects by position
+            arrays += [np.array(n_dict, np.int32),
+                       _place_plain(plain_parts, n_dict, cap, plan.itemsize)]
+            plain_spec = ("tail",)
+        elif saw_plain_data:
+            # interleaved dictionary and PLAIN pages: PLAIN values merge
+            # back into the dense stream by segment table
+            seg = _pad_runs(segs)
+            pb = _pad_bytes(plain_parts, min_len=plan.itemsize) \
+                .view(np.uint32)
+            arrays += [seg, pb]
+            plain_spec = ("segments", seg.shape[0], pb.shape[0])
+        else:
+            plain_spec = None
+        dense_values = (n_dict if lit is not None else 0) \
+            + (plain_seen if tail else 0)
         spec = ("dict", plan.itemsize, plan.vkind, out_np, plan.nullable,
-                lv_shape, (vr.shape[0], vb.shape[0]), db.shape[0],
-                plain_shape, cap)
+                lv_shape, idx_spec, db.shape[0], plain_spec, cap)
     else:
-        vb = np.zeros(cap * plan.itemsize, np.uint8)
-        ppos = 0
-        for p in plain_parts:
-            vb[ppos:ppos + len(p)] = np.frombuffer(p, np.uint8)
-            ppos += len(p)
-        arrays += [vb]
+        arrays += [_place_plain(plain_parts, 0, cap, plan.itemsize)]
+        dense_values = dense_seen
         spec = ("plain", plan.itemsize, plan.vkind, out_np, plan.nullable,
                 lv_shape, cap)
-    return _Staged(spec, arrays)
+    # (definition levels are a per-element run lookup of their own)
+    return _Staged(spec, arrays, 0 if plan.nullable else dense_values)
 
 
 # ---------------------------------------------------------------------------
@@ -1098,18 +1233,32 @@ def _build_program(specs: Tuple[Tuple, ...]):
                     dense = K.decode_bool_runs(vr, vb, cap)
             elif kind == "dict":
                 isz, vkind = spec[1], spec[2]
-                vr, vb, db = next(it), next(it), next(it)
+                idx_spec, plain_spec = spec[6], spec[8]
                 with jax.named_scope("rle_expand"):
-                    idx = K.expand_runs(vr, vb, cap)
+                    if idx_spec[0] == "dense":
+                        starts, counts, words = next(it), next(it), next(it)
+                        idx = K.unpack_dense_segments(
+                            words, idx_spec[1], idx_spec[2], starts, counts,
+                            idx_spec[3])
+                    else:
+                        vr, vb = next(it), next(it)
+                        idx = K.expand_runs(vr, vb, idx_spec[3])
+                db = next(it)
                 with jax.named_scope("dict_gather"):
                     dvals = K.plain_fixed_width(db, isz, vkind)
                     dense = K.dictionary_gather(dvals, idx)
-                if spec[8] is not None:  # mid-chunk dictionary fallback
-                    seg, pb = next(it), next(it)
+                if plain_spec is not None:  # mid-chunk dictionary fallback
                     with jax.named_scope("plain"):
-                        pvals = K.plain_fixed_width(pb, isz, vkind)
-                        dense = K.merge_plain_segments(seg, pvals, dense,
-                                                       cap)
+                        if plain_spec[0] == "tail":
+                            n_dict, pb = next(it), next(it)
+                            dense = K.place_plain_tail(
+                                dense, K.plain_fixed_width(pb, isz, vkind),
+                                n_dict)
+                        else:
+                            seg, pb = next(it), next(it)
+                            dense = K.merge_plain_segments(
+                                seg, K.plain_fixed_width(pb, isz, vkind),
+                                dense, cap)
             else:  # plain
                 isz, vkind = spec[1], spec[2]
                 vb = next(it)
@@ -1410,6 +1559,9 @@ class DeviceFileDecoder:
                     _bump("dispatches")
                     _bump("row_groups")
                     _bump("rows", num_rows)
+                    _bump("values", num_rows * len(kept))
+                    _bump("dense_values",
+                          sum(st.dense_values for st in staged))
                     _bump("device_columns", len(kept))
                     opjit.record_external_dispatch("parquet_decode")
                     outs = fn(np.int64(num_rows), *uploaded)

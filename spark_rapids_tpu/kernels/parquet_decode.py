@@ -4,32 +4,48 @@ Reference: the plugin decodes parquet bytes ON DEVICE after host staging —
 `GpuParquetScan.scala:1983,2506` acquires the semaphore and hands the raw
 (decompressed) column-chunk bytes to cuDF's page decoders. The TPU analogue
 lives here: every O(rows) transform of the Parquet physical encodings is a
-pure jnp function over device uint8 buffers, composed per row group into ONE
-cached program by io/device_decode.py. The host touches only O(pages) +
+pure jnp function over device uint8 / uint32 buffers, composed per row group
+into ONE cached program by io/device_decode.py. The host touches only O(pages) +
 O(runs) metadata (footer, page headers, RLE run headers) and the
 decompression pass; the unpack/expand/gather/scatter work below runs on
 device.
 
-Encodings covered (the flat fixed-width column classes):
+Encodings covered (the flat fixed-width column classes). What costs time
+on a TPU is a per-element gather (8.6 ns an element from a 4 K-entry int32
+table, 14-21 ns for float64, on a v5e: PERF.md PR 25), so the program looks
+a run or a byte up per element only where the page layout is irregular; the
+host's page walk says which (io/device_decode.py):
 
-* **bit-unpacking** (`unpack_bits`) — 1..32-bit packed little-endian values
-  at arbitrary per-element bit offsets (PLAIN booleans, bit-packed literal
-  runs, dictionary indices of any per-page bit width);
-* **RLE / bit-packed hybrid run expansion** (`expand_runs`) — dictionary
-  indices and definition levels. The host walks the varint run headers into
-  a run table (one row per run: output start, absolute bit offset, repeated
-  value, literal flag, bit width); the kernel positions every output element
-  in its run with one `searchsorted` and either bit-unpacks (literal run) or
-  broadcasts the run value (RLE run);
+* **dense bit-unpacking** (`unpack_dense`, `unpack_dense_segments`) — a
+  dictionary-index stream made of bit-packed literal runs only, staged
+  without its run headers: value k of every group of 32 is a static shift
+  over one or two uint32 word lanes. No lookup at all; a stream whose bit
+  width grows with the dictionary, or whose pages end inside a group of 8,
+  is several such segments, unpacked together per width;
+* **general bit-unpacking** (`unpack_bits`) — 1..32-bit packed little-endian
+  values at arbitrary per-element bit offsets (PLAIN booleans, literal runs
+  mixed with RLE runs);
+* **RLE / bit-packed hybrid run expansion** (`expand_runs`) — definition
+  levels and every index stream the dense layout does not cover. The host
+  walks the varint run headers into a run table (one row per run: output
+  start, absolute bit offset, repeated value, literal flag, bit width); the
+  kernel positions every output element in its run with one `searchsorted`
+  and either bit-unpacks (literal run) or broadcasts the run value (RLE
+  run) — about twenty gathers an element;
 * **dictionary gather** (`dictionary_gather`) — expanded indices into the
-  PLAIN-decoded dictionary values;
+  PLAIN-decoded dictionary values: the one gather an element that stays;
 * **definition levels → validity** (`validity_from_defs`) and **null
   compaction** (`expand_dense`) — Parquet stores only non-null values
   densely; the scatter re-expands them into the padded-batch layout
   `columnar/batch.py` uses (rows in [num_rows, capacity) stay zero/invalid);
-* **PLAIN fixed-width reinterpret** (`plain_fixed_width`) — raw
-  little-endian value bytes to int8/16/32/64, float32/64 carriers via byte
-  math + bitcast (no host round trip);
+* **PLAIN fixed-width reinterpret** (`plain_fixed_width`) — the staged
+  little-endian value bytes, viewed as uint32 words on the host, to
+  int32/int64/float32/float64 carriers: a bitcast, or the even and odd
+  words paired (no host round trip, no per-byte arithmetic);
+* **dictionary → PLAIN fallback** — one switch point is a concatenation
+  (`place_plain_tail`: the host stages the PLAIN values at their dense
+  positions, one select merges); interleaved pages keep the segment-table
+  merge (`merge_plain_segments`);
 * **BYTE_ARRAY strings** (`string_offsets`, `gather_string_bytes`) — the
   variable-width classes decode into the engine's own Arrow-style
   offsets+bytes layout (`columnar/vector.py`): per-row byte lengths (from
@@ -124,36 +140,111 @@ def dictionary_gather(dict_values, indices):
                     mode="clip")
 
 
-def plain_fixed_width(data_u8, itemsize: int, kind: str):
-    """PLAIN fixed-width reinterpret: little-endian value bytes → carrier
-    values, entirely on device (byte combine + bitcast).
+def unpack_dense(words_u32, width: int, out_len: int):
+    """Gather-free unpack of a header-free stream of `width`-bit values
+    (1..32, static): value i sits at bit i * width of the little-endian
+    uint32 words. 32 values fill exactly `width` words, so value k of every
+    group is the same static shift over the same one or two word lanes and
+    nothing is looked up per element. The lanes are the rows of the word
+    array transposed once, the output one transpose of the 32 value rows;
+    all arithmetic is uint32. `words_u32` holds out_len / 32 * width words
+    (`out_len` a multiple of 32, at least 32); slots past the staged values
+    read the zero padding."""
+    groups = out_len // 32
+    lanes = words_u32[:groups * width].reshape(groups, width).T
+    vals = []
+    for k in range(32):
+        w, s = divmod(k * width, 32)
+        v = lanes[w] >> jnp.uint32(s)
+        if s + width > 32:  # the value straddles two words
+            v = v | (lanes[w + 1] << jnp.uint32(32 - s))
+        if width < 32:
+            v = v & jnp.uint32((1 << width) - 1)
+        vals.append(v)
+    return jnp.stack(vals, axis=0).T.reshape(out_len)
 
-    kind: "i" signed int, "u" unsigned int, "f" float; itemsize 1/2/4/8.
+
+def unpack_dense_segments(words_u32, groups_py, slots: int, starts, counts,
+                          out_len: int):
+    """A dictionary-index stream of bit-packed literal runs only, staged as
+    header-free segments (io/device_decode.py::_literal_segments), each
+    padded to the common `slots` (static, a multiple of 32). `groups_py` is
+    the static ((width, segments), ...), widths ascending: a group's
+    segments lie back to back, so they unpack as ONE dense stream of
+    segments * slots values, and the groups' words follow one another.
+    `starts` and `counts` are data, one entry per segment slot in the same
+    order: the dense position of the segment's first value and how many
+    values it really holds (0 for a slot no segment fills). Each segment
+    writes its own `counts` values only, so the order of placement is free
+    and padding slots touch nothing."""
+    if len(groups_py) == 1 and groups_py[0][1] == 1 and slots >= out_len:
+        return unpack_dense(words_u32, groups_py[0][0], slots)[:out_len]
+    out = jnp.zeros((out_len + slots,), jnp.uint32)
+    lane = jnp.arange(slots, dtype=jnp.int32)
+    word_at = seg_at = 0
+    for width, n_seg in groups_py:
+        n_words = n_seg * (slots // 32) * width
+        vals = unpack_dense(
+            jax.lax.slice(words_u32, (word_at,), (word_at + n_words,)),
+            width, n_seg * slots).reshape(n_seg, slots)
+
+        def place(k, out, vals=vals, seg_at=seg_at):
+            at = jax.lax.dynamic_index_in_dim(starts, seg_at + k, 0, False)
+            n = jax.lax.dynamic_index_in_dim(counts, seg_at + k, 0, False)
+            seg = jax.lax.dynamic_index_in_dim(vals, k, 0, False)
+            kept = jax.lax.dynamic_slice(out, (at,), (slots,))
+            return jax.lax.dynamic_update_slice(
+                out, jnp.where(lane < n, seg, kept), (at,))
+        if n_seg <= 8:  # flat: the TPU compiler keeps even a loop of one
+            for k in range(n_seg):
+                out = place(k, out)
+        else:
+            out = jax.lax.fori_loop(0, n_seg, place, out)
+        word_at += n_words
+        seg_at += n_seg
+    return out[:out_len]
+
+
+def plain_fixed_width(words_u32, itemsize: int, kind: str):
+    """PLAIN fixed-width reinterpret: the staged little-endian value bytes,
+    handed over as uint32 words (a free host view), to carrier values.
+    4-byte types are one bitcast; 8-byte types pair the even (low) and odd
+    (high) words — strided slices, no per-byte arithmetic.
+
+    kind: "i" signed int, "f" float; itemsize 4/8 (INT32/INT64/FLOAT/DOUBLE).
     """
-    b = data_u8.reshape(-1, itemsize).astype(jnp.uint64)
-    word = jnp.zeros((b.shape[0],), jnp.uint64)
-    for k in range(itemsize):
-        word = word | (b[:, k] << jnp.uint64(8 * k))
-    if kind == "f":
-        if itemsize == 4:
-            return jax.lax.bitcast_convert_type(
-                word.astype(jnp.uint32), jnp.float32)
-        return jax.lax.bitcast_convert_type(word, jnp.float64)
-    target = {1: jnp.int8, 2: jnp.int16, 4: jnp.int32, 8: jnp.int64}[itemsize]
-    if kind == "u":
-        utarget = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32,
-                   8: jnp.uint64}[itemsize]
-        return word.astype(utarget)
-    narrow = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32,
-              8: jnp.uint64}[itemsize]
-    return jax.lax.bitcast_convert_type(word.astype(narrow), target)
+    if itemsize == 4:
+        return jax.lax.bitcast_convert_type(
+            words_u32, jnp.float32 if kind == "f" else jnp.int32)
+    n = words_u32.shape[0]
+    lo = jax.lax.slice(words_u32, (0,), (n - 1,), (2,)).astype(jnp.uint64)
+    hi = jax.lax.slice(words_u32, (1,), (n,), (2,)).astype(jnp.uint64)
+    return jax.lax.bitcast_convert_type(
+        lo | (hi << jnp.uint64(32)),
+        jnp.float64 if kind == "f" else jnp.int64)
+
+
+def place_plain_tail(dict_prefix, plain_values, n_dict):
+    """Dictionary fallback with one switch point — dictionary pages, then
+    PLAIN pages, what every parquet-cpp / parquet-mr writer produces: the
+    dense stream is a concatenation. The host stages the PLAIN values at
+    their dense positions (from `n_dict` on), `dict_prefix` holds the
+    dictionary-gathered values of the first `n_dict` (a run-time operand)
+    in a bucket of its own: one select by position merges them."""
+    pad = plain_values.shape[0] - dict_prefix.shape[0]
+    if pad:
+        dict_prefix = jnp.concatenate(
+            [dict_prefix, jnp.zeros((pad,), dict_prefix.dtype)])
+    idx = jnp.arange(plain_values.shape[0], dtype=jnp.int32)
+    return jnp.where(idx < n_dict, dict_prefix, plain_values)
 
 
 def merge_plain_segments(seg_table, plain_values, base, out_len: int):
-    """Mid-chunk dictionary fallback: once a writer's dictionary overflows,
-    later data pages store PLAIN values while earlier pages stay
-    dictionary-indexed (parquet's standard fallback; cuDF decodes such
-    chunks natively). `seg_table` marks each data page's dense range
+    """Mid-chunk dictionary fallback with interleaved segments (the general
+    form; the one-switch layout takes `place_plain_tail`): once a writer's
+    dictionary overflows, later data pages store PLAIN values while earlier
+    pages stay dictionary-indexed (parquet's standard fallback; cuDF decodes
+    such chunks natively). `seg_table` marks each data page's dense range
     ([dense_start, plain_src_start, 0, is_plain, 0] rows): elements inside
     a PLAIN page's range read `plain_values[src_start + (i - dense_start)]`,
     everything else keeps `base` (the dictionary-gathered stream)."""

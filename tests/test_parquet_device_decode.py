@@ -419,6 +419,520 @@ def test_orc_pushdown_filters_reach_scan(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# gather-free decode: dense index streams, dictionary -> PLAIN fallback by
+# position, PLAIN from uint32 words — chosen from the page layout alone, and
+# bit-identical to pyarrow whichever path a chunk takes
+# ---------------------------------------------------------------------------
+
+
+def _bucket(n):
+    from spark_rapids_tpu.columnar.vector import bucket_capacity
+    return bucket_capacity(n)
+
+
+def _program_specs():
+    return [spec for specs in dd._PROGRAMS for spec in specs]
+
+
+def _varint_bytes(v):
+    out = bytearray()
+    while v >= 0x80:
+        out.append((v & 0x7F) | 0x80)
+        v >>= 7
+    out.append(v)
+    return bytes(out)
+
+
+def _literal_page(values, bw, groups_per_run=63):
+    """One page's hybrid-encoded region made of bit-packed literal runs only
+    (what parquet-cpp writes for non-repeating values: runs of <= 504)."""
+    out = bytearray()
+    step = groups_per_run * 8
+    for a in range(0, len(values), step):
+        part = np.asarray(values[a:a + step], np.uint64)
+        groups = -(-len(part) // 8)
+        slots = np.zeros(groups * 8, np.uint64)
+        slots[:len(part)] = part
+        bits = ((slots[:, None] >> np.arange(bw, dtype=np.uint64)) & 1) \
+            .astype(np.uint8).reshape(-1)
+        out += _varint_bytes((groups << 1) | 1)
+        out += np.packbits(bits, bitorder="little").tobytes()
+    return bytes(out)
+
+
+def _walk_pages(pages, bw):
+    """Run rows + staged parts of consecutive pages, as _stage_column
+    accumulates them."""
+    runs, parts, seen, bits = [], [], 0, 0
+    for vals, region in pages:
+        runs += dd._walk_runs(region, 0, len(region), bw, len(vals), seen,
+                              bits)
+        parts.append(region)
+        seen += len(vals)
+        bits += len(region) * 8
+    return runs, parts, seen
+
+
+@pytest.mark.parametrize("width", range(1, 33))
+def test_dense_unpack_equals_run_expansion(width):
+    """Every bit width 1..32 (a file cannot carry a 2^31-entry dictionary,
+    so the kernel is driven from hand-encoded pages): pages of 504-value
+    runs with a short last run, page value counts that are multiples of 8
+    except the last, a total that is no multiple of 8 or 512."""
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.kernels import parquet_decode as K
+    rng = np.random.default_rng(width)
+    counts = [1016, 8, 2048, 900 + width]        # the last page ends mid-group
+    vals = [rng.integers(0, 1 << width, n, dtype=np.uint64) for n in counts]
+    runs, parts, n = _walk_pages(
+        [(v, _literal_page(v, width)) for v in vals], width)
+    want = np.concatenate(vals)
+    cap = 4096
+    lit = dd._literal_segments(runs, parts, n, cap)
+    assert lit is not None
+    (groups, slots), starts, counts, words = lit
+    assert (groups, slots) == (((width, 1),), cap)
+    assert (list(starts), list(counts)) == ([0], [n])
+    got = K.unpack_dense_segments(jnp.asarray(words), groups, slots,
+                                  jnp.asarray(starts), jnp.asarray(counts),
+                                  cap)
+    assert got.dtype == jnp.uint32
+    assert np.array_equal(np.asarray(got)[:n], want)
+    general = K.expand_runs(jnp.asarray(dd._pad_runs(runs)),
+                            jnp.asarray(dd._pad_bytes(parts)), cap)
+    assert np.array_equal(np.asarray(general)[:n], want.astype(np.int64))
+
+
+@pytest.mark.parametrize("case", ["width_change", "page_ends_mid_group",
+                                  "widths_out_of_order", "many_small_pages",
+                                  "tiny", "rle_run", "truncated_run",
+                                  "uneven_segments"])
+def test_literal_segments_layouts(case):
+    """What the walk may call dense: width changes and mid-group page ends
+    split the stream into segments, staged by width and placed at their
+    dense starts. The key holds the widths, a bucket of the segment count
+    of each and one slot bucket, never a page's own count. An RLE run, a
+    run the page end cuts short or segments too uneven to pad alike stay
+    general."""
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.kernels import parquet_decode as K
+    rng = np.random.default_rng(11)
+
+    def page(n, bw):
+        v = rng.integers(0, 1 << bw, n, dtype=np.uint64)
+        return v, _literal_page(v, bw), bw
+
+    cap = 8192
+    if case == "width_change":
+        pages = [page(1024, 9), page(520, 9), page(2048, 10), page(77, 11)]
+        want_key = (((9, 1), (10, 1), (11, 1)), 2048)
+    elif case == "page_ends_mid_group":
+        pages = [page(1001, 7), page(1024, 7), page(3, 7)]
+        want_key = (((7, 2),), 2048)             # the 3-value page continues
+    elif case == "widths_out_of_order":          # staged by width, not order
+        pages = [page(100, 9), page(1001, 7), page(50, 9), page(8, 7),
+                 page(300, 12), page(77, 7)]
+        want_key, cap = (((7, 4), (9, 2), (12, 1)), 1024), 2048
+    elif case == "many_small_pages":
+        pages = [page(9, 5) for _ in range(10)] + [page(100, 5)]
+        want_key, cap = (((5, 16),), 128), 512
+    elif case == "tiny":                         # an output bucket under 32
+        pages = [page(3, 2), page(6, 3)]
+        want_key, cap = (((2, 1), (3, 1)), 32), 16
+    elif case == "uneven_segments":
+        pages = [page(4099, 5)] + [page(9, 5) for _ in range(9)]
+        want_key = None
+    else:
+        pages = [page(1024, 6), page(512, 6)]
+        want_key = None
+    runs, parts, seen, bits, want = [], [], 0, 0, []
+    for vals, region, bw in pages:
+        if case == "rle_run" and seen:
+            region = _varint_bytes(40 << 1) + b"\x05" + region
+            vals = np.concatenate([np.full(40, 5, np.uint64), vals])
+        if case == "truncated_run" and seen:
+            region = region[:-3]
+        runs += dd._walk_runs(region, 0, len(region), bw, len(vals), seen,
+                              bits)
+        parts.append(region)
+        want.append(vals)
+        seen += len(vals)
+        bits += len(region) * 8
+    lit = dd._literal_segments(runs, parts, seen, cap)
+    if want_key is None:
+        assert lit is None
+        return
+    key, starts, counts, words = lit
+    assert key == want_key
+    assert int(counts.sum()) == seen
+    assert len(starts) == len(counts) == sum(k for _, k in key[0])
+    got = K.unpack_dense_segments(jnp.asarray(words), *key,
+                                  jnp.asarray(starts), jnp.asarray(counts),
+                                  cap)
+    assert got.shape == (cap,)
+    assert np.array_equal(np.asarray(got)[:seen], np.concatenate(want))
+    general = K.expand_runs(jnp.asarray(dd._pad_runs(runs)),
+                            jnp.asarray(dd._pad_bytes(parts)), cap)
+    assert np.array_equal(np.asarray(general)[:seen],
+                          np.asarray(got)[:seen].astype(np.int64))
+
+
+@pytest.mark.parametrize("counts", [(1001, 1003, 999, 520), (900, 1010, 1015),
+                                    (515, 77, 1020, 9)])
+def test_dense_key_holds_no_page_counts(counts):
+    """Page structures that differ only in where their pages end — all
+    inside a group of 8 — share one program key: the widths, a bucket of
+    the segment count, one slot bucket. Where the pages end is data."""
+    rng = np.random.default_rng(sum(counts))
+    vals = [rng.integers(0, 1 << 7, n, dtype=np.uint64) for n in counts]
+    runs, parts, n = _walk_pages([(v, _literal_page(v, 7)) for v in vals], 7)
+    key, starts, counts_, _ = dd._literal_segments(runs, parts, n, 4096)
+    assert key == (((7, 4),), 1024)
+    assert sorted(counts_[counts_ > 0].tolist()) == sorted(counts)
+    assert sorted(starts[counts_ > 0].tolist()) == \
+        np.cumsum((0,) + counts[:-1]).tolist()
+
+
+def _index_table(n, card, dtype=pa.int32()):
+    """Values whose dictionary has `card` entries and never repeats back to
+    back, so parquet-cpp writes bit-packed literal runs only."""
+    v = pa.array((np.arange(n, dtype=np.int64) * 7919) % card + 1000).cast(dtype)
+    return pa.Table.from_arrays([v], schema=pa.schema(
+        [pa.field("v", dtype, nullable=False)]))
+
+
+@pytest.mark.parametrize("width,n,pages,version", [
+    (1, 1003, "one", "1.0"), (2, 4099, "many", "2.0"),
+    (3, 515, "one", "2.0"), (4, 5000, "many", "1.0"),
+    (5, 2051, "many", "2.0"), (6, 70003, "many", "1.0"),
+    (7, 1021, "one", "1.0"), (8, 9001, "many", "2.0"),
+    (9, 20011, "many", "1.0"), (10, 3001, "many", "2.0"),
+    (11, 2500, "one", "1.0"), (12, 70001, "one", "2.0"),
+    (13, 9999, "one", "1.0"), (14, 20003, "one", "2.0"),
+    (15, 40003, "one", "1.0"), (16, 70003, "one", "2.0"),
+    (17, 131111, "one", "1.0"),
+])
+def test_dense_dictionary_index_oracle(tmp_path, width, n, pages, version):
+    """All-literal, one-width dictionary index streams from a real writer,
+    bit for bit against pyarrow: one page and many, data page v1 and v2,
+    row counts that are no multiple of 8 or of a run's 504 values."""
+    card = (1 << (width - 1)) + 1
+    kw = {"data_page_size": 1 << 26} if pages == "one" \
+        else {"data_page_size": 512}      # the dictionary fills in page one
+    p = _write(tmp_path, _index_table(n, card), compression="snappy",
+               data_page_version=version, dictionary_pagesize_limit=1 << 22,
+               **kw)
+    _assert_tables_equal(_device_read(p), pq.read_table(p))
+    (spec,) = _program_specs()
+    assert spec[0] == "dict" and spec[8] is None
+    assert spec[6][0] == "dense"
+    # (a big dictionary is cut into several pages whatever the page size:
+    # the widths then grow along the chunk, one segment each)
+    widths = [w for w, _ in spec[6][1]]
+    assert widths[-1] == width and widths == sorted(set(widths))
+    assert widths == [width] or width >= 14
+    st = dd.decode_stats()
+    assert (st["values"], st["dense_values"]) == (n, n)
+    assert st["fallback_columns"] == 0
+
+
+@pytest.mark.parametrize("version", ["1.0", "2.0"])
+@pytest.mark.parametrize("n", [1, 2, 7, 9, 16, 17, 31, 33])
+def test_dense_tiny_row_groups(tmp_path, n, version):
+    """Row groups whose output bucket is smaller than a group of 32 slots
+    (a short trailing row group of a REQUIRED dictionary column): the dense
+    unpack works on at least 32 slots and the bucket is cut from it."""
+    t = pa.Table.from_arrays(
+        [pa.array(np.arange(n, dtype=np.int32) * 3 + 5),
+         pa.array(np.arange(n, dtype=np.float64) / 4 - 1)],
+        schema=pa.schema([pa.field("v", pa.int32(), nullable=False),
+                          pa.field("x", pa.float64(), nullable=False)]))
+    p = _write(tmp_path, t, compression="snappy", data_page_version=version)
+    _assert_tables_equal(_device_read(p), pq.read_table(p))
+    specs = _program_specs()
+    assert [sp[0] for sp in specs] == ["dict", "dict"]
+    st = dd.decode_stats()
+    assert st["values"] == 2 * n and st["fallback_columns"] == 0
+    if n > 1:                     # (one value: a zero-width RLE run, general)
+        assert all(sp[6][0] == "dense" and sp[6][3] == max(16, _bucket(n))
+                   for sp in specs)
+        assert st["dense_values"] == 2 * n
+
+
+@pytest.mark.parametrize("case", ["rle_run_among_literals", "nullable",
+                                  "nullable_no_nulls", "boolean", "string"])
+def test_layouts_that_stay_general(tmp_path, case):
+    """Layouts the dense path must not take: equal to pyarrow all the same,
+    and counted under `values` only."""
+    n = 5003
+    v = (np.arange(n, dtype=np.int64) * 7919) % 50
+    if case == "rle_run_among_literals":
+        v[2000:2100] = 7
+        t = pa.table({"v": pa.array(v.astype(np.int32))})
+    elif case == "nullable":
+        t = pa.table({"v": pa.array([None if i % 5 == 0 else int(x)
+                                     for i, x in enumerate(v)], pa.int32())})
+    elif case == "nullable_no_nulls":
+        t = pa.table({"v": pa.array([int(x) for x in v], pa.int32())})
+    elif case == "boolean":
+        t = pa.table({"v": pa.array(v % 2 == 0)})
+    else:
+        t = pa.table({"v": pa.array([f"s{x}" for x in v])})
+    schema = None
+    if case in ("rle_run_among_literals", "boolean", "string"):
+        schema = pa.schema([pa.field("v", t.schema.field("v").type,
+                                     nullable=False)])
+        t = t.cast(schema)
+    p = _write(tmp_path, t, compression="snappy")
+    _assert_tables_equal(_device_read(p), pq.read_table(p))
+    specs = _program_specs()
+    assert not any(sp[0] == "dict" and sp[6][0] == "dense" for sp in specs)
+    st = dd.decode_stats()
+    assert (st["values"], st["dense_values"]) == (n, 0)
+    assert st["fallback_columns"] == 0
+
+
+def _chunk_pages(path):
+    """(page type, page bytes) of the first column chunk of row group 0."""
+    cc = pq.ParquetFile(path).metadata.row_group(0).column(0)
+    start, length = dd._chunk_range(cc)
+    with open(path, "rb") as f:
+        f.seek(start)
+        chunk = f.read(length)
+    pages, pos = [], 0
+    while pos < len(chunk):
+        hdr, dpos = dd._read_struct(chunk, pos)
+        pages.append((hdr[1], chunk[pos:dpos + hdr[3]]))
+        pos = dpos + hdr[3]
+    return pages
+
+
+def _decode_spliced(path, chunk, name, dtype):
+    """Row group 0 of `path` decoded with its column chunk's bytes replaced:
+    pages of two files of the same values spliced into layouts no writer
+    option produces on demand."""
+    from types import SimpleNamespace
+
+    from spark_rapids_tpu.config import RapidsConf
+    attr = SimpleNamespace(name=name, dtype=dtype)
+    with dd.DeviceFileDecoder(path, [attr], RapidsConf({})) as dec:
+        real = dec.reader
+        dec.reader = SimpleNamespace(read=lambda start, length: chunk,
+                                     close=real.close)
+        return dec.decode_row_group(0).to_arrow().column(name)
+
+
+@pytest.mark.parametrize("nullable", [False, True], ids=["required", "nullable"])
+@pytest.mark.parametrize("version", ["1.0", "2.0"])
+@pytest.mark.parametrize("case", [
+    "switch_at_page_boundary", "switch_after_first_page",
+    "empty_dictionary_prefix", "empty_plain_tail", "interleaved",
+    "growing_dictionary_then_plain", "prefix_of_5", "prefix_of_16",
+    "prefix_of_32"])
+def test_dictionary_fallback_chunk_layouts(tmp_path, case, version, nullable):
+    """The dictionary -> PLAIN fallback chunk in every order of its pages:
+    the same values written dictionary-encoded and PLAIN with equal page
+    boundaries, then spliced. One switch point is a concatenation (prefix
+    in its own bucket, PLAIN placed by position); interleaved pages keep
+    the segment-table merge. A prefix of a few values has an output bucket
+    under the 32 slots a dense group takes."""
+    from spark_rapids_tpu.types import DoubleType
+    n, per_page = 3000, 256
+    if case.startswith("prefix_of_"):
+        per_page = int(case.rsplit("_", 1)[1])
+        n = 20 * per_page + 3
+    rng = np.random.default_rng(5)
+    if case == "growing_dictionary_then_plain":
+        vals = rng.random(n) * 1e5               # every page adds entries
+    else:
+        vals = ((np.arange(n) * 7919) % 50) / 8.0    # dictionary fills at once
+    arr = pa.array([None if nullable and i % 7 == 3 else float(x)
+                    for i, x in enumerate(vals)], pa.float64())
+    t = pa.Table.from_arrays([arr], schema=pa.schema(
+        [pa.field("x", pa.float64(), nullable=nullable)]))
+    kw = dict(compression="snappy", write_batch_size=per_page,
+              data_page_size=1, data_page_version=version)
+    dp = _write(tmp_path, t, "dict.parquet", use_dictionary=True, **kw)
+    pp = _write(tmp_path, t, "plain.parquet", use_dictionary=False, **kw)
+    dict_pages, plain_pages = _chunk_pages(dp), _chunk_pages(pp)
+    assert dict_pages[0][0] == dd._PAGE_DICT
+    head, dpages = dict_pages[0][1], [b for _, b in dict_pages[1:]]
+    ppages = [b for _, b in plain_pages]
+    k = len(dpages)
+    assert k == len(ppages) == -(-n // per_page)
+    switch = {"switch_at_page_boundary": k // 2, "switch_after_first_page": 1,
+              "empty_dictionary_prefix": 0, "empty_plain_tail": k,
+              "growing_dictionary_then_plain": 5}.get(
+                  case, 1 if case.startswith("prefix_of_") else None)
+    if case == "interleaved":
+        body = [ppages[i] if i % 3 == 1 else dpages[i] for i in range(k)]
+    else:
+        body = dpages[:switch] + ppages[switch:]
+    got = _decode_spliced(dp, head + b"".join(body), "x", DoubleType())
+    assert got.combine_chunks().equals(arr)
+    (spec,) = _program_specs()
+    st = dd.decode_stats()
+    assert st["values"] == n and st["fallback_columns"] == 0
+    if case == "empty_dictionary_prefix":
+        assert spec[0] == "plain"
+        dense = n
+    elif case == "interleaved":
+        assert spec[8][0] == "segments" and spec[6][0] == "runs"
+        assert spec[6][3] == spec[-1]            # expands over the capacity
+        dense = 0
+    elif case == "empty_plain_tail":
+        assert spec[8] is None
+        dense = n
+    else:
+        assert spec[8] == ("tail",)
+        assert spec[6][3] < spec[-1]             # the prefix's own bucket
+        assert spec[6][3] == max(16, _bucket(switch * per_page))
+        dense = n
+    if nullable:
+        # definition levels are a per-element run lookup of their own
+        assert spec[0] == "plain" or spec[6][0] == "runs"
+        dense = 0
+    elif case == "growing_dictionary_then_plain":
+        assert spec[6][0] == "dense" and len(spec[6][1]) > 1   # widths grow
+    assert st["dense_values"] == dense
+
+
+def test_writer_made_fallback_chunk_oracle(tmp_path):
+    """parquet-cpp's own dictionary overflow (dictionary_pagesize_limit):
+    dictionary pages, then PLAIN pages, in one chunk — beside an ordinary
+    dictionary column and a PLAIN one in the same program."""
+    n = 9001
+    rng = np.random.default_rng(9)
+    t = pa.table({"x": pa.array(rng.random(n) * 1e5),
+                  "q": pa.array(((np.arange(n) * 7919) % 50).astype(np.int32)),
+                  "k": pa.array(rng.integers(-2**62, 2**62, n))})
+    t = t.cast(pa.schema([pa.field(c, t.schema.field(c).type, nullable=False)
+                          for c in t.column_names]))
+    p = _write(tmp_path, t, compression="snappy", use_dictionary=["x", "q"],
+               dictionary_pagesize_limit=20000, data_page_size=1000,
+               write_batch_size=100)
+    _assert_tables_equal(_device_read(
+        p, {"spark.rapids.tpu.parquet.deviceDecode.verify": "true"}),
+        pq.read_table(p))
+    x, q, k = _program_specs()
+    assert x[8] == ("tail",) and k[0] == "plain"
+    # q's pages end inside a group of 8, so each opens a segment: in the
+    # key they are one width, a bucket of their number and one slot bucket
+    assert q[6][:3] == ("dense", ((6, 16),), 1024)
+    assert x[6][0] == "dense"
+    st = dd.decode_stats()
+    assert st["values"] == 3 * n and st["fallback_columns"] == 0
+    assert st["dense_values"] == 3 * n
+
+
+def _gathers(jaxpr, out):
+    """(output elements, operand shape) of every gather of a jaxpr, nested
+    calls and loop bodies included (a loop body counts once)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            out.append((int(np.prod(eqn.outvars[0].aval.shape)),
+                        tuple(eqn.invars[0].aval.shape)))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _gathers(inner, out)
+    return out
+
+
+def test_cell_file_program_is_gather_free(tmp_path, monkeypatch):
+    """Structural guard on the benchmark cell's own layout (one 2^20-row
+    group of chipbench's file, the four columns Q6 reads): the decode
+    program holds one 2^20-element gather per dictionary column — the
+    dictionary lookup — and none over a run table, so a later refactor
+    cannot bring the 112 gathers an element back unnoticed (PERF.md PR 25).
+    A count made on the CPU, not a timing."""
+    import jax
+    from chipbench import datagen
+    rows = 1 << 20
+    path = str(tmp_path / "cell.parquet")
+    datagen.write_parquet(path, 25, rows, keep=())
+    captured = []
+    build = dd._build_program
+
+    def recording(specs):
+        fn = build(specs)
+
+        def call(*args):
+            captured.append((specs, fn, args))
+            return fn(*args)
+        return call
+    monkeypatch.setattr(dd, "_build_program", recording)
+    cols = ["l_quantity", "l_extendedprice", "l_discount", "l_shipdate"]
+    got = TpuSession({}).read.parquet(path).select(*cols).to_arrow()
+    _assert_tables_equal(got, pq.read_table(path, columns=cols))
+    (specs, fn, args), = captured
+    by_col = dict(zip(cols, specs))
+    for c in ("l_quantity", "l_shipdate"):       # all-literal, one width
+        assert by_col[c][6][0] == "dense" and len(by_col[c][6][1]) == 1
+        assert by_col[c][8] is None
+    for c in ("l_extendedprice", "l_discount"):  # dictionary, then PLAIN
+        assert by_col[c][8] == ("tail",) and by_col[c][6][0] == "dense"
+        assert by_col[c][6][3] == 1 << 18        # 131,088 values' bucket
+    gathers = _gathers(jax.make_jaxpr(fn)(*args).jaxpr, [])
+    full = [g for g in gathers if g[0] >= rows]
+    assert len(full) == 2, gathers               # quantity, shipdate
+    assert sum(n for n, _ in gathers) <= 2.5 * rows + 1024, gathers
+    assert not any(len(shape) == 2 for _, shape in gathers), gathers
+    st = dd.decode_stats()
+    assert (st["values"], st["dense_values"]) == (4 * rows, 4 * rows)
+
+
+@pytest.mark.parametrize("case", ["parent_without_counters",
+                                  "nothing_decoded", "partly_dense",
+                                  "all_dense", "decoded_from_a_real_scan"])
+def test_benchmark_dense_share_reader(tmp_path, case):
+    """chipbench's reader of `scan_dense_decode_share` on recorded
+    counters: a program without them, or a window that decoded no value,
+    reads as nothing (the metric is left out), never as 0 %."""
+    import json
+    from types import SimpleNamespace
+
+    from chipbench import manifest
+    from chipbench.readers import decode_value_share
+    spec = json.load(open(os.path.join(
+        manifest.HERE, "metrics", "scan_dense_decode_share.json")))
+    assert spec["reader"] == "decode_value_share"
+    old = {"dispatches": 3, "rows": 30, "fallback_columns": 0}
+    before = dict(old, values=120, dense_values=100)
+    if case == "parent_without_counters":
+        ctx = SimpleNamespace(before={"decode": old},
+                              after={"decode": dict(old, dispatches=9)})
+        want = None
+    elif case == "nothing_decoded":
+        ctx = SimpleNamespace(before={"decode": before},
+                              after={"decode": dict(before)})
+        want = None
+    elif case == "partly_dense":
+        ctx = SimpleNamespace(before={"decode": before}, after={"decode": dict(
+            before, values=120 + 4000, dense_values=100 + 3750)})
+        want = 93.75
+    elif case == "all_dense":
+        ctx = SimpleNamespace(before={"decode": before}, after={"decode": dict(
+            before, values=120 + 4000, dense_values=100 + 4000)})
+        want = 100.0
+    else:
+        first = dd.decode_stats()
+        n = 4099
+        p = _write(tmp_path, _index_table(n, 50).append_column(
+            "nullable", pa.array([None if i % 3 == 0 else i
+                                  for i in range(n)], pa.int64())))
+        _assert_tables_equal(_device_read(p), pq.read_table(p))
+        ctx = SimpleNamespace(before={"decode": first},
+                              after={"decode": dd.decode_stats()})
+        want = 50.0
+    got = decode_value_share.read(ctx, **spec["args"])
+    assert got is None if want is None else got == pytest.approx(want)
+
+
+# ---------------------------------------------------------------------------
 # tracelint: the new kernels classify device-clean
 # ---------------------------------------------------------------------------
 
