@@ -9,10 +9,13 @@ query needs and the chip's peaks (roofline.py, peaks.json). engine.py is the
 only module that touches the program under test.
 
 Adding to the benchmark needs new files and new BENCHMARK.json entries only:
-  a configuration   configs/<name>.json   (+ an entry under "configs")
-  a traffic mix     traffic/<name>.json   (read by loadgen.py)
+  a configuration   configs/<name>.json   (+ an entry under "configs"); its
+                    `tables` say what it holds: rows, column generators,
+                    storage (manifest.py, datagen.py)
+  a traffic mix     traffic/<name>.json   (read by loadgen.py); a template
+                    entry may carry `params`, one entry a parameter set
   a cell            an entry under "workloads" naming a config and a mix
-  a query template  queries/<name>.py     (COLUMNS, build, reference)
+  a query template  queries/<name>.py     (COLUMNS by table, build, reference)
   a metric          metrics/<name>.json   {"reader": ..., "args": {...}}
   a reader          readers/<name>.py     read(ctx, **args) -> number or None
 

@@ -43,33 +43,51 @@ def compare_rows(got, want) -> dict:
     return {"max_rel_err": worst, "inexact": inexact}
 
 
-def lower_precision(cols: dict) -> dict:
-    """The control's input: every DOUBLE column handed over as FLOAT (rounded to
-    float32), the step below what the configurations state."""
-    return {k: (v.astype(np.float32).astype(np.float64) if v.dtype == np.float64 else v)
-            for k, v in cols.items()}
+def _doubles(tables: dict, fn) -> dict:
+    return {t: {k: (fn(v) if v.dtype == np.float64 else v) for k, v in cols.items()}
+            for t, cols in tables.items()}
 
 
-def all_float32(cols: dict) -> dict:
+def lower_precision(tables: dict) -> dict:
+    """The control's input: every DOUBLE column of every table handed over as
+    FLOAT (rounded to float32), the step below what the configurations state."""
+    return _doubles(tables, lambda v: v.astype(np.float32).astype(np.float64))
+
+
+def all_float32(tables: dict) -> dict:
     """A harsher control: the columns stay float32, so the reference's own
     products and sums are float32 too."""
-    return {k: (v.astype(np.float32) if v.dtype == np.float64 else v)
-            for k, v in cols.items()}
+    return _doubles(tables, lambda v: v.astype(np.float32))
+
+
+def reference_columns(cell, seed: int, rows: int = 0) -> dict:
+    """tenant -> table -> the numpy columns the cell's templates read, made
+    from the seed alone: what a run of the cell keeps for its reference."""
+    from . import datagen
+    n_t = int(cell.traffic.get("tenants", 1))
+    out = {t: {} for t in range(n_t)}
+    for name, table in datagen.tables(cell.config, rows).items():
+        needed = cell.columns_read().get(name, ())
+        for t, (lo, hi) in enumerate(datagen.tenant_slices(table.rows, n_t)):
+            out[t][name] = table.kept(table.generate(seed, needed, lo, hi), needed)
+    return out
 
 
 class Checker:
-    """Caches one reference per (tenant, template); compares every record."""
+    """Caches one reference per (tenant, template), a parameter set being a
+    template entry of its own; compares every record."""
 
     def __init__(self, cell, tenant_columns):
         self.cell = cell
-        self.tenant_columns = tenant_columns       # tenant -> numpy columns
+        self.tenant_columns = tenant_columns       # tenant -> table -> numpy columns
         self._ref = {}
 
     def reference(self, tenant: int, template: int):
         key = (tenant, template)
         if key not in self._ref:
-            q = self.cell.query(self.cell.traffic["templates"][template]["query"])
-            self._ref[key] = q.reference(self.tenant_columns[tenant])
+            tpl = self.cell.traffic["templates"][template]
+            self._ref[key] = self.cell.query(tpl["query"]).reference(
+                self.tenant_columns[tenant], **tpl.get("params", {}))
         return self._ref[key]
 
     def check(self, records, extra: dict) -> dict:

@@ -11,7 +11,7 @@ import io
 import os
 from typing import Dict, List
 
-from . import datagen
+from . import datagen, roofline
 
 
 def configure_jax(root: str) -> str:
@@ -67,6 +67,8 @@ def counters() -> dict:
     from spark_rapids_tpu.io import device_decode
     from spark_rapids_tpu.obs.metrics import MetricsRegistry
     from spark_rapids_tpu.profiling import SyncLedger
+    from spark_rapids_tpu.serving.scheduler import QueryScheduler
+    sched = QueryScheduler.peek()
     plan = MetricsRegistry.get().snapshot()["histograms"].get("plan.build_ms", {})
     return {
         "syncs": SyncLedger.get().total(),
@@ -74,28 +76,32 @@ def counters() -> dict:
         "plan_ms": sum(h["sum"] for h in plan.values()),
         "plan_count": sum(h["count"] for h in plan.values()),
         "decode": device_decode.decode_stats(),
+        "plan_cache": sched.plan_cache.stats() if sched is not None else {},
     }
 
 
-def quiet_explain(df) -> str:
+def plan_text(df) -> str:
+    """The physical plan of `df.explain()`, which prints as well as returns."""
     with contextlib.redirect_stdout(io.StringIO()):
-        return df.explain()
+        return df.explain().split("== Physical Plan ==", 1)[-1].strip()
 
 
-def host_operators(df) -> List[str]:
-    """Lines of the physical plan that name a host operator."""
-    plan = quiet_explain(df).split("== Physical Plan ==", 1)[-1]
+def host_operators(plan: str) -> List[str]:
+    """Lines of a physical plan that name a host operator."""
     return [ln.strip() for ln in plan.splitlines() if "Cpu" in ln and "Exec" in ln]
 
 
 class Tenant:
-    """One tenant: its own session over its own slice of the table."""
+    """One tenant: its own session over its own slice of every table."""
 
-    def __init__(self, index: int, session, table, columns: dict):
+    def __init__(self, index: int, session):
         self.index = index
         self.session = session
-        self.table = table
-        self.columns = columns          # the slice as numpy, for the reference
+        self.tables: Dict[str, object] = {}         # table -> DataFrame
+        self.rows: Dict[str, int] = {}              # table -> rows of this tenant's slice
+        self.columns: Dict[str, dict] = {}          # table -> numpy columns, for the reference
+        self.files: Dict[str, str] = {}             # table -> its Parquet file
+        self.metadata: Dict[str, object] = {}       # table -> that file's FileMetaData
         self.frames: Dict[int, object] = {}
 
 
@@ -104,51 +110,64 @@ class Deployment:
     `send` the load generator drives."""
 
     def __init__(self, cell, seed: int, rows: int, data_dir: str, say):
+        import pyarrow.parquet as pq
         from spark_rapids_tpu.session import TpuSession
         import spark_rapids_tpu.functions as F
         self.F = F
         self.cell = cell
-        self.rows = rows
         self.tenants: List[Tenant] = []
-        self.files: List[str] = []
         self._stopped = False
         conf = cell.config
         traffic = cell.traffic
         n_t = int(traffic.get("tenants", 1))
+        self.schema = datagen.tables(conf, rows)
         self.templates = [cell.query(t["query"]) for t in traffic["templates"]]
+        self.params = [t.get("params", {}) for t in traffic["templates"]]
         self.classes = [t.get("class") for t in traffic["templates"]]
-        needed = sorted({c for q in self.templates for c in q.COLUMNS})
-        for t, (lo, hi) in enumerate(datagen.tenant_slices(rows, n_t)):
-            session = TpuSession(dict(conf["session_conf"]))
-            if conf["storage"] == "parquet":
-                path = os.path.join(data_dir, f"{cell.name}.{seed}.{t}.parquet")
-                cols = datagen.write_parquet(path, seed, rows, lo, hi, keep=needed)
-                self.files.append(path)
-                table = session.read.parquet(path)
-            elif conf["storage"] == "resident":
-                cols = datagen.generate(seed, rows, conf["columns"], lo, hi)
-                table = session.createDataFrame(datagen.to_arrow(cols)).device_cache()
-                cols = {k: cols[k] for k in needed}
-            else:
-                raise ValueError(f"unknown storage {conf['storage']!r}")
-            self.tenants.append(Tenant(t, session, table, cols))
-            say(f"tenant {t}: rows [{lo}, {hi}) {conf['storage']}")
+        needed = cell.columns_read()
+        for t in range(n_t):
+            ten = Tenant(t, TpuSession(dict(conf["session_conf"])))
+            self.tenants.append(ten)
+            for name, table in self.schema.items():
+                lo, hi = datagen.tenant_slices(table.rows, n_t)[t]
+                ten.rows[name] = hi - lo
+                if table.storage == "parquet":
+                    path = os.path.join(data_dir, f"{cell.name}.{seed}.{t}.{name}.parquet")
+                    ten.files[name] = path
+                    ten.columns[name] = table.write_parquet(path, seed, lo, hi,
+                                                            keep=needed.get(name, ()))
+                    ten.metadata[name] = pq.read_metadata(path)
+                    ten.tables[name] = ten.session.read.parquet(path)
+                elif table.storage == "resident":
+                    cols = table.generate(seed, table.cached, lo, hi)
+                    ten.tables[name] = ten.session.createDataFrame(
+                        table.to_arrow(cols)).device_cache()
+                    ten.columns[name] = table.kept(cols, needed.get(name, ()))
+                else:
+                    raise ValueError(f"table {name}: unknown storage {table.storage!r}")
+                say(f"tenant {t}: {name} rows [{lo}, {hi}) {table.storage}")
         for ten in self.tenants:
             for q, mod in enumerate(self.templates):
-                ten.frames[q] = mod.build(F, ten.table)
+                ten.frames[q] = mod.build(F, ten.tables, **self.params[q])
 
-    def tenant_rows(self, tenant: int) -> int:
-        return len(next(iter(self.tenants[tenant].columns.values())))
+    def query_rows(self, tenant: int, template: int) -> int:
+        """Input rows of one query: the tenant's rows of every table it reads."""
+        return sum(self.tenants[tenant].rows[name]
+                   for name in self.templates[template].COLUMNS)
 
-    def plans_on_device(self) -> List[str]:
-        bad = []
-        for q, _ in enumerate(self.templates):
-            bad += host_operators(self.tenants[0].frames[q])
-        return bad
+    def query_bytes(self, tenant: int, template: int) -> int:
+        """Bytes the question needs (roofline.py), over the tables it reads."""
+        ten, total = self.tenants[tenant], 0
+        for name, columns in self.templates[template].COLUMNS.items():
+            if name in ten.metadata:
+                total += roofline.parquet_bytes(ten.metadata[name], columns)
+            else:
+                total += roofline.resident_bytes(self.schema[name], columns, ten.rows[name])
+        return total
 
-    def plan_text(self, template: int) -> str:
-        return quiet_explain(self.tenants[0].frames[template]) \
-            .split("== Physical Plan ==", 1)[-1].strip()
+    def plans(self) -> List[str]:
+        """The physical plan of every template entry, explained once each."""
+        return [plan_text(self.tenants[0].frames[q]) for q in range(len(self.templates))]
 
     def send(self, rec, detail: bool = False) -> None:
         """One query through DataFrame.collect(): session -> scheduler ->
@@ -174,11 +193,11 @@ class Deployment:
         self._stopped = True
         for ten in self.tenants:
             ten.frames.clear()
-            ten.table = None
+            ten.tables.clear()
             ten.session.stop()
-        for path in self.files:
-            with contextlib.suppress(OSError):
-                os.remove(path)
+            for path in ten.files.values():
+                with contextlib.suppress(OSError):
+                    os.remove(path)
 
 
 def scan_times(session) -> dict:
@@ -193,8 +212,7 @@ def scan_times(session) -> dict:
 
 def trace_annotations(on: bool) -> None:
     """Make the program emit its per-operator `TraceAnnotation`s
-    (`profiling.trace_scope`) while the benchmark's own profiler session runs.
-    The program exposes the switch only through `TpuProfiler.start()`, which
-    starts a trace with the Python tracer on; PERF.md lists that for a later PR."""
+    (`profiling.trace_scope`) and `srt.<phase>` spans while the benchmark's
+    own profiler session runs."""
     from spark_rapids_tpu import profiling
-    profiling._PROFILING_ACTIVE = bool(on)
+    profiling.set_trace_annotations(on)

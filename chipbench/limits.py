@@ -19,24 +19,21 @@ import json
 import os
 import sys
 
-from . import check, datagen, manifest
+from . import check, manifest
 
 
 def control_readings(cell, seed: int, rows: int) -> dict:
     """Worst gap of each control against the float64 reference, over every
     (tenant, template) of the cell, on the columns the run of `seed` had."""
     out = {"f32_inputs": 0.0, "all_f32": 0.0}
-    needed = sorted({c for t in cell.traffic["templates"]
-                     for c in cell.query(t["query"]).COLUMNS})
-    for lo, hi in datagen.tenant_slices(rows, int(cell.traffic.get("tenants", 1))):
-        cols = datagen.generate(seed, rows, needed, lo, hi)
+    for cols in check.reference_columns(cell, seed, rows).values():
         for tpl in cell.traffic["templates"]:
-            q = cell.query(tpl["query"])
-            want = q.reference(cols)
+            q, params = cell.query(tpl["query"]), tpl.get("params", {})
+            want = q.reference(cols, **params)
             for name, fn in (("f32_inputs", check.lower_precision),
                              ("all_f32", check.all_float32)):
                 got = [{k: (float(v) if hasattr(v, "dtype") else v) for k, v in r.items()}
-                       for r in q.reference(fn(cols))]
+                       for r in q.reference(fn(cols), **params)]
                 c = check.compare_rows(got, want)
                 out[name] = max(out[name], c["max_rel_err"])
                 out.setdefault(name + "_inexact", 0)
@@ -58,7 +55,6 @@ def main(argv=None) -> int:
     import jax
     print(f"device {jax.devices()[0].platform} {jax.devices()[0].device_kind}", flush=True)
     cell = manifest.Cell(args.workload)
-    rows = int(args.rows or cell.config["rows"])
     rows_out = []
     for i in range(args.seeds):
         seed = args.first_seed + 7919 * i
@@ -68,7 +64,7 @@ def main(argv=None) -> int:
                "attempted": res["attempted"], "failed": res["failed"],
                **{k: v["value"] for k, v in res["checks"].items()}}
         if i < args.controls:
-            row["control"] = control_readings(cell, seed, rows)
+            row["control"] = control_readings(cell, seed, args.rows)
         rows_out.append(row)
         print("READING " + json.dumps(row), flush=True)
     lower = max(r["double_max_rel_err"] for r in rows_out)

@@ -24,7 +24,7 @@ from typing import Any, Callable, List, Optional
 
 import numpy as np
 
-from .stats import apportion
+from .stats import apportion, percentile
 
 
 @dataclass
@@ -143,6 +143,29 @@ def run_open(traffic: dict, seconds: float, seed: int, send: Send,
     _run_threads([threading.Thread(target=tenant, args=(recs,), name=f"tenant-{t}")
                   for t, recs in enumerate(per_tenant) if recs])
     return schedule
+
+
+def open_summary(records: List[Record], wall: float) -> dict:
+    """What an open-loop window looked like from the generator's side, for the
+    log: latency from due time at several quantiles, and for each tenant its
+    queries, the share of the window its one connection was busy, its own p95
+    and how many of the slowest twentieth of all queries were its."""
+    ok = [r for r in records if not r.failed]
+    lat = sorted((r.done - r.due) * 1e3 for r in ok)
+    if not lat:
+        return {}
+    cut = percentile(lat, 0.95)
+    out = {"mean_ms": sum(lat) / len(lat), "max_ms": lat[-1], "tenants": []}
+    for q in (0.5, 0.75, 0.9, 0.95, 0.99):
+        out[f"p{int(q * 100)}_ms"] = percentile(lat, q)
+    for t in sorted({r.tenant for r in ok}):
+        mine = [r for r in ok if r.tenant == t]
+        out["tenants"].append({
+            "n": len(mine), "busy": sum(r.done - r.sent for r in mine) / wall,
+            "p95_ms": percentile([(r.done - r.due) * 1e3 for r in mine], 0.95),
+            "behind_max_ms": max((r.sent - r.due) * 1e3 for r in mine),
+            "in_tail": sum((r.done - r.due) * 1e3 >= cut for r in mine)})
+    return out
 
 
 def _run_threads(threads) -> None:

@@ -3,7 +3,25 @@
 (`traffic/<traffic>.json`), its query templates (`queries/<name>.py`), its
 metrics (`metrics/<name>.json`) and their readers (`readers/<reader>.py`).
 A later PR adds a cell, a mix, a template or a metric as new files and new
-`BENCHMARK.json` entries; no file here names one."""
+`BENCHMARK.json` entries; no file here names one.
+
+Configuration file keys the harness reads (everything else is for the reader):
+  table, rows    the main table and its rows (`--rows` of a rehearsal replaces them)
+  session_conf   the confs every tenant's session is made with
+  storage, columns   without `tables`: PR 23's one LINEITEM, "parquet" (all 14
+                 columns, PR 23's writer) or "resident" (the `columns` cached)
+  tables         {name: {"rows": n | {"of": table, "ratio": [a, b]},
+                         "columns": {column: generator spec (datagen.py)},
+                         "storage": "parquet" | "resident"}}
+                 A Parquet table is written as PR 23's file is (snappy, REQUIRED,
+                 dictionary on, 2^20-row groups); a resident one goes through
+                 createDataFrame(...).device_cache(). With `tables`, the traffic
+                 may have one tenant only.
+Traffic file keys: loadgen.py; a `templates` entry may carry `params`, the
+keyword arguments its template's `build` and `reference` both receive.
+A template (`queries/<name>.py`): COLUMNS {table: columns it reads},
+build(F, {table: DataFrame}, **params), reference({table: {column: numpy}},
+**params)."""
 
 from __future__ import annotations
 
@@ -68,6 +86,33 @@ class Cell:
     def query(self, name: str):
         return importlib.import_module(f"chipbench.queries.{name}")
 
+    def columns_read(self) -> dict:
+        """{table: sorted columns} over every template of the traffic mix."""
+        out = {}
+        for tpl in self.traffic["templates"]:
+            for table, columns in self.query(tpl["query"]).COLUMNS.items():
+                out[table] = sorted(set(out.get(table, ())) | set(columns))
+        return out
+
+
+def _deployment_faults(cell: Cell) -> list:
+    """Faults of a cell's configuration against its traffic: tables that
+    cannot be generated, columns a template reads and no table has, tenants
+    over a multi-table configuration (no rule slices a dimension table)."""
+    from . import datagen
+    bad = []
+    schema = datagen.tables(cell.config)
+    if len(schema) > 1 and int(cell.traffic.get("tenants", 1)) > 1:
+        bad.append(f"workload {cell.name}: {len(schema)} tables and more than one tenant")
+    for tpl in cell.traffic["templates"]:
+        for table, columns in cell.query(tpl["query"]).COLUMNS.items():
+            held = schema[table].held() if table in schema else ()
+            missing = [c for c in columns if c not in held]
+            if missing:
+                bad.append(f"workload {cell.name}: template {tpl['query']} reads "
+                           f"{table}.{missing}, which the configuration does not hold")
+    return bad
+
 
 def validate(root: str = ROOT) -> list:
     """Faults of the manifest against the names, units and files the contract
@@ -97,6 +142,11 @@ def validate(root: str = ROOT) -> list:
             bad.append(f"workload {w['name']}: why is over 200 characters")
         if not os.path.isfile(os.path.join(bench_dir, "traffic", w["traffic"] + ".json")):
             bad.append(f"workload {w['name']}: no traffic file {w['traffic']}.json")
+    for w in b["workloads"]:
+        try:
+            bad += _deployment_faults(Cell(w["name"], root))
+        except (ManifestError, OSError, ValueError, KeyError) as e:
+            bad.append(f"workload {w['name']}: {type(e).__name__}: {e}")
     cells = {w["name"] for w in b["workloads"]}
     e2e = {m["name"] for m in b["end_to_end"]}
     for group in ("end_to_end", "per_layer"):

@@ -4,15 +4,15 @@ and the plain reference (`reference`)."""
 
 import numpy as np
 
-COLUMNS = ("l_shipdate", "l_returnflag", "l_linestatus", "l_quantity",
-           "l_extendedprice", "l_discount", "l_tax")
+COLUMNS = {"lineitem": ("l_shipdate", "l_returnflag", "l_linestatus", "l_quantity",
+                        "l_extendedprice", "l_discount", "l_tax")}
 SLO_CLASS = "batch"
 SHIP_MAX = 10471                         # 1998-12-01 less 90 days, as days since 1970
 
 
-def build(F, lineitem):
+def build(F, tables):
     """The DataFrame the window collects. Copied from benchmarks/tpch.py::q1."""
-    return (lineitem.filter(F.col("l_shipdate") <= SHIP_MAX)
+    return (tables["lineitem"].filter(F.col("l_shipdate") <= SHIP_MAX)
             .withColumn("disc_price",
                         F.col("l_extendedprice") * (1 - F.col("l_discount")))
             .withColumn("charge",
@@ -30,9 +30,10 @@ def build(F, lineitem):
             .sort("l_returnflag", "l_linestatus"))
 
 
-def reference(c: dict) -> list:
+def reference(tables: dict) -> list:
     """Rows of the answer, ordered by (l_returnflag, l_linestatus): float64
     element arithmetic as SQL DOUBLE prescribes, sums in extended precision."""
+    c = tables["lineitem"]
     keep = c["l_shipdate"] <= SHIP_MAX
     key = (c["l_returnflag"].view(np.uint8).astype(np.uint16) << 8) \
         | c["l_linestatus"].view(np.uint8)

@@ -8,8 +8,6 @@ from __future__ import annotations
 import json
 import os
 
-from .datagen import DEVICE_WIDTH, LINEITEM
-
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
@@ -21,10 +19,10 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def resident_bytes(columns, rows: int) -> int:
-    """Bytes a query over a device-resident table has to read: its rows times
-    the device width of each column it references, once."""
-    return rows * sum(DEVICE_WIDTH[LINEITEM[c][0]] for c in columns)
+def resident_bytes(table, columns, rows: int) -> int:
+    """Bytes a query over a device-resident table (`datagen.Table`) has to
+    read: its rows times the device width of each column it references, once."""
+    return rows * sum(table.width(c) for c in columns)
 
 
 def parquet_bytes(metadata, columns) -> int:

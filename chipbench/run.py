@@ -44,7 +44,7 @@ class Ctx:
         self.records = []            # loadgen.Record of every query of the window
         self.seconds = 0.0           # --seconds
         self.setup_s = 0.0
-        self.rows_of = {}            # tenant -> rows of its table
+        self.rows_of = {}            # (tenant, template) -> input rows of the query
         self.bytes_of = {}           # (tenant, template) -> bytes the question needs
         self.before = {}             # engine.counters() at window start
         self.after = {}              # ... once the last query has answered
@@ -126,11 +126,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
     ctx = Ctx()
     ctx.cell, ctx.seconds, ctx.device_kind = cell, float(seconds), dev.device_kind
-    rows = int(rehearsal_rows or cell.config["rows"])
     data_dir = os.path.join(root, ".chipbench_data")
     t = time.perf_counter()
-    dep = engine.Deployment(cell, seed, rows, data_dir, say)
-    say(f"data and tables: {rows} rows in {time.perf_counter() - t:.1f}s")
+    dep = engine.Deployment(cell, seed, rehearsal_rows, data_dir, say)
+    say(f"data and tables: {({n: t.rows for n, t in dep.schema.items()})} rows in "
+        f"{time.perf_counter() - t:.1f}s")
     try:
         return _measure(cell, dep, ctx, compiles, engine, seed, trace, root)
     finally:
@@ -138,23 +138,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
 
 
 def _measure(cell, dep, ctx, compiles, engine, seed, trace, root) -> dict:
-    bad = dep.plans_on_device()
-    for q, tpl in enumerate(cell.traffic["templates"]):
-        for ln in dep.plan_text(q).splitlines():
-            say(f"{tpl['query']} plan: {ln.rstrip()}")
+    bad, shown = [], set()
+    for tpl, text in zip(cell.traffic["templates"], dep.plans()):
+        bad += engine.host_operators(text)
+        if text not in shown:            # parameter sets of one template print once
+            shown.add(text)
+            for ln in text.splitlines():
+                say(f"{tpl['query']} plan: {ln.rstrip()}")
     if bad:
         raise SystemExit(f"chipbench: host operators in the plan, the cell "
                          f"measures the device path or nothing: {bad}")
     for ten in dep.tenants:
-        ctx.rows_of[ten.index] = dep.tenant_rows(ten.index)
-        for q, mod in enumerate(dep.templates):
-            if cell.config["storage"] == "parquet":
-                import pyarrow.parquet as pq
-                md = pq.ParquetFile(dep.files[ten.index]).metadata
-                ctx.bytes_of[(ten.index, q)] = roofline.parquet_bytes(md, mod.COLUMNS)
-            else:
-                ctx.bytes_of[(ten.index, q)] = roofline.resident_bytes(
-                    mod.COLUMNS, ctx.rows_of[ten.index])
+        for q in range(len(dep.templates)):
+            ctx.rows_of[(ten.index, q)] = dep.query_rows(ten.index, q)
+            ctx.bytes_of[(ten.index, q)] = dep.query_bytes(ten.index, q)
 
     # warm-up: every (tenant, template) the window can send, twice — the first
     # compiles or loads the programs, the second must be a plan-cache hit
@@ -225,6 +222,8 @@ def _measure(cell, dep, ctx, compiles, engine, seed, trace, root) -> dict:
     say(f"query seconds, sent to answered: min {times[0]:.4f} median "
         f"{times[len(times) // 2]:.4f} max {times[-1]:.4f}; first five in send order "
         f"{[round(r.done - r.sent, 4) for r in ctx.records[:5]]}")
+    if traffic["loop"] == "open":
+        say(f"open loop: {json.dumps(loadgen.open_summary(ctx.records, wall))}")
     say(f"window: {len(ctx.records)} queries in {wall:.2f}s; compiles inside "
         f"{ctx.compile_window}; decode {decode}; peak_bytes_in_use "
         f"{device['memory_peak_bytes']}")

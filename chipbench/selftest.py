@@ -5,14 +5,19 @@
 The trace reduction on a small trace recorded on the chip, the roofline's byte
 functions on hand-worked shapes, percentile and open-loop arithmetic, each plain
 reference against a hand-checked table, the manifest against the allowed names,
-a dummy configuration / mix / metric / reader / template added in a temporary copy
-without editing a file, the lower-precision control failing the limit, and whole
-runs (the look for a chip skipped) with the timed path broken underneath, each of
-which must come out not correct.
+a dummy configuration / mix / metric / reader / template and the three-table star
+rehearsal (testdata/tpch-star-resident.json, Q3) added in a temporary copy without
+editing a file, the generated data and the schedules of the accepted cells against
+hashes recorded from PR 25's tree, the 80 Q6 parameter sets (traffic/q6-params.json,
+a cell added in a temporary copy as well) against the reference,
+the lower-precision control failing the limit, and whole runs (the look for a chip
+skipped) with the timed path broken underneath, each of which must come out not
+correct.
 """
 
 from __future__ import annotations
 
+import hashlib
 import json
 import math
 import os
@@ -68,8 +73,16 @@ def test_recorded_trace():
 
 def test_resident_bytes():
     from .queries import q1, q6
-    assert roofline.resident_bytes(q6.COLUMNS, 1000) == 1000 * (4 + 8 + 4 + 8)
-    assert roofline.resident_bytes(q1.COLUMNS, 10) == 10 * (4 + 1 + 1 + 4 + 8 + 8 + 8)
+    li = datagen.tables({"rows": 1000, "storage": "resident", "columns": ["l_tax"]})["lineitem"]
+    assert roofline.resident_bytes(li, q6.COLUMNS["lineitem"], 1000) == 1000 * (4 + 8 + 4 + 8)
+    assert roofline.resident_bytes(li, q1.COLUMNS["lineitem"], 10) == \
+        10 * (4 + 1 + 1 + 4 + 8 + 8 + 8)
+    star = datagen.tables(manifest._json(os.path.join(HERE, "testdata",
+                                                       "tpch-star-resident.json")))
+    # BIGINT keys 8, DATE and INT 4, CHAR(10) for the longest market segment
+    assert roofline.resident_bytes(star["orders"], ("o_orderkey", "o_custkey", "o_orderdate",
+                                                    "o_shippriority"), 3) == 3 * (8 + 8 + 4 + 4)
+    assert roofline.resident_bytes(star["customer"], ("c_mktsegment",), 2) == 2 * 10
 
 
 def test_parquet_bytes_and_peaks():
@@ -91,6 +104,102 @@ def test_parquet_bytes_and_peaks():
         except KeyError:
             continue
         raise AssertionError(f"peaks({kind!r}) should be an error")
+
+
+# --- the generator ------------------------------------------------------------
+
+def _sha(arrays) -> str:
+    m = hashlib.sha256()
+    for a in arrays:
+        m.update(np.ascontiguousarray(a).view(np.uint8).tobytes())
+    return m.hexdigest()
+
+
+def _config(name: str) -> dict:
+    return manifest._json(os.path.join(HERE, "configs", name + ".json"))
+
+
+def test_accepted_data_equals_the_parents():
+    """testdata/parent_hashes.json was recorded from PR 25's tree, before the
+    generator learnt tables: every LINEITEM column, the Parquet file (bytes and
+    column-chunk sizes) and the eight tenant slices of the resident cache, for
+    two seeds at 2.5 M rows."""
+    import pyarrow.parquet as pq
+    want = manifest._json(os.path.join(HERE, "testdata", "parent_hashes.json"))
+    rows = want["rows"]
+    for seed in want["seeds"]:
+        li = datagen.tables(_config("tpch-sf1-parquet"), rows)["lineitem"]
+        assert li.storage == "parquet" and list(li.columns) == list(datagen.LINEITEM)
+        whole = li.generate(seed, list(li.columns))
+        assert {k: _sha([v]) for k, v in whole.items()} == want["parquet"][str(seed)]["columns"]
+        with tempfile.TemporaryDirectory() as d:
+            path = os.path.join(d, "t.parquet")
+            kept = li.write_parquet(path, seed, keep=("l_tax", "l_shipmode"))
+            assert np.array_equal(kept["l_tax"], whole["l_tax"])          # streamed = whole
+            assert kept["l_shipmode"][0] == datagen.SHIPMODES[whole["l_shipmode"][0]].encode()
+            md = pq.ParquetFile(path).metadata
+            chunks = [[rg, md.row_group(rg).column(c).path_in_schema,
+                       md.row_group(rg).column(c).total_compressed_size,
+                       md.row_group(rg).column(c).total_uncompressed_size]
+                      for rg in range(md.num_row_groups) for c in range(md.num_columns)]
+            assert hashlib.sha256(json.dumps(chunks).encode()).hexdigest() == \
+                want["parquet"][str(seed)]["chunks"]
+            with open(path, "rb") as f:
+                assert hashlib.sha256(f.read()).hexdigest() == want["parquet"][str(seed)]["file"]
+        res = datagen.tables(_config("tpch-sf10-resident"), rows)["lineitem"]
+        assert res.storage == "resident" and len(res.cached) == 7
+        got = [_sha([res.generate(seed, res.cached, lo, hi)[k] for k in res.cached])
+               for lo, hi in datagen.tenant_slices(rows, 8)]
+        assert got == want["resident"][str(seed)]
+
+
+def test_accepted_schedules_equal_the_parents():
+    want = manifest._json(os.path.join(HERE, "testdata", "parent_hashes.json"))
+    for key, digest in want["traffic"].items():
+        name, seed = key.rsplit(".", 1)
+        t = manifest._json(os.path.join(HERE, "traffic", name + ".json"))
+        if t["loop"] == "closed":
+            seq = loadgen.closed_order(t, int(seed), 0)
+        else:
+            seq = [(r.tenant, r.template, round(r.due, 9))
+                   for r in loadgen.open_schedule(t, 40.0, int(seed))]
+        assert hashlib.sha256(json.dumps(seq).encode()).hexdigest() == digest, key
+
+
+def _star_config() -> dict:
+    return manifest._json(os.path.join(HERE, "testdata", "tpch-star-resident.json"))
+
+
+def test_tables_keys_and_slices():
+    """The star rehearsal's tables at 3 M lineitem rows: sizes by ratio, a
+    dense primary key, foreign keys inside their domain with a third of the
+    customers never drawn, and the same table whole or in slices."""
+    full = datagen.tables(_star_config())
+    assert [full[n].rows for n in ("customer", "orders", "lineitem")] == \
+        [1_500_000, 15_000_000, 59_986_052]
+    t = datagen.tables(_star_config(), 3_000_000)
+    cust, orders, li = t["customer"], t["orders"], t["lineitem"]
+    assert (cust.rows, orders.rows) == (75_017, 750_174)
+    o = orders.generate(11, list(orders.columns))
+    assert np.array_equal(o["o_orderkey"], np.arange(orders.rows))
+    assert o["o_orderkey"].dtype == np.int64
+    drawn = np.unique(o["o_custkey"])
+    assert 0 <= drawn[0] and drawn[-1] < cust.rows
+    assert abs((cust.rows - len(drawn)) / cust.rows - 1 / 3) < 0.001
+    assert set(np.unique(o["o_shippriority"])) == {0} and o["o_orderdate"].max() < 10441
+    parts = [li.generate(11, ["l_orderkey", "l_discount"], lo, hi)
+             for lo, hi in datagen.tenant_slices(li.rows, 3)]
+    whole = li.generate(11, ["l_orderkey", "l_discount"])
+    for k in whole:
+        assert np.array_equal(np.concatenate([p[k] for p in parts]), whole[k])
+    assert whole["l_orderkey"].min() >= 0 and whole["l_orderkey"].max() < orders.rows
+    # a lineitem ships 1..121 days after its order, whole or sliced
+    ship = li.generate(11, ["l_shipdate"], 1_000_000, 2_200_000)["l_shipdate"]
+    gap = ship - o["o_orderdate"][whole["l_orderkey"][1_000_000:2_200_000]]
+    assert (gap.min(), gap.max()) == (1, 121) and li.width("l_shipdate") == 4
+    # another table's column in the same place draws other values; another seed too
+    assert not np.array_equal(whole["l_discount"][:1000],
+                              li.generate(12, ["l_discount"], 0, 1000)["l_discount"])
 
 
 # --- arithmetic -------------------------------------------------------------
@@ -142,7 +251,7 @@ def test_gen_late_and_latency_readers():
     ctx.records = [R(0, 0, due=1.0, sent=1.001, done=1.5, free_at=0.0),
                    R(0, 1, due=1.1, sent=1.502, done=2.0, free_at=1.5),
                    R(0, 0, due=3.0, sent=3.0, done=3.2, free_at=2.0, failed=True)]
-    ctx.rows_of = {0: 1000}
+    ctx.rows_of = {(0, 0): 1000, (0, 1): 1000}
     ctx.completed = lambda: [r for r in ctx.records if not r.failed]
     assert abs(gen_late_percentile.read(ctx, q=1.0) - 2.0) < 1e-9       # 1.502 - 1.5
     # the failed query counts as the slowest seen (900 ms), never as its own 200 ms
@@ -154,7 +263,7 @@ def test_gen_late_and_latency_readers():
 # --- references -------------------------------------------------------------
 
 def tiny_table() -> dict:
-    return {
+    return {"lineitem": {
         "l_shipdate": np.array([8766, 9130, 9131, 8800, 8800, 10471, 10472], np.int32),
         "l_discount": np.array([0.05, 0.07, 0.06, 0.04, 0.06, 0.06, 0.06]),
         "l_quantity": np.array([23, 1, 1, 1, 24, 10, 10], np.int32),
@@ -162,15 +271,59 @@ def tiny_table() -> dict:
         "l_tax": np.array([0.0, 0.5, 0.0, 0.0, 0.0, 0.25, 0.0]),
         "l_returnflag": np.array([b"R", b"A", b"A", b"R", b"R", b"A", b"N"], "S1"),
         "l_linestatus": np.array([b"O", b"F", b"F", b"O", b"O", b"F", b"O"], "S1"),
-    }
+    }}
 
 
 def test_q6_reference():
     from .queries import q6
     # rows 0 and 1 pass: date in [8766, 9131), discount in [0.05, 0.07], quantity < 24
     assert q6.reference(tiny_table()) == [{"revenue": 100.0 * 0.05 + 200.0 * 0.07}]
-    empty = {k: v[:0] for k, v in tiny_table().items()}
+    empty = {"lineitem": {k: v[:0] for k, v in tiny_table()["lineitem"].items()}}
     assert q6.reference(empty) == [{"revenue": None}]
+
+
+def test_q6_literals():
+    from .queries import q6
+    assert q6.literals() == (8766, 9131, 0.05, 0.07, 24)          # PR 23's constants
+    assert q6.literals(1996, 0.09, 25) == (9496, 9862, 0.08, 0.1, 25)
+    t = tiny_table()
+    # a year earlier nothing ships; quantity 25 lets the row of quantity 24 in
+    assert q6.reference(t, year=1993, discount=0.06, quantity=24) == [{"revenue": None}]
+    assert q6.reference(t, year=1994, discount=0.06, quantity=25) == \
+        [{"revenue": 100.0 * 0.05 + 200.0 * 0.07 + 500.0 * 0.06}]
+
+
+def test_q6_parameter_sets():
+    """traffic/q6-params.json: the 80 sets of clause 2.4.6.3, each answered by
+    the program on the CPU at 2^16 rows as the reference answers it, and no two
+    alike."""
+    from . import engine
+    with tempfile.TemporaryDirectory() as d:
+        _with_q6_params(d)
+        assert manifest.validate(d) == [], manifest.validate(d)
+        cell = manifest.Cell("parquet-q6-params", d)
+    sets = _q6_sets()
+    assert len(sets) == 80 == len({json.dumps(p, sort_keys=True) for p in sets})
+    assert {p["year"] for p in sets} == set(range(1993, 1998)) \
+        and {p["quantity"] for p in sets} == {24, 25} \
+        and sorted({p["discount"] for p in sets}) == [round(0.01 * i, 2) for i in range(2, 10)]
+    assert all(t["share"] == 1.0 for t in cell.traffic["templates"])
+    order = loadgen.closed_order(cell.traffic, 5, 0)
+    assert len({q for _, q in order[:400]}) == 80
+    assert order != loadgen.closed_order(cell.traffic, 6, 0)
+    with tempfile.TemporaryDirectory() as d:
+        dep = engine.Deployment(cell, 2**31 + 17, 1 << 16, d, lambda _msg: None)
+        try:
+            records = [loadgen.Record(tenant=0, template=q, due=0.0) for q in range(80)]
+            for rec in records:
+                dep.send(rec)
+            checker = check.Checker(cell, {0: dep.tenants[0].columns})
+        finally:
+            dep.stop()
+    checks = checker.check(records, {})
+    assert check.verdict(checks) and checks["answers_compared"]["value"] == 80, checks
+    revenues = {checker.reference(0, q)[0]["revenue"] for q in range(80)}
+    assert len(revenues) == 80 and None not in revenues
 
 
 def test_q1_reference():
@@ -201,16 +354,22 @@ def test_compare_rows():
 def test_control_fails_the_limit():
     """The reference in the program's place, one precision step down (DOUBLE
     columns handed over as FLOAT), has to read above the limit: three seeds, at
-    a size a test can hold. PERF.md has the readings at the cells' own sizes."""
-    from .queries import q1, q6
+    a size a test can hold, Q6 under seven of its parameter sets. PERF.md has the
+    readings at the cells' own sizes."""
+    from .queries import q1, q3, q6
     limit = check.limits()["double_max_rel_err"]
-    cols = sorted(set(q1.COLUMNS) | set(q6.COLUMNS))
+    cols = sorted(set(q1.COLUMNS["lineitem"]) | set(q6.COLUMNS["lineitem"]))
+    li = datagen.tables({"rows": 400_000, "storage": "parquet"})["lineitem"]
+    sets = _q6_sets()
+    star = datagen.tables(_star_config(), 400_000)
     for seed in (1, 2**31 + 7, 987654321):
-        c = datagen.generate(seed, 400_000, cols)
-        for q in (q1, q6):
-            got = q.reference(check.lower_precision(c))
-            gap = check.compare_rows(got, q.reference(c))
-            assert gap["max_rel_err"] > limit, (seed, q.__name__, gap)
+        c = {"lineitem": li.generate(seed, cols)}
+        c3 = {n: star[n].kept(star[n].generate(seed, q3.COLUMNS[n]), q3.COLUMNS[n])
+              for n in q3.COLUMNS}
+        for q, tables, params in [(q1, c, {}), (q3, c3, {})] + [(q6, c, p) for p in sets[::13]]:
+            got = q.reference(check.lower_precision(tables), **params)
+            gap = check.compare_rows(got, q.reference(tables, **params))
+            assert gap["max_rel_err"] > limit, (seed, q.__name__, params, gap)
 
 
 # --- the manifest -----------------------------------------------------------
@@ -250,8 +409,8 @@ def test_add_by_files_alone():
             json.dump({"loop": "closed", "clients": 1, "tenants": 1,
                        "templates": [{"query": "dummy_q", "share": 1.0}]}, f)
         with open(os.path.join(bench, "queries", "dummy_q.py"), "w") as f:
-            f.write("COLUMNS = ('l_tax',)\n"
-                    "def build(F, t):\n    return t\n"
+            f.write("COLUMNS = {'lineitem': ('l_tax',)}\n"
+                    "def build(F, t):\n    return t['lineitem']\n"
                     "def reference(c):\n    return []\n")
         with open(os.path.join(bench, "readers", "dummy_reader.py"), "w") as f:
             f.write("def read(ctx, k):\n    return k\n")
@@ -275,6 +434,113 @@ def test_add_by_files_alone():
         p = subprocess.run([sys.executable, "-c", code], cwd=os.path.realpath(d),
                            capture_output=True, text=True, timeout=120)
         assert p.returncode == 0, p.stderr[-2000:]
+
+
+def _copy_and_add(d: str, add) -> None:
+    """In `d`, a copy of the benchmark to which `add(bench_dir, BENCHMARK.json)`
+    adds what a later PR would: new files and new entries. No file is edited."""
+    bench = os.path.join(d, "chipbench")
+    shutil.copytree(HERE, bench, ignore=shutil.ignore_patterns("__pycache__"))
+    b = manifest.benchmark()
+    add(bench, b)
+    with open(os.path.join(d, "BENCHMARK.json"), "w") as f:
+        json.dump(b, f)
+
+
+def _add_cell(b: dict, name: str, config: str, traffic: str, like: str) -> None:
+    """A cell that reports the metrics the cell `like` reports."""
+    b["workloads"].append({"name": name, "config": config, "traffic": traffic,
+                           "chips": 1, "why": "test"})
+    for group in ("end_to_end", "per_layer"):
+        for m in b[group]:
+            if like in m.get("workloads", ()):
+                m["workloads"].append(name)
+
+
+def _with_star(d: str, tenants: int = 1) -> None:
+    """The star rehearsal as a cell: a configuration file, a traffic file and
+    entries in BENCHMARK.json (queries/q3.py is already there)."""
+    def add(bench, b):
+        shutil.copy(os.path.join(HERE, "testdata", "tpch-star-resident.json"),
+                    os.path.join(bench, "configs"))
+        mix = manifest._json(os.path.join(HERE, "testdata", "q3-stream.json"))
+        mix["tenants"] = tenants
+        with open(os.path.join(bench, "traffic", "q3-stream.json"), "w") as f:
+            json.dump(mix, f)
+        b["configs"].append({"name": "tpch-star-resident", "source": _star_config()["source"],
+                             "file": "chipbench/configs/tpch-star-resident.json",
+                             "reduced": ["cached_columns"], "why": "the star join"})
+        _add_cell(b, "star-q3-stream", "tpch-star-resident", "q3-stream", "resident-q1-stream")
+    _copy_and_add(d, add)
+
+
+def _q6_sets() -> list:
+    mix = manifest._json(os.path.join(HERE, "traffic", "q6-params.json"))
+    return [t["params"] for t in mix["templates"]]
+
+
+def _with_q6_params(d: str) -> None:
+    """`parquet-q6-params` (PERF.md section 7) as the PR that admits it will add
+    it: traffic/q6-params.json and metrics/plan_cache_hit_share.json are there,
+    so entries in BENCHMARK.json are all it takes."""
+    def add(_bench, b):
+        _add_cell(b, "parquet-q6-params", "tpch-sf1-parquet", "q6-params", "parquet-q6-stream")
+        b["per_layer"].append({"name": "plan_cache_hit_share", "unit": "%", "better": "higher",
+                               "source": "program_counter", "layer": "plan + plan cache",
+                               "moves": "rows_per_s", "workloads": ["parquet-q6-params"]})
+    _copy_and_add(d, add)
+
+
+def test_star_join_by_files_alone():
+    """Three resident tables, Q3 and its reference, added as files and entries
+    alone, through a whole run at 2^16 lineitem rows on the CPU."""
+    from . import run
+    from .queries import q3
+    with tempfile.TemporaryDirectory() as d:
+        _with_star(d)
+        assert manifest.validate(d) == [], manifest.validate(d)
+        r = run.run_cell("star-q3-stream", 2**31 + 29, 0.3, trace=False,
+                         rehearsal_rows=1 << 16, root=d)
+        assert r["correct"] is True and r["attempted"] >= 1, r["checks"]
+        rows = (1 << 16) + (1 << 16) * 1_500_000 // 59_986_052 \
+            + (1 << 16) * 15_000_000 // 59_986_052
+        assert r["metrics"]["rows_per_s"]["value"] > 0
+        cell = manifest.Cell("star-q3-stream", d)
+        ref = q3.reference(check.reference_columns(cell, 2**31 + 29, 1 << 16)[0])
+        assert len(ref) == 10 and list(ref[0]) == ["l_orderkey", "revenue", "o_orderdate",
+                                                   "o_shippriority"]
+        assert [x["revenue"] for x in ref] == sorted((x["revenue"] for x in ref), reverse=True)
+        assert sum(datagen.tables(cell.config, 1 << 16)[t].rows for t in q3.COLUMNS) == rows
+
+
+def test_tenants_over_several_tables_are_refused():
+    with tempfile.TemporaryDirectory() as d:
+        _with_star(d, tenants=2)
+        faults = manifest.validate(d)
+        assert len(faults) == 1 and "more than one tenant" in faults[0], faults
+
+
+def test_q3_reference():
+    """Hand-worked: customers 0 and 2 are BUILDING; order 10 (customer 0, early)
+    has two late lineitems, order 11 (customer 1) is another segment's, order 12
+    (customer 2) is too recent, order 13 (customer 2, early) has one late and one
+    early lineitem; lineitem of key 99 has no order."""
+    from .queries import q3
+    t = {"customer": {"c_custkey": np.array([0, 1, 2]),
+                      "c_mktsegment": np.array([b"BUILDING", b"MACHINERY", b"BUILDING"])},
+         "orders": {"o_orderkey": np.array([10, 11, 12, 13]), "o_custkey": np.array([0, 1, 2, 2]),
+                    "o_orderdate": np.array([9000, 9000, 9204, 9203], np.int32),
+                    "o_shippriority": np.array([0, 0, 0, 0], np.int32)},
+         "lineitem": {"l_orderkey": np.array([10, 10, 11, 12, 13, 13, 99]),
+                      "l_extendedprice": np.array([100., 200., 300., 400., 500., 600., 700.]),
+                      "l_discount": np.array([0.5, 0.0, 0.0, 0.0, 0.1, 0.0, 0.0]),
+                      "l_shipdate": np.array([9205, 9300, 9300, 9300, 9205, 9204, 9300],
+                                             np.int32)}}
+    import datetime
+    day = lambda n: datetime.date(1970, 1, 1) + datetime.timedelta(n)  # noqa: E731
+    assert q3.reference(t) == [
+        {"l_orderkey": 13, "revenue": 450.0, "o_orderdate": day(9203), "o_shippriority": 0},
+        {"l_orderkey": 10, "revenue": 250.0, "o_orderdate": day(9000), "o_shippriority": 0}]
 
 
 def test_refuses_a_bare_directory():
@@ -305,13 +571,13 @@ def test_refuses_a_cpu_backend():
 ROWS = 150_000
 
 
-def _run(workload="resident-q1-stream", seed=2**31 + 11):
+def _run(workload="resident-q1-stream", seed=2**31 + 11, root=manifest.ROOT):
     from . import run
-    return run.run_cell(workload, seed, 0.3, trace=False, rehearsal_rows=ROWS)
+    return run.run_cell(workload, seed, 0.3, trace=False, rehearsal_rows=ROWS, root=root)
 
 
 def test_a_sound_run_is_correct():
-    for cell in ("resident-q1-stream", "parquet-q6-stream"):
+    for cell in ("resident-q1-stream", "parquet-q6-stream", "parquet-q1-stream"):
         r = _run(cell)
         assert r["correct"] is True and r["failed"] == 0 and r["attempted"] >= 1, r["checks"]
         assert list(r)[-1] == "checks" and "setup_s" in r["metrics"]
@@ -368,15 +634,31 @@ def test_a_failing_query_is_not_correct():
     _expect_incorrect(alter, "unanswered")
 
 
+def test_another_sets_literals_are_not_correct():
+    """Every parameter set answered with the literals of the set after it."""
+    from .queries import q6
+    sets = _q6_sets()
+    real = q6.build
+    q6.build = lambda F, tables, **p: real(F, tables, **sets[(sets.index(p) + 1) % len(sets)])
+    try:
+        with tempfile.TemporaryDirectory() as d:
+            _with_q6_params(d)
+            r = _run("parquet-q6-params", root=d)
+    finally:
+        q6.build = real
+    assert r["correct"] is False and r["attempted"] >= 1
+    assert r["checks"]["double_max_rel_err"]["value"] > 1e-3, r["checks"]
+
+
 def test_half_the_rows_left_out_is_not_correct():
     """The program is handed the first half of the table; the reference keeps all of it."""
-    real = datagen.to_arrow
-    datagen.to_arrow = lambda cols, required=True: real(
-        {k: v[:len(v) // 2] for k, v in cols.items()}, required)
+    real = datagen.Table.to_arrow
+    datagen.Table.to_arrow = lambda self, cols: real(
+        self, {k: v[:len(v) // 2] for k, v in cols.items()})
     try:
         r = _run()
     finally:
-        datagen.to_arrow = real
+        datagen.Table.to_arrow = real
     assert r["correct"] is False
     assert r["checks"]["inexact_values"]["value"] > 0
 

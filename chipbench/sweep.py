@@ -33,7 +33,7 @@ def main(argv=None) -> int:
     engine.configure_jax(manifest.ROOT)
     import jax
     print(f"device {jax.devices()[0].platform} {jax.devices()[0].device_kind}", flush=True)
-    dep = engine.Deployment(cell, args.seed, int(args.rows or cell.config["rows"]),
+    dep = engine.Deployment(cell, args.seed, args.rows,
                             os.path.join(manifest.ROOT, ".chipbench_data"), print)
     try:
         for _pass in (1, 2):
