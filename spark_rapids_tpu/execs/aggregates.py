@@ -1320,7 +1320,11 @@ class TpuHashAggregateExec(TpuExec):
 
     def additional_metrics(self):
         return {"sortTime": "MODERATE", "reduceTime": "MODERATE",
-                "numGroups": "DEBUG", "opFusedAggBatches": "DEBUG"}
+                "numGroups": "DEBUG", "opFusedAggBatches": "DEBUG",
+                "sortFallbacks": "DEBUG"}
+
+    def query_counters(self):
+        return [("agg.sort_fallback", self.metrics["sortFallbacks"])]
 
     def internal_do_execute_columnar(self, idx: int, ctx: TaskContext) -> Iterator:
         child = self.children[0]
@@ -1350,6 +1354,7 @@ class TpuHashAggregateExec(TpuExec):
             # key-boundary-aligned slices — the reference's sort-based
             # fallback (GpuAggregateExec.scala:757, GpuOutOfCoreSortIterator
             # reuse); no group straddles a slice so no state merge is needed
+            self.metrics["sortFallbacks"].add(1)
             yield from self._sort_fallback(batches, agg_fns, result_exprs,
                                            ctx, max_rows)
             return

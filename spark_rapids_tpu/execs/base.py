@@ -16,7 +16,7 @@ from __future__ import annotations
 import threading
 import time
 from contextlib import contextmanager
-from typing import Dict, Iterator, List, Optional, Sequence
+from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 from ..config import RapidsConf, default_conf
 from ..expressions.base import AttributeReference, EvalContext, Expression
@@ -258,6 +258,15 @@ class PhysicalPlan:
 
     def additional_metrics(self) -> Dict[str, str]:
         return {}
+
+    def query_counters(self) -> List[Tuple[str, TpuMetric]]:
+        """The counters of its query's summary this node feeds
+        (docs/observability.md "Span model"), each with the metric that
+        holds the count: a node that joins, exchanges or can fall back
+        silently names them here. `profiling.plan_query_counters` reads
+        them once the plan has run, a metric named twice (two joins over
+        one child, as a stage's fallback shares its source's) once."""
+        return []
 
     # --- execution --------------------------------------------------------
     def num_partitions(self) -> int:

@@ -18,6 +18,7 @@ from typing import Iterator, List, Optional, Sequence
 
 from ..columnar.batch import TpuColumnarBatch, concat_batches
 from ..expressions.base import AttributeReference, Expression
+from ..obs import tracer as _obs
 from .base import CpuExec, PhysicalPlan, TaskContext, TpuExec
 from .joins import CpuShuffledHashJoinExec, TpuShuffledHashJoinExec
 
@@ -47,11 +48,13 @@ class TpuBroadcastHashJoinExec(TpuShuffledHashJoinExec):
     def _build_side(self, ctx: TaskContext) -> Optional[TpuColumnarBatch]:
         with self._broadcast_lock:
             if not self._broadcast_done:
-                batches = []
-                child = self.children[1]
-                for p in range(child.num_partitions()):
-                    batches.extend(child.execute_partition(p, ctx))
-                self._broadcast_batch = concat_batches(batches) if batches else None
+                with _obs.phase("join.collect"):
+                    batches = []
+                    child = self.children[1]
+                    for p in range(child.num_partitions()):
+                        batches.extend(child.execute_partition(p, ctx))
+                    self._broadcast_batch = concat_batches(batches) \
+                        if batches else None
                 self._broadcast_done = True
             return self._broadcast_batch
 
@@ -76,8 +79,9 @@ class TpuBroadcastHashJoinExec(TpuShuffledHashJoinExec):
                                      left.capacity)
             yield TpuColumnarBatch(left.columns + nulls_r, left.num_rows, names)
             return
-        with self.metrics["joinTime"].timed():
-            yield self._join(left, right, ctx)
+        with self.metrics["joinTime"].timed(), _obs.phase("join.probe"):
+            out = self._join(left, right, ctx)
+        yield out
 
 
 class CpuBroadcastHashJoinExec(CpuShuffledHashJoinExec):

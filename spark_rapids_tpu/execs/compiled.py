@@ -727,6 +727,9 @@ class TpuCompiledAggStageExec(TpuExec):
         return {"stageTime": "MODERATE", "numGroups": "DEBUG",
                 "fallbackReruns": "DEBUG"}
 
+    def query_counters(self):
+        return [("stage.fallback_reruns", self.metrics["fallbackReruns"])]
+
     def internal_do_execute_columnar(self, idx: int,
                                      ctx: TaskContext) -> Iterator:
         from ..memory.hbm import TpuRetryOOM, TpuSplitAndRetryOOM

@@ -900,6 +900,9 @@ class TpuCompiledJoinAggStageExec(TpuExec):
         return {"stageTime": "MODERATE", "buildTime": "MODERATE",
                 "numGroups": "DEBUG", "fallbackReruns": "DEBUG"}
 
+    def query_counters(self):
+        return [("joinstage.fallback_reruns", self.metrics["fallbackReruns"])]
+
     def internal_do_execute_columnar(self, idx: int,
                                      ctx: TaskContext) -> Iterator:
         from ..memory.hbm import TpuRetryOOM, TpuSplitAndRetryOOM

@@ -80,6 +80,7 @@ from ..config import (DISPATCH_PARTITION_BATCH, OPJIT_ENABLED,
                       RapidsConf)
 from ..config import TASK_RETRY_LIMIT as _TRL
 from ..expressions.base import Expression, to_column
+from ..obs import tracer as _obs
 from .base import PhysicalPlan, TaskContext, TpuExec
 from .basic import TpuFilterExec, TpuProjectExec
 
@@ -197,7 +198,29 @@ class TpuFusedSegmentExec(TpuExec):
     def additional_metrics(self):
         return {"opFusedBatches": "DEBUG", "opFusedFallbackOps": "DEBUG",
                 "opFusedJoinBatches": "DEBUG", "opFusedGroupedBatches": "DEBUG",
-                "buildTime": "MODERATE", "numPairs": "DEBUG"}
+                "buildTime": "MODERATE", "numPairs": "DEBUG",
+                "joinOutputRows": "DEBUG"}
+
+    def query_counters(self):
+        # an absorbed aggregate's own, and the join's from the nodes the
+        # segment pulls (_stream, _collect_build): its own children, which a
+        # later pass (coalesce) wraps and the join operator's links pass
+        # over, but the broadcast operator's own build child
+        from .broadcast import TpuBroadcastHashJoinExec
+        out = [c for op in self._ops[self._has_join:]
+               for c in op.query_counters()]
+        if self._has_join:
+            join = self._ops[0]
+            build = join.children[1] \
+                if isinstance(join, TpuBroadcastHashJoinExec) \
+                else self.children[self._join_builds[0]]
+            out += [("join.rows_left",
+                     self.children[0].metrics["numOutputRows"]),
+                    ("join.rows_right", build.metrics["numOutputRows"]),
+                    ("join.rows_out", self.metrics["joinOutputRows"]),
+                    ("join.subpartitioned",
+                     join.metrics["subPartitionedJoins"])]
+        return out
 
     # --- execution --------------------------------------------------------
     def _input_partitions(self, idx: int):
@@ -226,35 +249,48 @@ class TpuFusedSegmentExec(TpuExec):
         out_attrs = self._ops[n_stream - 1].output if n_stream else None
         names = [a.name for a in out_attrs] if out_attrs else None
         join_state: dict = {}
-
-        if self._has_join:
-            delegated = self._join_delegation(idx, ctx, join_state)
-            if delegated is not None:
-                # original join operator runs the partition (oversized /
-                # untraceable builds, non-inner types kept for safety);
-                # remaining ops apply per output batch
-                for batch in delegated:
-                    with op_time.timed():
-                        out = self._apply_tail(batch, 1, n_stream, ctx)
-                    if out is not None:
-                        yield out.rename(names)
-                return
-
-        def transform(batch: TpuColumnarBatch):
-            out = self._transform(batch, ctx, join_state, n_stream)
-            return out.rename(names) if out is not None else None
-
-        for p in self._input_partitions(idx):
-            for batch in self.children[0].execute_partition(p, ctx):
-                with op_time.timed():
-                    # the streamed segment is row-wise over probe rows, so
-                    # the operator-level retry-with-split contract holds for
-                    # the fused chain (incl. the inner-join probe) too
-                    for out in with_retry(SpillableColumnarBatch(batch),
-                                          transform,
-                                          max_retries=ctx.conf.get(_TRL)):
+        # phase `segment.launch`: one lap a batch put through the chain (the
+        # absorbed join's probe included), never open across a yield, and
+        # flushed however the consumer leaves the generator. The child's pull
+        # lies outside it, and so does the fused join's build side; a
+        # delegated join's laps cover the tail alone, its own `join.collect`
+        # and `join.probe` run in the pull between them
+        laps = _obs.PhaseLaps()
+        try:
+            if self._has_join:
+                delegated = self._join_delegation(idx, ctx, join_state)
+                if delegated is not None:
+                    # original join operator runs the partition (oversized /
+                    # untraceable builds, non-inner types kept for safety);
+                    # remaining ops apply per output batch
+                    for batch in delegated:
+                        self.metrics["joinOutputRows"].add_lazy(
+                            batch.rows_lazy)
+                        with op_time.timed(), laps.lap("segment.launch"):
+                            out = self._apply_tail(batch, 1, n_stream, ctx)
                         if out is not None:
-                            yield out
+                            yield out.rename(names)
+                    return
+
+            def transform(batch: TpuColumnarBatch):
+                with laps.lap("segment.launch"):
+                    out = self._transform(batch, ctx, join_state, n_stream)
+                    return out.rename(names) if out is not None else None
+
+            for p in self._input_partitions(idx):
+                for batch in self.children[0].execute_partition(p, ctx):
+                    with op_time.timed():
+                        # the streamed segment is row-wise over probe rows,
+                        # so the operator-level retry-with-split contract
+                        # holds for the fused chain (incl. the inner-join
+                        # probe) too
+                        for out in with_retry(SpillableColumnarBatch(batch),
+                                              transform,
+                                              max_retries=ctx.conf.get(_TRL)):
+                            if out is not None:
+                                yield out
+        finally:
+            laps.flush()
 
     def _apply_tail(self, batch: TpuColumnarBatch, start: int, end: int,
                     ctx: TaskContext) -> Optional[TpuColumnarBatch]:
@@ -305,6 +341,7 @@ class TpuFusedSegmentExec(TpuExec):
                 cur = fused
                 self.metrics["opFusedJoinBatches"].add(1)
                 start = jr["end"]
+            self.metrics["joinOutputRows"].add_lazy(cur.rows_lazy)
         return self._apply_tail(cur, start, n_stream, ctx)
 
     # --- join stage -------------------------------------------------------
@@ -317,7 +354,7 @@ class TpuFusedSegmentExec(TpuExec):
             with self.metrics["buildTime"].timed():
                 return join._build_side(ctx)
         child = self.children[self._join_builds[0]]
-        with self.metrics["buildTime"].timed():
+        with self.metrics["buildTime"].timed(), _obs.phase("join.collect"):
             batches = []
             if join.per_partition:
                 batches.extend(child.execute_partition(idx, ctx))
@@ -751,17 +788,23 @@ class TpuFusedSegmentExec(TpuExec):
                         (i, seq, ctx, b))
                 else:
                     singles.append((i, seq, ctx, b))
-        with profiling.sync_scope(name), op_time.timed():
-            for lanes in pending.values():
-                pos = 0
-                while pos < len(lanes):
-                    chunk = lanes[pos:pos + group_size]
-                    pos += group_size
-                    self._run_group(chunk, results, names)
-            for i, seq, ctx, b in singles:
-                out = self._transform_single(b, ctx, names)
-                if out is not None:
-                    results[i].append((seq, out))
+        laps = _obs.PhaseLaps()  # `segment.launch`: one lap a grouped launch
+        try:
+            with profiling.sync_scope(name), op_time.timed():
+                for lanes in pending.values():
+                    pos = 0
+                    while pos < len(lanes):
+                        chunk = lanes[pos:pos + group_size]
+                        pos += group_size
+                        with laps.lap("segment.launch"):
+                            self._run_group(chunk, results, names)
+                for i, seq, ctx, b in singles:
+                    with laps.lap("segment.launch"):
+                        out = self._transform_single(b, ctx, names)
+                    if out is not None:
+                        results[i].append((seq, out))
+        finally:
+            laps.flush()
         for i in ids:
             for _, out in sorted(results[i], key=lambda so: so[0]):
                 out_rows.add_lazy(out.rows_lazy)

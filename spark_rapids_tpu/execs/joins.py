@@ -29,6 +29,7 @@ import numpy as np
 from ..columnar.batch import TpuColumnarBatch, compact, concat_batches, gather
 from ..columnar.vector import TpuColumnVector, bucket_capacity, row_mask
 from ..expressions.base import (AttributeReference, Expression, to_column)
+from ..obs import tracer as _obs
 from ..types import StringType
 from .aggregates import _sortable_bits
 from .base import (CpuExec, PhysicalPlan, TaskContext, TpuExec, bind_all,
@@ -241,16 +242,25 @@ class TpuShuffledHashJoinExec(TpuExec):
 
     def additional_metrics(self):
         return {"buildTime": "MODERATE", "joinTime": "MODERATE",
-                "numPairs": "DEBUG"}
+                "numPairs": "DEBUG", "subPartitionedJoins": "DEBUG"}
+
+    def query_counters(self):
+        rows = [n.metrics["numOutputRows"]
+                for n in (self.children[0], self.children[1], self)]
+        return [("join.rows_left", rows[0]), ("join.rows_right", rows[1]),
+                ("join.rows_out", rows[2]),
+                ("join.subpartitioned", self.metrics["subPartitionedJoins"])]
 
     def _collect_side(self, child: PhysicalPlan, ctx, idx: int) -> Optional[TpuColumnarBatch]:
-        batches = []
-        if self.per_partition:
-            batches.extend(child.execute_partition(idx, ctx))
-        else:
-            for p in range(child.num_partitions()):
-                batches.extend(child.execute_partition(p, ctx))
-        return concat_batches(batches) if batches else None
+        """Pull one input whole and concatenate it: phase `join.collect`."""
+        with _obs.phase("join.collect"):
+            batches = []
+            if self.per_partition:
+                batches.extend(child.execute_partition(idx, ctx))
+            else:
+                for p in range(child.num_partitions()):
+                    batches.extend(child.execute_partition(p, ctx))
+            return concat_batches(batches) if batches else None
 
     def _collect_sides(self, ctx, idx: int):
         """Collect both join inputs. The two sides are independent subtrees,
@@ -261,7 +271,6 @@ class TpuShuffledHashJoinExec(TpuExec):
         from ..config import SHUFFLE_PIPELINE_ENABLED
         if ctx.conf.get(SHUFFLE_PIPELINE_ENABLED):
             import threading
-            from ..obs import tracer as _obs
             res: dict = {}
             # per-query tracing routes by thread: the side-collector thread
             # inherits this query's tracer via the captured handoff token,
@@ -314,6 +323,7 @@ class TpuShuffledHashJoinExec(TpuExec):
             # GpuSubPartitionHashJoin.scala)
             from ..shuffle.partitioner import hash_split_parts
             k = max(2, -(-max(left.num_rows, right.num_rows) // max_rows))
+            self.metrics["subPartitionedJoins"].add(1)
             # seed 100 (not the exchange's 42): upstream co-partitioning fixes
             # h42 % N, so re-bucketing with the same seed would collapse into
             # few sub-partitions (GpuSubPartitionHashJoin.scala hashSeed=100).
@@ -323,14 +333,23 @@ class TpuShuffledHashJoinExec(TpuExec):
                                        metrics=self.metrics)
             r_parts = hash_split_parts(right, self.right_keys, k, ctx,
                                        seed=100, metrics=self.metrics)
-            with self.metrics["joinTime"].timed():
-                for lp, rp in zip(l_parts, r_parts):
-                    out = self._join_pair(lp, rp, names, ctx)
-                    if out is not None and out.num_rows:
-                        yield out
+            # phase `join.probe`: a pair joined outside a segment (build +
+            # probe + gather), one lap a pair; never open across a yield, and
+            # flushed however the consumer leaves the generator
+            laps = _obs.PhaseLaps()
+            try:
+                with self.metrics["joinTime"].timed():
+                    for lp, rp in zip(l_parts, r_parts):
+                        with laps.lap("join.probe"):
+                            out = self._join_pair(lp, rp, names, ctx)
+                        if out is not None and out.num_rows:
+                            yield out
+            finally:
+                laps.flush()
             return
-        with self.metrics["joinTime"].timed():
-            yield self._join(left, right, ctx)
+        with self.metrics["joinTime"].timed(), _obs.phase("join.probe"):
+            out = self._join(left, right, ctx)
+        yield out
 
     def _join_pair(self, lp, rp, names, ctx):
         """One sub-partition pair with the empty-side fast paths preserved."""

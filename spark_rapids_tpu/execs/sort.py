@@ -16,6 +16,7 @@ import numpy as np
 from ..columnar.batch import TpuColumnarBatch, concat_batches, gather
 from ..columnar.vector import TpuColumnVector
 from ..expressions.base import to_column
+from ..obs import tracer as _obs
 from ..plan.logical import SortOrder
 from ..types import StringType
 from .aggregates import _sortable_bits, lex_sort_permutation
@@ -87,31 +88,38 @@ class TpuTopNExec(TpuExec):
     def additional_metrics(self):
         return {"sortTime": "MODERATE"}
 
-    def _topn_of_partition(self, p: int, ctx: TaskContext, keep: int):
+    def _topn_of_partition(self, p: int, ctx: TaskContext, keep: int, laps):
+        from ..columnar.batch import slice_batch
         running = None
         for b in self.children[0].execute_partition(p, ctx):
-            cand = b if running is None else concat_batches([running, b])
-            with self.metrics["sortTime"].timed():
-                s = sort_batch(cand, self.order, ctx)
-            from ..columnar.batch import slice_batch
-            running = slice_batch(s, 0, min(keep, s.num_rows))
+            with laps.lap("sort.topn"):
+                cand = b if running is None else concat_batches([running, b])
+                with self.metrics["sortTime"].timed():
+                    s = sort_batch(cand, self.order, ctx)
+                running = slice_batch(s, 0, min(keep, s.num_rows))
         return running
 
     def internal_do_execute_columnar(self, idx: int, ctx: TaskContext) -> Iterator:
         from ..columnar.batch import slice_batch
         keep = self.offset + self.n
         tops = []
-        for p in range(self.children[0].num_partitions()):
-            t = self._topn_of_partition(p, ctx, keep)
-            if t is not None:
-                tops.append(t)
-        if not tops:
-            return
-        whole = concat_batches(tops)
-        with self.metrics["sortTime"].timed():
-            s = sort_batch(whole, self.order, ctx)
-        out = slice_batch(s, self.offset, self.n)
-        if out.num_rows:
+        # phase `sort.topn`: one lap a sort + slice (a batch into the running
+        # top-N, then the merge); the child's pull lies outside it
+        laps = _obs.PhaseLaps()
+        try:
+            for p in range(self.children[0].num_partitions()):
+                t = self._topn_of_partition(p, ctx, keep, laps)
+                if t is not None:
+                    tops.append(t)
+            if tops:
+                with laps.lap("sort.topn"):
+                    whole = concat_batches(tops)
+                    with self.metrics["sortTime"].timed():
+                        s = sort_batch(whole, self.order, ctx)
+                    out = slice_batch(s, self.offset, self.n)
+        finally:
+            laps.flush()
+        if tops and out.num_rows:
             yield out
 
 

@@ -300,6 +300,7 @@ def query_end(token: int, rows: Optional[int] = None,
         "cpu_ns": phases.get("query", {}).get("cpu_ns"),
         "annotated": bool(annotated or _profiling._PROFILING_ACTIVE),
         "phases": phases,
+        "counters": {} if qctx is None else qctx.counter_table(),
         "compiles": compiled.get("count", 0),
         "compile_ns": compiled.get("wall_ns", 0)}
     with _QL_LOCK:
@@ -322,8 +323,8 @@ def recent_queries(n: Optional[int] = None) -> List[Dict[str, Any]]:
     (``time.perf_counter_ns()``, absolute)``, t_begin_unix_ns``
     (``time.time_ns()``)``, admit_wait_ns, wall_ns, cpu_ns, annotated,
     phases: {name: {count, wall_ns, cpu_ns (None: not sampled),
-    child_wall_ns, cat}}, compiles, compile_ns}`` — docs/observability.md
-    "Span model"."""
+    child_wall_ns, cat}}, counters: {name: n}, compiles, compile_ns}`` —
+    docs/observability.md "Span model"."""
     with _QL_LOCK:
         out = list(_RECENT)
     return out if n is None else out[-n:] if n > 0 else []

@@ -275,6 +275,20 @@ def snapshot_plan_metrics(plan) -> Dict[str, Dict[str, tuple]]:
     return out
 
 
+def plan_query_counters(plan) -> Dict[str, int]:
+    """The plan's nodes' `query_counters`, added up; a metric counts once
+    under a name however many nodes name it. Read after
+    :func:`snapshot_plan_metrics`, which has fetched the parked counts."""
+    out: Dict[str, int] = {}
+    seen = set()
+    for node in plan.collect_nodes():
+        for name, metric in node.query_counters():
+            if (name, id(metric)) not in seen:
+                seen.add((name, id(metric)))
+                out[name] = out.get(name, 0) + metric.value
+    return out
+
+
 def metric_level_filter(snapshot: Dict[str, Dict[str, tuple]],
                         level: str) -> Dict[str, Dict[str, int]]:
     want = _LEVEL_ORDER.get(str(level).upper(), 1)

@@ -167,6 +167,11 @@ class QueryContext:
         #: Written under _mu: pipeline workers bound to this context add
         #: beside the query's own thread.
         self.phases: Dict[str, list] = {}
+        #: counters of the query: what the nodes of its plan counted in
+        #: their own metrics (PhysicalPlan.query_counters: rows through the
+        #: joins and exchanges, fallbacks taken), added once the plan has run
+        #: and folded into the same summary
+        self.counters: Dict[str, int] = {}
         self._cancel = threading.Event()
         self._mu = threading.Lock()
         self._retry_budget = int(retry_budget)
@@ -265,6 +270,16 @@ class QueryContext:
             cell[2] = None if cpu_ns is None or cell[2] is None \
                 else cell[2] + cpu_ns
             cell[3] += child_wall_ns
+
+    def add_counters(self, counted: Dict[str, int]) -> None:
+        """Host ints of one executed plan (a nested query adds its own)."""
+        with self._mu:
+            for name, n in counted.items():
+                self.counters[name] = self.counters.get(name, 0) + n
+
+    def counter_table(self) -> Dict[str, int]:
+        with self._mu:
+            return dict(self.counters)
 
     def phase_table(self) -> Dict[str, Dict]:
         """Snapshot of the phase table, as the query's summary carries it."""

@@ -836,7 +836,7 @@ def _run_admitted(session, plan_fn, conf, qctx: QueryContext, stem: str,
     failed = True  # cleared once every partition completed
     try:
         with obs.phase("query"):
-            _run_query(session, plan_fn, conf, qname, tables)
+            _run_query(session, plan_fn, conf, qname, tables, qctx)
         failed = False
     finally:
         session._last_query_phases = obs.metrics.query_end(
@@ -845,7 +845,8 @@ def _run_admitted(session, plan_fn, conf, qctx: QueryContext, stem: str,
     return tables
 
 
-def _run_query(session, plan_fn, conf, qname: str, tables: List) -> None:
+def _run_query(session, plan_fn, conf, qname: str, tables: List,
+               qctx: QueryContext) -> None:
     """The root phase's body: planning (via the scheduler-owned plan
     cache), partition loop(s) appending to `tables`, failure handling, and
     the per-query observability snapshotting. Planning runs AFTER the
@@ -855,7 +856,7 @@ def _run_query(session, plan_fn, conf, qname: str, tables: List) -> None:
                           TRACE_ENABLED)
     from ..parallel.mesh import mesh_session_active
     from ..profiling import (SyncLedger, TaskMetricsRegistry,
-                             snapshot_plan_metrics)
+                             plan_query_counters, snapshot_plan_metrics)
     task_metrics_before = TaskMetricsRegistry.get().snapshot()
     syncs_before = SyncLedger.get().snapshot()
     qroot = None
@@ -1006,6 +1007,10 @@ def _run_query(session, plan_fn, conf, qname: str, tables: List) -> None:
             for node in final.collect_nodes():
                 if hasattr(node, "cleanup_shuffle"):
                     node.cleanup_shuffle(conf)
+    # a query that ran to its end says what its plan's nodes counted: the
+    # snapshot above has fetched the row counts they had parked. A failed
+    # query's summary carries none (its device values may not exist)
+    qctx.add_counters(plan_query_counters(final))
 
 
 def _finish_query_profile(session, qroot, conf, opjit_before) -> None:

@@ -48,6 +48,21 @@ class _ExchangeBase:
     def num_partitions(self) -> int:
         return self._n_out
 
+    def additional_metrics(self):
+        return {"shuffleRecordsWritten": "MODERATE", "dataSize": "MODERATE",
+                "shufflePartitions": "DEBUG"}
+
+    def query_counters(self):
+        return [("exchange.rows", self.metrics["shuffleRecordsWritten"]),
+                ("exchange.bytes", self.metrics["dataSize"]),
+                ("exchange.partitions", self.metrics["shufflePartitions"])]
+
+    def _count_block(self, rows: int, nbytes: int) -> None:
+        """One map-output block put or written (both numbers are on the
+        host there); pool threads count into the same metrics."""
+        self.metrics["shuffleRecordsWritten"].add(rows)
+        self.metrics["dataSize"].add(nbytes)
+
     def _shuffle_mode(self, ctx: TaskContext) -> str:
         from ..config import SHUFFLE_MODE
         return str(ctx.conf.get(SHUFFLE_MODE)).upper()
@@ -87,6 +102,7 @@ class _ExchangeBase:
                     if self._try_materialize_collective(sid, ctx):
                         self._n_maps = 1  # one collective "map": whole
                         self._shuffle_id = sid  # exchange
+                        self.metrics["shufflePartitions"].add(self._n_out)
                         return
                     self._n_maps = child.num_partitions()
                     threads = self._map_task_threads(ctx)
@@ -105,6 +121,7 @@ class _ExchangeBase:
                         for ids in groups:
                             self._run_group_guarded(sid, ids, ctx, mgr)
                     self._shuffle_id = sid
+                    self.metrics["shufflePartitions"].add(self._n_out)
             except BaseException:
                 # A cancel/shed/deadline trip (or any map-task error)
                 # unwinding MID-materialization leaves blocks already
@@ -232,7 +249,8 @@ class _ExchangeBase:
         with sync_scope(self.node_name()), \
                 obs.span(f"map s{sid}m{map_id}", cat="shuffle.map",
                          parent=getattr(self, "_obs_parent", None),
-                         shuffle=sid, map=map_id):
+                         shuffle=sid, map=map_id), \
+                obs.phase("exchange.map"):
             try:
                 if gate_device and isinstance(self, TpuExec):
                     # pipelined map tasks take a permit up front so
@@ -474,7 +492,8 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
         return base + "]"
 
     def additional_metrics(self):
-        return {"partitionTime": "MODERATE", "serializationTime": "MODERATE",
+        return {**super().additional_metrics(),
+                "partitionTime": "MODERATE", "serializationTime": "MODERATE",
                 "deserializationTime": "MODERATE",
                 "dictionaryEncodeTime": "MODERATE"}
 
@@ -843,6 +862,8 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
         out = []
         for p in range(n):
             out.append(pa.concat_tables(acc[p]) if acc[p] else None)
+            if acc[p]:
+                self._count_block(out[-1].num_rows, out[-1].nbytes)
         return out
 
     def _run_map_task(self, sid: int, map_id: int, map_ctx: TaskContext,
@@ -869,6 +890,7 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
                 if batches:
                     blk = batches[0] if len(batches) == 1 \
                         else concat_batches(batches)
+                    self._count_block(blk.num_rows, blk.device_memory_size())
                     catalog.put_block(sid, map_id, p, blk,
                                       owner=f"executor-{map_id}")
             catalog.mark_map_complete(sid, map_id)
@@ -928,7 +950,8 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
         with sync_scope(self.node_name()), \
                 obs.span(f"map s{sid}g{ids[0]}-{ids[-1]}", cat="shuffle.map",
                          parent=getattr(self, "_obs_parent", None),
-                         shuffle=sid, maps=list(ids)):
+                         shuffle=sid, maps=list(ids)), \
+                obs.phase("exchange.map"):
             try:
                 # ONE permit for the whole group — the group is one unit of
                 # device work (member batches share grouped launches)
@@ -1019,6 +1042,8 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
                     if batches:
                         blk = batches[0] if len(batches) == 1 \
                             else concat_batches(batches)
+                        self._count_block(blk.num_rows,
+                                          blk.device_memory_size())
                         catalog.put_block(sid, i, p, blk,
                                           owner=f"executor-{i}")
                 catalog.mark_map_complete(sid, i)
@@ -1026,6 +1051,9 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
         commits = []
         for i in ids:
             tables = [pa.concat_tables(a) if a else None for a in acc[i]]
+            for t in tables:
+                if t is not None:
+                    self._count_block(t.num_rows, t.nbytes)
             commits.append(
                 lambda t=tables, m=i: mgr.write_map_output(sid, m, t))
         return commits
@@ -1051,9 +1079,10 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
                 obs.event("mesh.read", cat="shuffle",
                           exchange_seq=self._collective_seq,
                           shuffle=self._shuffle_id, reduce=idx)
-            blocks = self._ici_fetch_blocks(
-                idx, ctx, mgr, catalog,
-                metric=self.metrics["deserializationTime"])
+            with obs.phase("exchange.fetch"):
+                blocks = self._ici_fetch_blocks(
+                    idx, ctx, mgr, catalog,
+                    metric=self.metrics["deserializationTime"])
             for b in blocks:
                 if b.num_rows:
                     # dictionary-encoded collective blocks decode on read
@@ -1159,7 +1188,11 @@ class CpuShuffleExchangeExec(_ExchangeBase, CpuExec):
                 sel = np.nonzero(pids == p)[0]
                 if len(sel):
                     acc[p].append(t.take(pa.array(sel)))
-        return [pa.concat_tables(a) if a else None for a in acc]
+        out = [pa.concat_tables(a) if a else None for a in acc]
+        for t in out:
+            if t is not None:
+                self._count_block(t.num_rows, t.nbytes)
+        return out
 
     def execute_partition(self, idx: int, ctx: TaskContext) -> Iterator:
         self._ensure_materialized(ctx)
@@ -1261,23 +1294,30 @@ def _pipelined_upload(exch, tables_it, names, ctx: TaskContext,
         it = tables_it
         if coalesce_enabled(ctx.conf):
             it = coalesce_arrow_stream(it, *coalesce_targets(ctx.conf))
-        while True:
-            with deser.timed(), sync_scope(exch.node_name()):
-                t = next(it, None)
-                b = (TpuColumnarBatch.from_arrow(t)
-                     if t is not None and t.num_rows else None)
-            if t is None:
-                return
-            if b is not None:
-                if obs._ACTIVE:
-                    # one reduce-side block fetched+uploaded (the row count
-                    # stays out of the args: an event must never force a
-                    # deferred device count — TL012)
-                    obs.event("shuffle.read", cat="shuffle")
-                if account_output:
-                    out_rows.add(b.num_rows)
-                    out_batches.add(1)
-                yield b.rename(names)
+        # `exchange.fetch`: one lap a fetch + upload, flushed however the
+        # reader leaves the generator
+        laps = obs.PhaseLaps()
+        try:
+            while True:
+                with deser.timed(), sync_scope(exch.node_name()), \
+                        laps.lap("exchange.fetch"):
+                    t = next(it, None)
+                    b = (TpuColumnarBatch.from_arrow(t)
+                         if t is not None and t.num_rows else None)
+                if t is None:
+                    return
+                if b is not None:
+                    if obs._ACTIVE:
+                        # one reduce-side block fetched+uploaded (the row
+                        # count stays out of the args: an event must never
+                        # force a deferred device count — TL012)
+                        obs.event("shuffle.read", cat="shuffle")
+                    if account_output:
+                        out_rows.add(b.num_rows)
+                        out_batches.add(1)
+                    yield b.rename(names)
+        finally:
+            laps.flush()
 
     yield from prefetch_iterator(_upload(), exch._prefetch_depth(ctx))
 
