@@ -21,17 +21,21 @@ classes:
   accounting (`opjit.cache_stats()["calls_by_kind"]`);
 * the walk also records what layout it saw, and the spec carries it as
   static structure: an index stream of bit-packed literal runs only is
-  staged without its run headers and unpacked with static shifts, PLAIN
-  values go over as uint32 words, and "dictionary pages, then PLAIN pages"
-  is placed by position — the program looks a run or a byte up per element
-  only where the layout is irregular (RLE runs, definition levels,
-  interleaved pages), and never because of a conf or a column name
-  (`decode_stats()["dense_values"]` of `["values"]` says how often);
+  staged without its run headers and unpacked with static shifts, one with
+  RLE runs among them as the same literal stream plus an O(runs) boundary
+  table (`_mixed_segments`), PLAIN values go over as uint32 words,
+  "dictionary pages, then PLAIN pages" is placed by position, and a
+  REQUIRED string column whose dictionary entries all have one length
+  takes its chars from the dictionary's matrix — the program searches per
+  element only where the layout leaves no other way (definition levels,
+  booleans, ragged strings, interleaved pages), and never because of a
+  conf or a column name (`decode_stats()["dense_values"]` of `["values"]`
+  says how often, `["general_<reason>"]` why not);
 * BYTE_ARRAY string/binary columns decode into the engine's own
   offsets+bytes device layout (`columnar/vector.py`): PLAIN pages walk
   their 4-byte length prefixes host-side into per-value (start, length)
   tables (vectorized pointer-doubling — no per-value Python), dictionary
-  pages ship the raw dictionary bytes plus the index run table, and the
+  pages ship the raw dictionary bytes plus the index stream, and the
   device program cumsums row lengths into the int32 offsets vector and
   byte-gathers the char buffer (`kernels/parquet_decode.string_offsets` /
   `gather_string_bytes`). RLE_DICTIONARY string columns additionally
@@ -96,15 +100,29 @@ _STATS: Dict[str, int] = {
     "row_groups": 0,
     "rows": 0,
     "values": 0,            # decoded values: rows x device columns
-    "dense_values": 0,      # ... of those, decoded with no per-element run
-                            # lookup (dense index streams, PLAIN values of
-                            # REQUIRED columns)
+    "dense_values": 0,      # ... of those, decoded with no per-element search
+                            # (dense or mixed index streams, PLAIN values of
+                            # REQUIRED columns; a string column only where
+                            # its chars took none either)
     "bytes_staged": 0,      # raw page bytes shipped to HBM
     "device_columns": 0,
     "fallback_columns": 0,     # per-column host demotions
     "fallback_row_groups": 0,  # per-row-group host re-reads (decode errors)
     "fallback_files": 0,       # whole-file host fallbacks
 }
+#: why a device column stayed on the general side (a search an element for
+#: its levels, its index stream or its chars), one reason a column, the first
+#: that applies; counted a decoded row group as `general_<reason>`
+GENERAL_REASONS = (
+    "nullable",                    # definition levels expand by run table
+    "boolean",                     # bit-packed / RLE values by run table
+    "interleaved_plain",           # dictionary and PLAIN pages interleaved
+    "truncated_run",               # a literal run the page end cuts short
+    "uneven_segments",             # literal payloads too scattered to pad
+    "variable_length_dictionary",  # ragged chars: cumsum + byte search
+    "plain_strings",               # PLAIN BYTE_ARRAY pages: ragged chars
+)
+_STATS.update({"general_" + r: 0 for r in GENERAL_REASONS})
 _PROGRAMS: "OrderedDict[Tuple, Any]" = OrderedDict()
 _PROGRAM_CACHE_MAX = 64
 
@@ -271,8 +289,10 @@ def encrypted_message(path: str, reason: str) -> str:
 # RLE / bit-packed hybrid run-header walk (host: O(runs), tiny)
 # ---------------------------------------------------------------------------
 
-from ..kernels.parquet_decode import (RUN_BITOFF, RUN_COLS, RUN_LITERAL,
-                                      RUN_PAD_START, RUN_START, RUN_WIDTH)
+from ..kernels.parquet_decode import (BOUND_BASE, BOUND_POS, BOUND_ROWS,
+                                      BOUND_STEP, BOUND_VALUE, RUN_BITOFF,
+                                      RUN_COLS, RUN_LITERAL, RUN_PAD_START,
+                                      RUN_START, RUN_VALUE, RUN_WIDTH)
 
 #: host-only sixth field of a walked run: the slots (values, padding of the
 #: last group included) its payload holds; 0 marks a literal run whose payload
@@ -531,7 +551,8 @@ class _Staged:
     assembled column can surface a device `dict_encoding`."""
     spec: Tuple
     arrays: List[np.ndarray]
-    dense_values: int = 0   # values decoded with no per-element run lookup
+    dense_values: int = 0   # values decoded with no per-element search
+    general: Optional[str] = None   # else why not: one of GENERAL_REASONS
     dict_offsets: Optional[np.ndarray] = None
     dict_chars: Optional[np.ndarray] = None
 
@@ -616,14 +637,7 @@ def _literal_segments(runs: List[List[int]], parts: List[bytes], n: int,
     starts[slot] = start[opens][order]
     counts[slot] = np.diff(np.append(start[opens], n))[order]
     # every run's payload goes from its part to its place in one copy
-    views = [memoryview(p) for p in parts]
-    part_at = np.cumsum([0] + [len(p) for p in parts[:-1]])
-    src = table[:, RUN_BITOFF] >> 3
-    part = np.searchsorted(part_at, src, side="right") - 1
-    lo = src - part_at[part]
-    nbytes = slots * width // 8
-    payload = [views[p][a:b] for p, a, b in zip(
-        part.tolist(), lo.tolist(), (lo + nbytes).tolist())]
+    payload, nbytes = _run_payloads(table, parts)
     used = np.add.reduceat(nbytes, opens).tolist()
     bounds = opens.tolist() + [len(runs)]
     zeros = memoryview(bytes(seg_slots * 4))
@@ -636,6 +650,115 @@ def _literal_segments(runs: List[List[int]], parts: List[bytes], n: int,
         pieces += [zeros[:seg_bytes]] * (k - int(per_width[g]))
     words = np.frombuffer(b"".join(pieces), np.uint32)
     return (groups, seg_slots), starts, counts, words
+
+
+def _run_payloads(table: np.ndarray, parts: List[bytes]):
+    """The payload of every literal run of `table` (walked rows, RUN_SLOTS
+    included) as a view into the staged part that holds it, and the
+    payloads' byte lengths."""
+    views = [memoryview(p) for p in parts]
+    part_at = np.cumsum([0] + [len(p) for p in parts[:-1]])
+    src = table[:, RUN_BITOFF] >> 3
+    part = np.searchsorted(part_at, src, side="right") - 1
+    lo = src - part_at[part]
+    nbytes = table[:, RUN_SLOTS] * table[:, RUN_WIDTH] // 8
+    return [views[p][a:b] for p, a, b in zip(
+        part.tolist(), lo.tolist(), (lo + nbytes).tolist())], nbytes
+
+
+def _mixed_segments(runs: List[List[int]], parts: List[bytes], out_cap: int):
+    """An index stream with RLE runs among its bit-packed literal runs, for
+    `kernels/parquet_decode.expand_mixed`: the literal payloads header-free
+    and back to back, one dense stream a bit width (a literal run holds a
+    multiple of 8 slots, so payloads join byte-aligned), and an O(runs)
+    int32 boundary table. Output element i of a literal run reads the
+    literal stream at `i + base` (base = the run's place in the stream less
+    its dense start: it changes after an RLE run, whose count is no multiple
+    of 8, after a page that ends inside a group of 8, and where the width
+    changes), an element of an RLE run reads the run's value, kept in the
+    table's own BOUND_VALUE row at slot `base`. A width-0 run (one-entry
+    dictionary) reads as an RLE run of value 0. Runs that continue the rule
+    of the one before add no boundary.
+
+    Returns ((width, slot bucket), ...) with widths ascending — what the
+    program's key takes beside the bucket of the boundary count — the
+    boundary table and the staged uint32 words (none where no literal run
+    holds a bit); or the reason (one of GENERAL_REASONS) the run table has
+    to drive the general expansion.
+    O(runs) Python + one O(bytes) copy."""
+    table = np.array(runs, np.int64).reshape(-1, RUN_SLOTS + 1)
+    start, slots = table[:, RUN_START], table[:, RUN_SLOTS]
+    lit = (table[:, RUN_LITERAL] != 0) & (table[:, RUN_WIDTH] > 0)
+    if not slots[lit].all():
+        return "truncated_run"
+    lt = table[lit]
+    widths, group = np.unique(lt[:, RUN_WIDTH], return_inverse=True)
+    per_group = np.bincount(group, minlength=len(widths))
+    order = np.argsort(group, kind="stable")        # as staged: by width
+    first_run = np.cumsum(np.append(0, per_group))
+    staged = np.cumsum(lt[order, RUN_SLOTS])
+    ends = staged[first_run[1:] - 1]        # slots staged, up to each group
+    total = np.diff(ends, prepend=0)
+    g_slots = [max(bucket_capacity(int(t)), 32) for t in total]
+    if sum(g_slots) > _DENSE_PAD_LIMIT * out_cap:
+        return "uneven_segments"
+    g_base = np.cumsum([0] + g_slots)       # of each group's unpacked values
+    # a literal run's place among the unpacked values of all groups
+    g = group[order]
+    place = np.empty(len(lt), np.int64)
+    place[order] = staged - lt[order, RUN_SLOTS] - (ends - total)[g] \
+        + g_base[g]
+    base = np.zeros(len(runs), np.int64)
+    base[lit] = place - start[lit]
+    keep = np.ones(len(runs), bool)
+    keep[1:] = ~(lit[1:] & lit[:-1] & (base[1:] == base[:-1]))
+    kept = np.flatnonzero(keep)
+    rle = ~lit[kept]
+    base[kept[rle]] = g_base[-1] + np.flatnonzero(rle)
+    bounds = np.zeros((BOUND_ROWS, bucket_capacity(len(kept))), np.int32)
+    bounds[BOUND_POS] = out_cap             # padding: dropped by the scatter
+    bounds[BOUND_POS, :len(kept)] = start[kept]
+    bounds[BOUND_STEP, :len(kept)] = np.diff(lit[kept].astype(np.int32),
+                                             prepend=0)
+    bounds[BOUND_BASE, :len(kept)] = np.diff(base[kept], prepend=0)
+    bounds[BOUND_VALUE, :len(kept)] = \
+        table[kept, RUN_VALUE].astype(np.uint32).view(np.int32)
+    payload, nbytes = _run_payloads(lt, parts)
+    pieces = []
+    for k, w in enumerate(widths.tolist()):
+        mine = order[first_run[k]:first_run[k + 1]]
+        pieces += [payload[r] for r in mine.tolist()]
+        pieces.append(bytes(g_slots[k] // 8 * w - int(nbytes[mine].sum())))
+    words = [np.frombuffer(b"".join(pieces), np.uint32)] if pieces else []
+    return tuple(zip(widths.tolist(), g_slots)), bounds, words
+
+
+def _stage_indices(runs: List[List[int]], parts: List[bytes], n: int,
+                   out_cap: int, general: Optional[str] = None):
+    """A dictionary-index stream of `n` values as the program decodes it,
+    chosen from the runs the walk saw: literal runs only -> header-free
+    segments (`_literal_segments`, unpacked with static shifts); RLE runs
+    among them -> the literal stream plus a boundary table
+    (`_mixed_segments`, one gather an element); else — or where `general`
+    already names a reason the column is on the general side (a nullable
+    column, interleaved PLAIN pages) — the run table and `expand_runs`.
+    Returns (the spec's index part, the staged arrays, the reason the run
+    table was taken or None)."""
+    if general is None:
+        lit = _literal_segments(runs, parts, n, out_cap)
+        if lit is not None:
+            (groups, seg_slots), starts, counts, words = lit
+            return ("dense", groups, seg_slots, out_cap), \
+                [starts, counts, words], None
+        mixed = _mixed_segments(runs, parts, out_cap)
+        if not isinstance(mixed, str):
+            groups, bounds, words = mixed
+            return ("mixed", groups, bounds.shape[1], out_cap), \
+                [bounds] + words, None
+        general = mixed
+    vr = _pad_runs(runs)
+    vb = _pad_bytes(parts)
+    return ("runs", vr.shape[0], vb.shape[0], out_cap), [vr, vb], general
 
 
 def _place_plain(parts: List[bytes], first: int, cap: int,
@@ -830,15 +953,23 @@ def _stage_string_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
                 f"column {plan.name}: {total_chars} chars exceed the int32 "
                 f"offsets range")
         char_cap = bucket_capacity(max(total_chars, 1))
-        vr = _pad_runs(val_runs)
-        vb = _pad_bytes(val_parts)
+        idx_spec, idx_arrays, general = _stage_indices(
+            val_runs, val_parts, dense_seen, cap,
+            "nullable" if plan.nullable else None)
+        arrays += idx_arrays
         dict_cap = bucket_capacity(max(n_dict, 1))
-        dsrc = np.zeros(dict_cap, np.int64)
-        dsrc[:n_dict] = dict_srcs
-        dln = np.zeros(dict_cap, np.int32)
-        dln[:n_dict] = dict_lens
-        db = _pad_bytes([dict_bytes])
-        arrays += [vr, vb, dsrc, dln, db]
+        # every entry of one byte length in a REQUIRED column: nothing is
+        # ragged, the dictionary goes over as a [entries, length] matrix
+        fixed_len = int(dict_lens[0]) if not plan.nullable and n_dict \
+            and int(dict_lens.min()) == int(dict_lens.max()) else None
+        if fixed_len is None:
+            general = general or "variable_length_dictionary"
+            dsrc = np.zeros(dict_cap, np.int64)
+            dsrc[:n_dict] = dict_srcs
+            dln = np.zeros(dict_cap, np.int32)
+            dln[:n_dict] = dict_lens
+            db = _pad_bytes([dict_bytes])
+            arrays += [dsrc, dln, db]
         # the parquet dictionary doubles as the column's device
         # dict_encoding — but codes only preserve equality when the
         # writer's dictionary is actually duplicate-free (true for every
@@ -873,10 +1004,18 @@ def _stage_string_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
         else:
             uniq = False
         emit_codes = bool(n_dict) and uniq
-        spec = ("str_dict", plan.nullable, out_kind, lv_shape,
-                (vr.shape[0], vb.shape[0]), dict_cap, db.shape[0], cap,
-                char_cap, emit_codes)
+        if fixed_len is None:
+            spec = ("str_dict", plan.nullable, out_kind, lv_shape, idx_spec,
+                    dict_cap, db.shape[0], cap, char_cap, emit_codes)
+        else:
+            if fixed_len:
+                dmat = np.zeros((dict_cap, fixed_len), np.uint8)
+                dmat[:n_dict] = dchars.reshape(n_dict, fixed_len)
+                arrays += [dmat]
+            spec = ("str_fixed", False, out_kind, None, idx_spec, dict_cap,
+                    fixed_len, cap, char_cap, emit_codes)
         return _Staged(spec, arrays,
+                       0 if general else dense_seen, general,
                        dict_offsets=doffs.astype(np.int32)
                        if emit_codes else None,
                        dict_chars=dchars if emit_codes else None)
@@ -899,7 +1038,8 @@ def _stage_string_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
     arrays += [srcs, lens, vb]
     spec = ("str_plain", plan.nullable, out_kind, lv_shape, dense_cap,
             vb.shape[0], cap, char_cap)
-    return _Staged(spec, arrays)
+    return _Staged(spec, arrays,
+                   general="nullable" if plan.nullable else "plain_strings")
 
 
 def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
@@ -1090,6 +1230,7 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
     out_np = str(np.dtype(plan.out_dtype.np_dtype))
     arrays: List[np.ndarray] = []
     dense_values = 0
+    general = "nullable" if plan.nullable else None
     if plan.nullable:
         lvr = _pad_runs(lv_runs)
         lvb = _pad_bytes(lv_parts)
@@ -1107,6 +1248,7 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
         vr = _pad_runs(val_runs)
         vb = _pad_bytes(val_parts)
         arrays += [vr, vb]
+        general = general or "boolean"
         spec = ("bool", out_np, plan.nullable, lv_shape,
                 (vr.shape[0], vb.shape[0]), cap)
     elif saw_dict_data:
@@ -1118,17 +1260,11 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
         n_dict = dense_seen - plain_seen
         idx_cap = bucket_capacity(max(n_dict, 1)) if tail else cap
         # (interleaved dictionary and PLAIN pages keep the general path whole)
-        lit = None if plan.nullable or (saw_plain_data and not tail) \
-            else _literal_segments(val_runs, val_parts, n_dict, idx_cap)
-        if lit is not None:
-            (groups, seg_slots), starts, counts, words = lit
-            arrays += [starts, counts, words]
-            idx_spec = ("dense", groups, seg_slots, idx_cap)
-        else:
-            vr = _pad_runs(val_runs)
-            vb = _pad_bytes(val_parts)
-            arrays += [vr, vb]
-            idx_spec = ("runs", vr.shape[0], vb.shape[0], idx_cap)
+        idx_spec, idx_arrays, general = _stage_indices(
+            val_runs, val_parts, n_dict, idx_cap,
+            "nullable" if plan.nullable else
+            "interleaved_plain" if saw_plain_data and not tail else None)
+        arrays += idx_arrays
         db = _pad_bytes([dict_bytes], min_len=plan.itemsize).view(np.uint32)
         arrays += [db]
         if tail:
@@ -1147,7 +1283,7 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
             plain_spec = ("segments", seg.shape[0], pb.shape[0])
         else:
             plain_spec = None
-        dense_values = (n_dict if lit is not None else 0) \
+        dense_values = (n_dict if general is None else 0) \
             + (plain_seen if tail else 0)
         spec = ("dict", plan.itemsize, plan.vkind, out_np, plan.nullable,
                 lv_shape, idx_spec, db.shape[0], plain_spec, cap)
@@ -1157,7 +1293,8 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
         spec = ("plain", plan.itemsize, plan.vkind, out_np, plan.nullable,
                 lv_shape, cap)
     # (definition levels are a per-element run lookup of their own)
-    return _Staged(spec, arrays, 0 if plan.nullable else dense_values)
+    return _Staged(spec, arrays, 0 if plan.nullable else dense_values,
+                   general)
 
 
 # ---------------------------------------------------------------------------
@@ -1181,6 +1318,23 @@ def _build_program(specs: Tuple[Tuple, ...]):
                 return K.validity_from_defs(defs, 1, num_rows)
             return jnp.arange(cap, dtype=jnp.int64) < num_rows
 
+    def indices(it, idx_spec):
+        """The dictionary indices of one column, as `_stage_indices` staged
+        them."""
+        with jax.named_scope("rle_expand"):
+            if idx_spec[0] == "dense":
+                starts, counts, words = next(it), next(it), next(it)
+                return K.unpack_dense_segments(
+                    words, idx_spec[1], idx_spec[2], starts, counts,
+                    idx_spec[3])
+            if idx_spec[0] == "mixed":
+                bounds = next(it)
+                words = next(it) if idx_spec[1] else None
+                return K.expand_mixed(words, idx_spec[1], bounds,
+                                      idx_spec[3])
+            vr, vb = next(it), next(it)
+            return K.expand_runs(vr, vb, idx_spec[3])
+
     # the steps carry stable names into the device trace (jax.named_scope:
     # metadata only); the program itself reads jit_parquet_decode there
     def parquet_decode(num_rows, *bufs):
@@ -1188,6 +1342,18 @@ def _build_program(specs: Tuple[Tuple, ...]):
         outs = []
         for spec in specs:
             kind = spec[0]
+            if kind == "str_fixed":
+                # one entry length, REQUIRED: offsets are an iota, chars a
+                # take from the dictionary's matrix, codes the indices
+                length, char_cap = spec[6], spec[8]
+                idx = indices(it, spec[4])
+                with jax.named_scope("string_gather"):
+                    dmat = next(it) if length \
+                        else jnp.zeros((1, 0), jnp.uint8)
+                    offs, chars, codes = K.fixed_length_strings(
+                        dmat, idx, num_rows, char_cap)
+                outs += [offs, chars] + ([codes] if spec[9] else [])
+                continue
             if kind in ("str_plain", "str_dict"):
                 # BYTE_ARRAY → offsets+bytes device layout: row lengths
                 # cumsum into int32 offsets, one searchsorted byte gather
@@ -1197,10 +1363,8 @@ def _build_program(specs: Tuple[Tuple, ...]):
                 char_cap = spec[8] if kind == "str_dict" else spec[7]
                 valid = levels(it, nullable, cap, num_rows)
                 if kind == "str_dict":
-                    vr, vb = next(it), next(it)
+                    idx = indices(it, spec[4])
                     dsrc, dlen, db = next(it), next(it), next(it)
-                    with jax.named_scope("rle_expand"):
-                        idx = K.expand_runs(vr, vb, cap)
                     with jax.named_scope("dict_gather"):
                         src_dense = K.dictionary_gather(dsrc, idx)
                         len_dense = K.dictionary_gather(dlen, idx)
@@ -1234,15 +1398,7 @@ def _build_program(specs: Tuple[Tuple, ...]):
             elif kind == "dict":
                 isz, vkind = spec[1], spec[2]
                 idx_spec, plain_spec = spec[6], spec[8]
-                with jax.named_scope("rle_expand"):
-                    if idx_spec[0] == "dense":
-                        starts, counts, words = next(it), next(it), next(it)
-                        idx = K.unpack_dense_segments(
-                            words, idx_spec[1], idx_spec[2], starts, counts,
-                            idx_spec[3])
-                    else:
-                        vr, vb = next(it), next(it)
-                        idx = K.expand_runs(vr, vb, idx_spec[3])
+                idx = indices(it, idx_spec)
                 db = next(it)
                 with jax.named_scope("dict_gather"):
                     dvals = K.plain_fixed_width(db, isz, vkind)
@@ -1563,6 +1719,9 @@ class DeviceFileDecoder:
                     _bump("dense_values",
                           sum(st.dense_values for st in staged))
                     _bump("device_columns", len(kept))
+                    for st in staged:
+                        if st.general is not None:
+                            _bump("general_" + st.general)
                     opjit.record_external_dispatch("parquet_decode")
                     outs = fn(np.int64(num_rows), *uploaded)
 
@@ -1571,13 +1730,13 @@ class DeviceFileDecoder:
                     dev_cols: Dict[str, TpuColumnVector] = {}
                     for st, plan in zip(staged, kept):
                         kind = st.spec[0]
-                        if kind in ("str_plain", "str_dict"):
+                        if kind in ("str_plain", "str_dict", "str_fixed"):
                             offs = next(out_it)
                             chars = next(out_it)
                             valid = next(out_it) if st.spec[1] else None
                             col = TpuColumnVector(plan.out_dtype, chars, valid,
                                                   num_rows, offsets=offs)
-                            if kind == "str_dict" and st.spec[9]:
+                            if kind != "str_plain" and st.spec[9]:
                                 codes = next(out_it)
                                 col.dict_encoding = (
                                     codes,

@@ -10,11 +10,13 @@ O(runs) metadata (footer, page headers, RLE run headers) and the
 decompression pass; the unpack/expand/gather/scatter work below runs on
 device.
 
-Encodings covered (the flat fixed-width column classes). What costs time
-on a TPU is a per-element gather (8.6 ns an element from a 4 K-entry int32
-table, 14-21 ns for float64, on a v5e: PERF.md PR 25), so the program looks
-a run or a byte up per element only where the page layout is irregular; the
-host's page walk says which (io/device_decode.py):
+Encodings covered (the flat fixed-width column classes and BYTE_ARRAY).
+What costs time on a TPU is a per-element search or a gather from a large
+table (8.6 ns an element from a 4 K-entry int32 table, 14-21 ns for
+float64, 64-112 ns from the emulated-int64 run table, on a v5e: PERF.md
+PR 25), so the program searches per element only where the page layout
+leaves no other way; the host's page walk says which
+(io/device_decode.py). The sides after PR 28, from dense to general:
 
 * **dense bit-unpacking** (`unpack_dense`, `unpack_dense_segments`) — a
   dictionary-index stream made of bit-packed literal runs only, staged
@@ -22,16 +24,30 @@ host's page walk says which (io/device_decode.py):
   over one or two uint32 word lanes. No lookup at all; a stream whose bit
   width grows with the dictionary, or whose pages end inside a group of 8,
   is several such segments, unpacked together per width;
+* **mixed streams by boundary table** (`expand_mixed`) — RLE runs among
+  the literal runs (low-cardinality columns). The literal payloads unpack
+  as above, one dense stream a width; an O(runs) int32 table says where
+  the rule `index = step * i + base` changes (after an RLE run, whose
+  count is no multiple of 8; after a page that ends inside a group of 8;
+  where the width changes), the device scatters it, takes one two-row
+  prefix sum and moves each value once: one 32-bit gather an element, no
+  `searchsorted`, no byte gathers;
+* **fixed-length dictionary strings** (`fixed_length_strings`) — a
+  REQUIRED BYTE_ARRAY column whose dictionary entries all have one byte
+  length: offsets are an iota, chars a take from the dictionary's
+  [entries, length] matrix, codes the indices;
 * **general bit-unpacking** (`unpack_bits`) — 1..32-bit packed little-endian
   values at arbitrary per-element bit offsets (PLAIN booleans, literal runs
-  mixed with RLE runs);
+  addressed through the run table);
 * **RLE / bit-packed hybrid run expansion** (`expand_runs`) — definition
-  levels and every index stream the dense layout does not cover. The host
-  walks the varint run headers into a run table (one row per run: output
-  start, absolute bit offset, repeated value, literal flag, bit width); the
-  kernel positions every output element in its run with one `searchsorted`
-  and either bit-unpacks (literal run) or broadcasts the run value (RLE
-  run) — about twenty gathers an element;
+  levels (so every nullable column), booleans, and the index streams the
+  two layouts above turn down (a literal run the page end cuts short,
+  payloads too scattered to pad). The host walks the varint run headers
+  into a run table (one row per run: output start, absolute bit offset,
+  repeated value, literal flag, bit width); the kernel positions every
+  output element in its run with one `searchsorted` and either bit-unpacks
+  (literal run) or broadcasts the run value (RLE run) — about twenty
+  gathers an element;
 * **dictionary gather** (`dictionary_gather`) — expanded indices into the
   PLAIN-decoded dictionary values: the one gather an element that stays;
 * **definition levels → validity** (`validity_from_defs`) and **null
@@ -46,14 +62,17 @@ host's page walk says which (io/device_decode.py):
   (`place_plain_tail`: the host stages the PLAIN values at their dense
   positions, one select merges); interleaved pages keep the segment-table
   merge (`merge_plain_segments`);
-* **BYTE_ARRAY strings** (`string_offsets`, `gather_string_bytes`) — the
-  variable-width classes decode into the engine's own Arrow-style
-  offsets+bytes layout (`columnar/vector.py`): per-row byte lengths (from
-  the 4-byte PLAIN length prefixes, or gathered from the dictionary's
-  entry lengths) cumsum into the int32 offsets vector, and one
-  searchsorted byte gather materializes the char buffer — the same ragged
-  shape `kernels/strings.py` computes over, so a decoded string column is
-  immediately a first-class device string column.
+* **ragged BYTE_ARRAY strings** (`string_offsets`, `gather_string_bytes`)
+  — what stays general among strings, because the row lengths differ or
+  rows are null: variable-length dictionaries, nullable columns, PLAIN
+  pages. They decode into the engine's own Arrow-style offsets+bytes
+  layout (`columnar/vector.py`): per-row byte lengths (from the 4-byte
+  PLAIN length prefixes, or gathered from the dictionary's entry lengths)
+  cumsum into the int32 offsets vector, and one searchsorted byte gather
+  materializes the char buffer — the same ragged shape
+  `kernels/strings.py` computes over, so a decoded string column is
+  immediately a first-class device string column. (A dictionary column's
+  index stream is dense or mixed all the same when it is REQUIRED.)
 
 All functions are shape-polymorphic jnp (no data-dependent host syncs), so
 tracelint's kernel scan classifies them device-clean and io/device_decode.py
@@ -203,6 +222,69 @@ def unpack_dense_segments(words_u32, groups_py, slots: int, starts, counts,
         word_at += n_words
         seg_at += n_seg
     return out[:out_len]
+
+
+#: rows of the boundary table of a mixed index stream (int32 [4, n],
+#: io/device_decode.py::_mixed_segments): the dense position where a stretch
+#: begins, the change there of `step` and of `base`, and the repeated value
+#: of an RLE run that begins there
+BOUND_POS, BOUND_STEP, BOUND_BASE, BOUND_VALUE = range(4)
+BOUND_ROWS = 4
+
+
+def expand_mixed(words_u32, groups_py, bounds, out_len: int):
+    """A dictionary-index stream with RLE runs among its bit-packed literal
+    runs, with no search an element. The literal payloads are staged
+    header-free, back to back — `groups_py` is the static ((width, slots),
+    ...), one dense stream a bit width, each padded to its `slots` (a
+    multiple of 32) — and unpack with static shifts (`unpack_dense`); the RLE
+    runs' values, one per boundary, follow them in one table. Element i then
+    reads `table[step[i] * i + base[i]]`: inside a stretch of literal runs
+    `step` is 1 and `base` the stretch's place in the literal stream less
+    its dense start, inside an RLE run `step` is 0 and `base` the run's slot.
+    Both change only where `bounds` says, so they are two prefix sums over a
+    scatter of O(runs) entries; padding entries carry a position of
+    `out_len` or more and are dropped. One 32-bit gather an element stays.
+    Elements past the last run repeat its rule and are masked downstream."""
+    lit = []
+    word_at = 0
+    for width, slots in groups_py:
+        n_words = slots // 32 * width
+        lit.append(unpack_dense(
+            jax.lax.slice(words_u32, (word_at,), (word_at + n_words,)),
+            width, slots))
+        word_at += n_words
+    table = jnp.concatenate(lit + [jax.lax.bitcast_convert_type(
+        bounds[BOUND_VALUE], jnp.uint32)])
+    rule = jnp.zeros((2, out_len), jnp.int32).at[:, bounds[BOUND_POS]].add(
+        bounds[BOUND_STEP:BOUND_BASE + 1], mode="drop")
+    step, base = jnp.cumsum(rule, axis=1, dtype=jnp.int32)
+    i = jnp.arange(out_len, dtype=jnp.int32)
+    return jnp.take(table, step * i + base, mode="clip")
+
+
+def fixed_length_strings(dict_rows_u8, indices, num_rows, char_cap: int):
+    """Strings of a REQUIRED column whose dictionary entries all have one
+    byte length L (`dict_rows_u8` is the dictionary as a [n_dict, L]
+    matrix): nothing is ragged, so the offsets are `L * min(row, num_rows)`,
+    the chars the matrix's rows taken by index (a gather from a small
+    table) and the codes the indices themselves — no prefix sum, no search.
+    Returns (offsets int32 [capacity + 1], chars uint8 [char_cap], codes
+    int32 [capacity]); padding rows read 0 and add no chars."""
+    cap = indices.shape[0]
+    length = dict_rows_u8.shape[1]
+    n = num_rows.astype(jnp.int32)
+    valid = jnp.arange(cap, dtype=jnp.int32) < n
+    codes = jnp.where(valid, indices.astype(jnp.int32), 0)
+    offs = jnp.minimum(jnp.arange(cap + 1, dtype=jnp.int32), n) * length
+    if length == 0:
+        return offs, jnp.zeros((char_cap,), jnp.uint8), codes
+    rows = jnp.take(dict_rows_u8, codes, axis=0, mode="clip")
+    chars = jnp.where(valid[:, None], rows, jnp.uint8(0)).reshape(cap * length)
+    if cap * length < char_cap:
+        chars = jnp.concatenate(
+            [chars, jnp.zeros((char_cap - cap * length,), jnp.uint8)])
+    return offs, chars[:char_cap], codes
 
 
 def plain_fixed_width(words_u32, itemsize: int, kind: str):

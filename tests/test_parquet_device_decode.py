@@ -665,7 +665,9 @@ def test_dense_tiny_row_groups(tmp_path, n, version):
                                   "nullable_no_nulls", "boolean", "string"])
 def test_layouts_that_stay_general(tmp_path, case):
     """Layouts the dense path must not take: equal to pyarrow all the same,
-    and counted under `values` only."""
+    counted under `values` only and under the reason they stayed general.
+    (An RLE run among the literals was one of them before the mixed
+    expansion: it is dense now, by the boundary table.)"""
     n = 5003
     v = (np.arange(n, dtype=np.int64) * 7919) % 50
     if case == "rle_run_among_literals":
@@ -690,8 +692,261 @@ def test_layouts_that_stay_general(tmp_path, case):
     specs = _program_specs()
     assert not any(sp[0] == "dict" and sp[6][0] == "dense" for sp in specs)
     st = dd.decode_stats()
-    assert (st["values"], st["dense_values"]) == (n, 0)
+    reason = {"rle_run_among_literals": None, "nullable": "nullable",
+              "nullable_no_nulls": "nullable", "boolean": "boolean",
+              "string": "variable_length_dictionary"}[case]
+    assert (st["values"], st["dense_values"]) == (n, 0 if reason else n)
     assert st["fallback_columns"] == 0
+    assert {k[8:]: c for k, c in st.items()
+            if k.startswith("general_") and c} == \
+        ({reason: 1} if reason else {})
+    if reason is None:
+        assert specs[0][6][0] == "mixed"
+
+
+# ---------------------------------------------------------------------------
+# mixed index streams (RLE runs among the literal runs) and fixed-length
+# dictionary strings: no search an element, chosen from the walked layout
+# ---------------------------------------------------------------------------
+
+
+def _hybrid_page(pieces, bw):
+    """One page's hybrid region from ("lit", values) / ("rle", value, count)
+    pieces, and the values it decodes to. A literal piece whose length is no
+    multiple of 8 pads its last group (legal only as a page's last run)."""
+    out, vals = bytearray(), []
+    for piece in pieces:
+        if piece[0] == "lit":
+            v = np.asarray(piece[1], np.uint64)
+            out += _literal_page(v, bw, groups_per_run=1 << 20) if bw else \
+                _varint_bytes((-(-len(v) // 8) << 1) | 1)
+            vals.append(v)
+        else:
+            _, value, count = piece
+            out += _varint_bytes(count << 1) \
+                + int(value).to_bytes((bw + 7) // 8, "little")
+            vals.append(np.full(count, value, np.uint64))
+    return np.concatenate(vals), bytes(out)
+
+
+@pytest.mark.parametrize("layout", ["rle_8_to_17", "one_long_run",
+                                    "page_ends_mid_group", "widths_change",
+                                    "rle_only", "runs_within_a_bucket"])
+@pytest.mark.parametrize("width", [0, 1, 2, 3, 12])
+def test_mixed_segments_equal_run_expansion(width, layout):
+    """Hand-encoded index streams with RLE runs among the literal runs: the
+    boundary table and the header-free literal stream decode to what the
+    run table decodes to. RLE counts are no multiples of 8, pages end
+    inside a group of 8, widths change between pages, a one-entry
+    dictionary has width 0. The key holds the widths, their slot buckets
+    and a bucket of the boundary count."""
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.kernels import parquet_decode as K
+    rng = np.random.default_rng(width * 7 + len(layout))
+
+    def lit(n, bw=width):
+        return ("lit", rng.integers(0, 1 << bw, n, dtype=np.uint64))
+
+    def rle(count, bw=width):
+        return ("rle", int(rng.integers(0, 1 << bw)), count)
+
+    if layout == "rle_8_to_17":
+        pages = [([lit(64)] + [x for c in range(8, 18)
+                               for x in (rle(c), lit(8 * (c % 3 + 1)))],
+                  width)]
+    elif layout == "one_long_run":
+        pages = [([lit(504), rle(3001), lit(16)], width),
+                 ([rle(9), lit(24)], width)]
+    elif layout == "page_ends_mid_group":
+        pages = [([rle(11), lit(1003)], width), ([lit(21)], width),
+                 ([lit(8), rle(13), lit(5)], width)]
+    elif layout == "widths_change":
+        pages = [([lit(40, width), rle(9, width), lit(19, width)], width),
+                 ([lit(512, width + 1), rle(17, width + 1)], width + 1),
+                 ([rle(10, width), lit(7, width)], width),
+                 ([lit(100, width + 5)], width + 5)]
+    elif layout == "rle_only":
+        pages = [([rle(700), rle(9)], width), ([rle(15)], width)]
+    else:
+        pages = [([x for _ in range(20 + width) for x in (lit(16), rle(9))],
+                  width)]
+    runs, parts, want, seen, bits = [], [], [], 0, 0
+    for pieces, bw in pages:
+        vals, region = _hybrid_page(pieces, bw)
+        runs += dd._walk_runs(region, 0, len(region), bw, len(vals), seen,
+                              bits)
+        parts.append(region)
+        want.append(vals)
+        seen += len(vals)
+        bits += len(region) * 8
+    want = np.concatenate(want)
+    cap = _bucket(seen)
+    assert dd._literal_segments(runs, parts, seen, cap) is None
+    spec, arrays, general = dd._stage_indices(runs, parts, seen, cap)
+    assert general is None and spec[0] == "mixed" and spec[3] == cap
+    widths = sorted({bw for pieces, bw in pages if bw
+                     and any(p[0] == "lit" for p in pieces)})
+    assert [w for w, _ in spec[1]] == widths
+    assert all(s % 32 == 0 and s == max(32, _bucket(s)) for _, s in spec[1])
+    bounds = arrays[0]
+    assert bounds.dtype == np.int32 and bounds.shape == (4, spec[2])
+    assert spec[2] == _bucket(int((bounds[0] < cap).sum()))
+    if layout == "runs_within_a_bucket":     # 40..64 boundaries: one bucket
+        assert spec[2] == 64
+    got = K.expand_mixed(jnp.asarray(arrays[1]) if widths else None, spec[1],
+                         jnp.asarray(bounds), cap)
+    assert got.dtype == jnp.uint32 and got.shape == (cap,)
+    assert np.array_equal(np.asarray(got)[:seen], want)
+    general_side = K.expand_runs(jnp.asarray(dd._pad_runs(runs)),
+                                 jnp.asarray(dd._pad_bytes(parts)), cap)
+    assert np.array_equal(np.asarray(general_side)[:seen],
+                          want.astype(np.int64))
+
+
+def test_mixed_segments_turn_down_a_truncated_run():
+    """A literal run the page end cuts short (its header promises more
+    bytes than the page holds) keeps the run table, under its reason."""
+    rng = np.random.default_rng(3)
+    vals, region = _hybrid_page(
+        [("rle", 2, 9), ("lit", rng.integers(0, 8, 61, dtype=np.uint64))], 3)
+    region = region[:-2]
+    runs = dd._walk_runs(region, 0, len(region), 3, len(vals), 0, 0)
+    spec, arrays, general = dd._stage_indices(runs, [region], len(vals), 128)
+    assert (spec[0], general) == ("runs", "truncated_run")
+
+
+def _runny_values(n, card, rng):
+    """Uniform draws over `card` values with RLE-able stretches planted:
+    runs of 8..17 equal values, and one long one."""
+    v = rng.integers(0, card, n)
+    at = 100
+    for c in list(range(8, 18)) * 3:
+        v[at:at + c] = v[at]
+        at += c + int(rng.integers(30, 90))
+    v[n // 2:n // 2 + 1500] = v[n // 2]
+    return v
+
+
+@pytest.mark.parametrize("version", ["1.0", "2.0"])
+@pytest.mark.parametrize("width", [0, 1, 2, 3, 12, "grows"])
+@pytest.mark.parametrize("kind", ["string", "int64"])
+def test_mixed_dictionary_index_oracle(tmp_path, kind, width, version):
+    """Writer-made index streams with RLE runs among the literals, bit for
+    bit against pyarrow, for a fixed-length string column and a fixed-width
+    dictionary column: many pages (they end inside groups of 8), data page
+    v1 and v2, a dictionary that grows between pages (widths change). Every
+    value is counted dense: no search for the indices, none for the chars."""
+    n = 20011
+    rng = np.random.default_rng(41)
+    if width == "grows":
+        v = np.concatenate([_runny_values(6000, 3, rng),
+                            _runny_values(n - 6000, 300, rng)])
+    else:
+        card = 1 if width == 0 else (1 << (width - 1)) + 1
+        v = _runny_values(n, card, rng)
+    if kind == "string":
+        arr = pa.array([f"{x:05d}" for x in v])
+    else:
+        arr = pa.array(v.astype(np.int64) * 1_000_003)
+    t = pa.Table.from_arrays([arr], schema=pa.schema(
+        [pa.field("v", arr.type, nullable=False)]))
+    p = _write(tmp_path, t, compression="snappy", data_page_version=version,
+               data_page_size=600, write_batch_size=250,
+               dictionary_pagesize_limit=1 << 22)
+    _assert_tables_equal(_device_read(
+        p, {"spark.rapids.tpu.parquet.deviceDecode.verify": "true"}),
+        pq.read_table(p))
+    (spec,) = _program_specs()
+    idx_spec = spec[4] if kind == "string" else spec[6]
+    assert spec[0] == ("str_fixed" if kind == "string" else "dict")
+    assert idx_spec[0] == "mixed"
+    widths = [w for w, _ in idx_spec[1]]
+    if width == "grows":
+        assert len(widths) > 1 and widths == sorted(set(widths))
+    elif width:                  # (a one-entry dictionary: zero-width runs)
+        assert widths[-1] == width
+    st = dd.decode_stats()
+    assert (st["values"], st["dense_values"]) == (n, n)
+    assert st["fallback_columns"] == 0
+    assert not any(c for k, c in st.items() if k.startswith("general_"))
+
+
+def _decode_columns(path, names):
+    """Row group 0 of `path` through the device decoder: {name: column}."""
+    from types import SimpleNamespace
+
+    from spark_rapids_tpu.config import RapidsConf
+    from spark_rapids_tpu.types import StringType
+    attrs = [SimpleNamespace(name=c, dtype=StringType()) for c in names]
+    with dd.DeviceFileDecoder(path, attrs, RapidsConf({})) as dec:
+        batch = dec.decode_row_group(0)
+    return dict(zip(names, batch.columns))
+
+
+@pytest.mark.parametrize("version", ["1.0", "2.0"])
+@pytest.mark.parametrize("length", [0, 1, 3])
+def test_fixed_length_dictionary_strings(tmp_path, length, version):
+    """A REQUIRED string column whose dictionary entries all have one byte
+    length takes no ragged path — offsets an iota, chars a take from the
+    dictionary's matrix, codes the indices — beside a nullable, a
+    variable-length and a PLAIN string column of the same file, which keep
+    the general side and are not counted dense. Buffers are what the
+    general side gives: offsets, chars padded with zeros to the char
+    bucket, the parquet dictionary and its indices as the `dict_encoding`."""
+    n = 3001
+    rng = np.random.default_rng(length)
+    v = _runny_values(n, 1 if length == 0 else 3, rng)
+    words = ["", "", ""] if length == 0 else \
+        [w[:length] for w in ("RAN", "AFO", "NXY")]
+    fixed = [words[x] for x in v]
+    t = pa.Table.from_arrays(
+        [pa.array(fixed),
+         pa.array([None if i % 7 == 0 else s for i, s in enumerate(fixed)]),
+         pa.array([("ab", "c", "defg")[i * 7 % 3] for i in range(n)]),
+         pa.array([f"p{x}" for x in v])],
+        schema=pa.schema([pa.field("fixed", pa.string(), nullable=False),
+                          pa.field("nullable", pa.string()),
+                          pa.field("ragged", pa.string(), nullable=False),
+                          pa.field("plain", pa.string(), nullable=False)]))
+    p = _write(tmp_path, t, compression="snappy", data_page_version=version,
+               use_dictionary=["fixed", "nullable", "ragged"],
+               data_page_size=500, write_batch_size=200)
+    _assert_tables_equal(_device_read(p), pq.read_table(p))
+    kinds = {sp[0]: sp for sp in _program_specs()}
+    assert set(kinds) == {"str_fixed", "str_dict", "str_plain"}
+    assert kinds["str_fixed"][6] == length
+    st = dd.decode_stats()
+    assert (st["values"], st["dense_values"]) == (4 * n, n)
+    assert (st["general_nullable"], st["general_variable_length_dictionary"],
+            st["general_plain_strings"]) == (1, 1, 1)
+    # the buffers themselves, and the dictionary encoding
+    dd.reset_for_tests()
+    cols = _decode_columns(p, ["fixed", "ragged"])
+    cap = _bucket(n)
+    ref = pq.read_table(p, read_dictionary=["fixed", "ragged"])
+    for name, col in cols.items():
+        want = ref.column(name).combine_chunks()
+        plain = want.cast(pa.string())
+        offs = np.asarray(col.offsets)
+        chars = np.asarray(col.data)
+        want_offs = np.frombuffer(plain.buffers()[1], np.int32, n + 1)
+        total = int(want_offs[-1])
+        assert offs.dtype == np.int32 and offs.shape == (cap + 1,)
+        assert np.array_equal(offs[:n + 1], want_offs)
+        assert (offs[n:] == total).all()
+        assert chars.dtype == np.uint8 and chars.shape == (_bucket(total),)
+        assert bytes(chars[:total]) == \
+            (plain.buffers()[2].to_pybytes()[:total] if total else b"")
+        assert not chars[total:].any()
+        assert col.validity is None
+        codes, dictionary = col.dict_encoding
+        codes = np.asarray(codes)
+        assert codes.dtype == np.int32 and codes.shape == (cap,)
+        assert np.array_equal(codes[:n], want.indices.to_numpy())
+        assert not codes[n:].any()
+        assert dictionary.to_arrow().to_pylist() == \
+            want.dictionary.to_pylist()
 
 
 def _chunk_pages(path):
@@ -885,9 +1140,107 @@ def test_cell_file_program_is_gather_free(tmp_path, monkeypatch):
     assert (st["values"], st["dense_values"]) == (4 * rows, 4 * rows)
 
 
+def _recorded_programs(monkeypatch):
+    """(specs, program, arguments) of every decode program launched from
+    here on."""
+    captured = []
+    build = dd._build_program
+
+    def recording(specs):
+        fn = build(specs)
+
+        def call(*args):
+            captured.append((specs, fn, args))
+            return fn(*args)
+        return call
+    monkeypatch.setattr(dd, "_build_program", recording)
+    return captured
+
+
+def _primitives(jaxpr, out):
+    """Names of every primitive of a jaxpr and of the calls nested in it
+    (a `pjit` adds the name of the function it calls)."""
+    for eqn in jaxpr.eqns:
+        out.add(eqn.primitive.name)
+        if "name" in eqn.params:
+            out.add(str(eqn.params["name"]))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, out)
+    return out
+
+
+def test_cell_file_q1_program_has_no_search(tmp_path, monkeypatch):
+    """Structural guard on `parquet-q1-stream`'s own layout (one 2^20-row
+    group of chipbench's file, the seven columns Q1 reads): the two CHAR(1)
+    columns' index streams hold RLE runs among their literal runs, and the
+    program still holds no `searchsorted` and no sort, and at most two
+    2^20-element gathers a string column (the literal stream by position,
+    the dictionary's matrix by index). A count made on the CPU, not a
+    timing (PERF.md PR 28)."""
+    import jax
+    from chipbench import datagen
+    rows = 1 << 20
+    path = str(tmp_path / "cell.parquet")
+    datagen.write_parquet(path, 25, rows, keep=())
+    captured = _recorded_programs(monkeypatch)
+    cols = ["l_quantity", "l_extendedprice", "l_discount", "l_tax",
+            "l_returnflag", "l_linestatus", "l_shipdate"]    # the file's order
+    got = TpuSession({}).read.parquet(path).select(*cols).to_arrow()
+    _assert_tables_equal(got, pq.read_table(path, columns=cols))
+    (specs, fn, args), = captured
+    by_col = dict(zip(cols, specs))
+    for c in ("l_returnflag", "l_linestatus"):
+        assert by_col[c][0] == "str_fixed" and by_col[c][6] == 1
+        assert by_col[c][4][0] == "mixed" and len(by_col[c][4][1]) == 1
+        assert by_col[c][9]                          # codes ride along
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    names = _primitives(jaxpr, set())
+    assert not names & {"sort", "searchsorted", "while", "scan"}, names
+    gathers = _gathers(jaxpr, [])
+    full = [g for g in gathers if g[0] >= rows]
+    # quantity, shipdate, and two a string column
+    assert len(full) == 2 + 2 * 2, gathers
+    assert sorted(shape for _, shape in full if len(shape) == 2) == \
+        [(16, 1), (16, 1)], gathers                  # the dictionaries' rows
+    st = dd.decode_stats()
+    assert (st["values"], st["dense_values"]) == (7 * rows, 7 * rows)
+    assert not any(c for k, c in st.items() if k.startswith("general_"))
+
+
+def test_mixed_key_holds_no_run_counts(tmp_path, monkeypatch):
+    """Row groups of the cell's CHAR(1) columns whose RLE-run and boundary
+    counts differ (another seed's draws) inside one bucket share one
+    program: the counts enter the key as buckets only."""
+    from chipbench import datagen
+    rows = 1 << 16
+    seen = []
+    mixed = dd._mixed_segments
+
+    def recording(runs, parts, out_cap):
+        out = mixed(runs, parts, out_cap)
+        seen.append((len(runs), int((out[1][0] < out_cap).sum())))
+        return out
+    monkeypatch.setattr(dd, "_mixed_segments", recording)
+    s = TpuSession({})
+    for seed in (25, 26, 27):
+        path = str(tmp_path / f"s{seed}.parquet")
+        datagen.write_parquet(path, seed, rows, keep=())
+        got = s.read.parquet(path).select("l_returnflag").to_arrow()
+        _assert_tables_equal(got, pq.read_table(path,
+                                                columns=["l_returnflag"]))
+    assert len(set(seen)) == 3, seen     # other runs, other boundaries
+    assert len({_bucket(b) for _, b in seen}) == 1, seen
+    st = dd.decode_stats()
+    assert (st["programs"], st["dispatches"]) == (1, 3)
+
+
 @pytest.mark.parametrize("case", ["parent_without_counters",
                                   "nothing_decoded", "partly_dense",
-                                  "all_dense", "decoded_from_a_real_scan"])
+                                  "all_dense", "decoded_from_a_real_scan",
+                                  "parquet-q1"])
 def test_benchmark_dense_share_reader(tmp_path, case):
     """chipbench's reader of `scan_dense_decode_share` on recorded
     counters: a program without them, or a window that decoded no value,
@@ -918,6 +1271,28 @@ def test_benchmark_dense_share_reader(tmp_path, case):
         ctx = SimpleNamespace(before={"decode": before}, after={"decode": dict(
             before, values=120 + 4000, dense_values=100 + 4000)})
         want = 100.0
+    elif case == "parquet-q1":
+        # a string column counts as dense only when both halves applied:
+        # CHAR(1) flags (indices and chars without a search) do, a
+        # variable-length dictionary (dense indices, ragged chars) does not
+        first = dd.decode_stats()
+        n = 4099
+        v = _runny_values(n, 3, np.random.default_rng(1))
+        t = pa.Table.from_arrays(
+            [pa.array([("R", "A", "N")[x] for x in v]),
+             pa.array([("O", "F")[x % 2] for x in v]),
+             pa.array([("AIR", "REG AIR", "MAIL")[x] for x in v]),
+             pa.array(v.astype(np.int64))],
+            schema=pa.schema(
+                [pa.field(c, ty, nullable=False) for c, ty in (
+                    ("flag", pa.string()), ("status", pa.string()),
+                    ("mode", pa.string()), ("qty", pa.int64()))]))
+        p = _write(tmp_path, t)
+        _assert_tables_equal(_device_read(p), pq.read_table(p))
+        ctx = SimpleNamespace(before={"decode": first},
+                              after={"decode": dd.decode_stats()})
+        assert ctx.after["decode"]["general_variable_length_dictionary"] == 1
+        want = 75.0
     else:
         first = dd.decode_stats()
         n = 4099

@@ -186,10 +186,15 @@ def row_group_file(tmp_path_factory):
         "name": pa.array(np.char.add("Customer#",
                                      np.arange(n).astype(str))),
     })
+    # the same flags REQUIRED, as chipbench's file declares them: the mixed
+    # index stream and the fixed-length dictionary (no search an element)
+    flag = t.column("l_returnflag").combine_chunks()
+    t = t.append_column(pa.field("flag_required", flag.type, nullable=False),
+                        flag)
     path = str(tmp_path_factory.mktemp("tpu_compile") / "rg.parquet")
     pq.write_table(t, path, row_group_size=n, compression="snappy",
                    use_dictionary=["l_discount", "l_tax", "l_returnflag",
-                                   "l_linestatus"])
+                                   "l_linestatus", "flag_required"])
     return path
 
 
@@ -208,7 +213,8 @@ def _decode_programs(monkeypatch, path, columns):
     ("l_extendedprice",),      # PLAIN fixed-width (8-byte, u64 -> f64 view)
     ("l_returnflag",),         # dictionary-encoded BYTE_ARRAY (RLE indices)
     ("name",),                 # PLAIN BYTE_ARRAY
-], ids=["fixed_width", "dictionary", "byte_array"])
+    ("flag_required",),        # CHAR(1) REQUIRED: boundary table, iota offsets
+], ids=["fixed_width", "dictionary", "byte_array", "fixed_length_dictionary"])
 def test_parquet_decode_program_compiles_for_v5e(monkeypatch, one_chip,
                                                  row_group_file, columns):
     jitted, args, kwargs = _decode_programs(monkeypatch, row_group_file,
