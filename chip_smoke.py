@@ -385,7 +385,7 @@ def run_one_chip(args, platform: str, jaxc: JaxCompileCounter) -> None:
 def run_four_chips(args, platform: str) -> None:
     import jax
 
-    import benchmarks.multichip as mc
+    import benchmarks.tpch as tpch
     from spark_rapids_tpu.obs import mesh_profile
     from spark_rapids_tpu.parallel.sharded import run_mesh_query
 
@@ -395,8 +395,12 @@ def run_four_chips(args, platform: str) -> None:
                          f"{len(jax.devices())}")
     rows = args.rows
     extra = {"spark.rapids.sql.batchSizeRows": str(max(rows, 1 << 16))}
-    for name, build in (("tpch_q3", mc._q3(rows, n)),
-                        ("tpch_q18", mc._q18(rows, n))):
+    for q in ("q3", "q18"):
+        name = f"tpch_{q}"
+
+        def build(s, query=tpch.QUERIES[q]):
+            return query(s, tpch.load_tables(s, rows, parts=n))
+
         seq0 = mesh_profile.current_seq()
         t = time.perf_counter()
         rec = run_mesh_query(name, build, n_devices=n, extra_conf=extra)

@@ -169,8 +169,7 @@ class TpuColumnarBatch:
         return self.to_arrow().to_pylist()
 
     @staticmethod
-    def from_arrow(table, bucket: bool = True,
-                   to_device: bool = True) -> "TpuColumnarBatch":
+    def from_arrow(table, to_device: bool = True) -> "TpuColumnarBatch":
         """Arrow table/record-batch → device batch (H→D; reference
         HostColumnarToGpu). All buffers ship in ONE device_put.
         `to_device=False` keeps numpy buffers (valid column payloads — jax
@@ -185,7 +184,7 @@ class TpuColumnarBatch:
         table = table.combine_chunks()
         _keep_host.active = True
         try:
-            cols = [TpuColumnVector.from_arrow(table.column(i), bucket=bucket)
+            cols = [TpuColumnVector.from_arrow(table.column(i))
                     for i in range(table.num_columns)]
             # all columns in one batch must share a row capacity
             if cols:
@@ -235,15 +234,15 @@ class TpuColumnarBatch:
         return TpuColumnarBatch(cols, table.num_rows, list(table.column_names))
 
     @staticmethod
-    def from_pydict(data: Dict[str, Sequence], types: Optional[Dict[str, DataType]] = None,
-                    bucket: bool = True) -> "TpuColumnarBatch":
+    def from_pydict(data: Dict[str, Sequence],
+                    types: Optional[Dict[str, DataType]] = None) -> "TpuColumnarBatch":
         import pyarrow as pa
         from ..types import to_arrow as type_to_arrow
         arrays = {}
         for name, vals in data.items():
             at = type_to_arrow(types[name]) if types and name in types else None
             arrays[name] = pa.array(vals, type=at)
-        return TpuColumnarBatch.from_arrow(pa.table(arrays), bucket=bucket)
+        return TpuColumnarBatch.from_arrow(pa.table(arrays))
 
     def select(self, indices: Sequence[int]) -> "TpuColumnarBatch":
         names = self.names

@@ -4,9 +4,9 @@ TPU analogue of the reference's `GpuColumnVector` (a Spark ColumnVector wrapping
 cuDF device column, /root/reference/sql-plugin/src/main/java/com/nvidia/spark/rapids/
 GpuColumnVector.java:40). Differences driven by XLA's compilation model:
 
-  * Static shapes: every column has a *physical capacity* (bucketed to powers of two
-    when `spark.rapids.tpu.batch.bucketPadding.enabled`) and a *logical* `num_rows`
-    kept host-side. Rows in [num_rows, capacity) are padding and always invalid.
+  * Static shapes: every column has a *physical capacity* (always padded to a
+    power-of-two bucket, `bucket_capacity`) and a *logical* `num_rows` kept
+    host-side. Rows in [num_rows, capacity) are padding and always invalid.
     cuDF kernels take dynamic sizes; XLA would recompile per size, so we bucket.
   * Validity is a dense bool array (Arrow uses bitmaps; a bool vector vectorizes
     better through XLA and converts to/from Arrow bitmaps at the host boundary).
@@ -28,10 +28,8 @@ from ..types import (ArrayType, BinaryType, BooleanType, DataType, DecimalType,
                      NullType, StringType, is_fixed_width)
 
 
-def bucket_capacity(n: int, enabled: bool = True, minimum: int = 16) -> int:
+def bucket_capacity(n: int, minimum: int = 16) -> int:
     """Round row counts up to power-of-two buckets to bound XLA recompilation."""
-    if not enabled:
-        return max(n, 1)
     cap = minimum
     while cap < n:
         cap <<= 1
@@ -309,10 +307,9 @@ class TpuColumnVector:
     @staticmethod
     def from_numpy(dtype: DataType, values: np.ndarray,
                    validity: Optional[np.ndarray] = None,
-                   capacity: Optional[int] = None,
-                   bucket: bool = True) -> "TpuColumnVector":
+                   capacity: Optional[int] = None) -> "TpuColumnVector":
         n = len(values)
-        cap = capacity if capacity is not None else bucket_capacity(n, bucket)
+        cap = capacity if capacity is not None else bucket_capacity(n)
         carrier = dtype.np_dtype
         buf = np.zeros(cap, dtype=carrier)
         buf[:n] = values.astype(carrier, copy=False)
@@ -327,12 +324,11 @@ class TpuColumnVector:
     def from_strings(dtype: DataType, offsets: np.ndarray, chars: np.ndarray,
                      validity: Optional[np.ndarray] = None,
                      capacity: Optional[int] = None,
-                     char_capacity: Optional[int] = None,
-                     bucket: bool = True) -> "TpuColumnVector":
+                     char_capacity: Optional[int] = None) -> "TpuColumnVector":
         n = len(offsets) - 1
-        cap = capacity if capacity is not None else bucket_capacity(n, bucket)
+        cap = capacity if capacity is not None else bucket_capacity(n)
         ccap = char_capacity if char_capacity is not None else bucket_capacity(
-            max(int(offsets[-1]), 1), bucket)
+            max(int(offsets[-1]), 1))
         obuf = np.full(cap + 1, offsets[-1], dtype=np.int32)
         obuf[: n + 1] = offsets
         cbuf = np.zeros(ccap, dtype=np.uint8)
@@ -345,7 +341,7 @@ class TpuColumnVector:
         return TpuColumnVector(dtype, _np_to_jax(cbuf), vmask, n, offsets=_np_to_jax(obuf))
 
     @staticmethod
-    def from_arrow(arr, bucket: bool = True) -> "TpuColumnVector":
+    def from_arrow(arr) -> "TpuColumnVector":
         """Host Arrow array → device column (the H→D upload)."""
         import pyarrow as pa
         from ..types import from_arrow as a2t
@@ -355,7 +351,7 @@ class TpuColumnVector:
         if not device_layout_ok(dtype):
             return TpuColumnVector(dtype, jnp.zeros((0,), jnp.int8), None, n,
                                    host_data=arr,
-                                   host_capacity=bucket_capacity(n, bucket))
+                                   host_capacity=bucket_capacity(n))
         if arr.null_count:
             validity = np.asarray(arr.is_valid())
         else:
@@ -363,10 +359,10 @@ class TpuColumnVector:
         from ..types import StructType as _St
         if isinstance(dtype, _St):
             # struct = validity over per-field child columns (cuDF STRUCT)
-            cap = bucket_capacity(n, bucket)
+            cap = bucket_capacity(n)
             kids = []
             for i in range(arr.type.num_fields):
-                kid = TpuColumnVector.from_arrow(arr.field(i), bucket=bucket)
+                kid = TpuColumnVector.from_arrow(arr.field(i))
                 if kid.capacity != cap:
                     from .batch import _repad
                     kid = _repad(kid, cap)
@@ -392,9 +388,9 @@ class TpuColumnVector:
                            _Sf("value", dtype.value_type,
                                dtype.value_contains_null)])
             kcol = TpuColumnVector.from_arrow(
-                arr.keys.slice(base, n_elems), bucket=bucket)
+                arr.keys.slice(base, n_elems))
             vcol = TpuColumnVector.from_arrow(
-                arr.items.slice(base, n_elems), bucket=bucket)
+                arr.items.slice(base, n_elems))
             ecap = max(kcol.capacity, vcol.capacity)
             from .batch import _repad
             if kcol.capacity != ecap:
@@ -403,7 +399,7 @@ class TpuColumnVector:
                 vcol = _repad(vcol, ecap)
             child = TpuColumnVector(entry_t, jnp.zeros((0,), jnp.int8),
                                     None, n_elems, children=[kcol, vcol])
-            cap = bucket_capacity(n, bucket)
+            cap = bucket_capacity(n)
             obuf = np.full(cap + 1, n_elems, dtype=np.int32)
             obuf[: n + 1] = offsets
             vmask = None
@@ -424,8 +420,8 @@ class TpuColumnVector:
             offsets -= base
             n_elems = int(offsets[-1])
             values = arr.values.slice(base, n_elems)
-            child = TpuColumnVector.from_arrow(values, bucket=bucket)
-            cap = bucket_capacity(n, bucket)
+            child = TpuColumnVector.from_arrow(values)
+            cap = bucket_capacity(n)
             obuf = np.full(cap + 1, n_elems, dtype=np.int32)
             obuf[: n + 1] = offsets
             vmask = None
@@ -444,11 +440,10 @@ class TpuColumnVector:
                 # zero out data regions of null rows? keep: gathers only read valid rows
                 pass
             return TpuColumnVector.from_strings(dtype, offsets, chars,
-                                                validity, bucket=bucket)
+                                                validity)
         if isinstance(dtype, NullType):
             buf = np.zeros(n, dtype=bool)
-            return TpuColumnVector.from_numpy(dtype, buf, np.zeros(n, dtype=bool),
-                                              bucket=bucket)
+            return TpuColumnVector.from_numpy(dtype, buf, np.zeros(n, dtype=bool))
         if isinstance(dtype, DecimalType):
             if dtype.precision > DecimalType.MAX_DEVICE_PRECISION:
                 # two-limb carrier: (capacity, 2) int64 [hi, lo]
@@ -456,7 +451,7 @@ class TpuColumnVector:
                 unscaled = [0 if v is None else unscaled_int(v, dtype.scale)
                             for v in arr.to_pylist()]
                 limbs = pack(unscaled)
-                cap = bucket_capacity(n, bucket)
+                cap = bucket_capacity(n)
                 buf = np.zeros((cap, 2), np.int64)
                 buf[:n] = limbs
                 vmask = None
@@ -468,7 +463,7 @@ class TpuColumnVector:
             scaled = np.array(
                 [0 if v is None else int(v.scaleb(dtype.scale)) for v in arr.to_pylist()],
                 dtype=np.int64)
-            return TpuColumnVector.from_numpy(dtype, scaled, validity, bucket=bucket)
+            return TpuColumnVector.from_numpy(dtype, scaled, validity)
         carrier = dtype.np_dtype
         if pa.types.is_boolean(arr.type):
             np_arr = np.asarray(arr.fill_null(False).to_numpy(zero_copy_only=False))
@@ -485,7 +480,7 @@ class TpuColumnVector:
             if validity is not None:
                 np_arr[~validity] = 0
             np_arr = np_arr.astype(carrier, copy=False)
-        return TpuColumnVector.from_numpy(dtype, np_arr, validity, bucket=bucket)
+        return TpuColumnVector.from_numpy(dtype, np_arr, validity)
 
     @staticmethod
     def from_scalar(value: Any, dtype: DataType, num_rows: int,

@@ -229,8 +229,7 @@ cache tracks it:
   group with one bounds readback).
 * With fusion on, a fully-fused N-operator chain contributes ONE `segment`
   dispatch per batch; with fusion off the same chain contributes N
-  `project`/`filter` dispatches. bench.py's q3_general detail reports the
-  per-run deltas so the reduction is directly visible.
+  `project`/`filter` dispatches.
 * `opJitTraceTime` isolates first-sight compile cost from steady-state
   dispatch cost; steady state should be all hits.
 
@@ -309,8 +308,7 @@ split bounds, `pairs` — join pair count, `chars` — string gather sizing,
 `batch` — batch materialization at the D→H boundary, ...).
 
 * `SyncLedger.get().snapshot()` returns per-operator counts;
-  `total()` the process-wide sum. bench.py's q3_general detail reports the
-  per-run delta next to `opJitDispatchesByKind`.
+  `total()` the process-wide sum.
 * With deferred compaction + coalescing on, a healthy general-path run
   shows blocking syncs per partition bounded by O(exchanges) — one `bounds`
   sync per map batch and one `batch` materialization per boundary — not
@@ -354,8 +352,7 @@ spill state — in a postmortem bundle under
 `spark.rapids.tpu.obs.postmortemDir` whenever a fatal device error, an
 exhausted transient-retry loop, or a genuine HBM budget OOM kills a query.
 docs/observability.md documents the registry naming scheme and the
-postmortem schema; `python -m tools.bench_diff` gates one bench round
-against the previous one on these numbers.
+postmortem schema.
 
 ## Mesh efficiency profiler + collective watchdog
 
@@ -368,8 +365,8 @@ per-chip rows, the imbalance factor, and the straggler chip id when one
 chip's share exceeds `spark.rapids.tpu.obs.meshStragglerFactor` × the
 median. Profiles land in `last_query_profile()['mesh']`,
 `session.metrics_snapshot()` (with the `mesh.skew_imbalance` /
-`mesh.straggler_wait_ms` registry histograms), `python -m tools.obs_report
---mesh`, and the MULTICHIP bench's per-query `efficiency_attribution`. A
+`mesh.straggler_wait_ms` registry histograms) and `python -m tools.obs_report
+--mesh`. A
 collective blocked past `spark.rapids.tpu.obs.collectiveWatchdogMs` trips
 the watchdog WHILE still waiting (flight-recorder event +
 `mesh.watchdog_fired` counter — a hung chip is otherwise indistinguishable
@@ -432,8 +429,7 @@ keys; an exchange past the cardinality/2^31-byte guards
 (`spark.rapids.tpu.exchange.dictionaryEncode.maxCardinality`) falls back
 per-map with reason `dictionary_overflow`. Only nested or host-only
 payloads transparently keep the per-map
-device-resident path. Design, fault model and the MULTICHIP bench:
-docs/distributed.md.
+device-resident path. Design and fault model: docs/distributed.md.
 
 ## Robustness
 
@@ -569,12 +565,6 @@ TASK_RETRY_LIMIT = _conf("spark.rapids.memory.tpu.taskRetryLimit").doc(
     "TpuRetryOOM (splitting on TpuSplitAndRetryOOM) before giving up "
     "(reference RmmRapidsRetryIterator bound)."
 ).integer(8)
-
-BUCKET_PADDING = _conf("spark.rapids.tpu.batch.bucketPadding.enabled").doc(
-    "Pad batch capacities to power-of-two buckets to bound XLA recompilation under "
-    "data-dependent row counts (TPU-specific; no reference analogue — cuDF kernels "
-    "accept dynamic sizes, XLA does not)."
-).boolean(True)
 
 COALESCE_ENABLED = _conf("spark.rapids.tpu.coalesce.enabled").doc(
     "Batch coalescing for the general path (reference GpuCoalesceBatches + "
@@ -1156,16 +1146,15 @@ TRACE_CATEGORIES = _conf("spark.rapids.tpu.trace.categories").doc(
 
 TRACE_TAG = _conf("spark.rapids.tpu.trace.tag").doc(
     "Stem prefix for traced-query names and their artifact files "
-    "(<tag>-<n>.trace.json instead of query-<n>.trace.json) — bench.py "
-    "tags each stage so artifacts from different stages never collide."
+    "(<tag>-<n>.trace.json instead of query-<n>.trace.json), so that "
+    "artifacts of different runs never collide."
 ).string(None)
 
 TRACE_DIR = _conf("spark.rapids.tpu.trace.dir").doc(
     "When set (and tracing is enabled), every traced query writes its "
     "Chrome trace (<query>.trace.json) and diagnostics bundle "
     "(<query>.profile.json) under this directory; the paths are recorded "
-    "in last_query_profile()['artifacts']. bench.py points this at its "
-    "artifact directory so each stage ships a loadable trace."
+    "in last_query_profile()['artifacts']."
 ).string(None)
 
 TRACE_MAX_CONCURRENT = _conf(
@@ -1220,7 +1209,7 @@ OBS_MESH_STRAGGLER_FACTOR = _conf(
     "Straggler threshold for the mesh efficiency profiler: an exchange "
     "whose heaviest chip receives more than this multiple of the median "
     "per-chip rows reports that chip as the straggler (skew table in "
-    "last_query_profile()['mesh'] and the MULTICHIP summary) and feeds "
+    "last_query_profile()['mesh']) and feeds "
     "the mesh.straggler_wait_ms histogram."
 ).double(2.0)
 

@@ -31,8 +31,7 @@ The grouped reduction uses a direct-indexed group table: combined key code =
 `lax.scan` whose chunk size scales inversely with the table width (bounded
 working set, no scatter — TPU scatter serializes under index collisions).
 The tiny group table also ELIMINATES the partial/final shuffle: partials
-merge on one shard, the same psum-over-state design as the multichip kernel
-(parallel/distributed.py).
+merge on one shard.
 
 Compiled executables are cached process-wide keyed by a structural
 fingerprint of the stage (expressions by class/ordinal/literal, dtypes,
@@ -407,7 +406,7 @@ def _build_stage_fn(spec: _StageSpec, cap: int,
     ch = min(ch, cap)
     n_chunks = max(cap // ch, 1)
     if cap % n_chunks:
-        n_chunks = 1  # unpadded capacities (bucketPadding off): one chunk
+        n_chunks = 1  # a capacity that is no power of two: one chunk
 
     agg_fns = spec.agg_fns
     layers = spec.layers

@@ -68,7 +68,7 @@ _EAGER_PINS: "OrderedDict[Tuple, None]" = OrderedDict()
 _EAGER_PIN_MAX = 4096
 _FAILED = object()  # call outcome: run the eager fallback
 
-#: process-wide counters (bench.py reads these; per-exec metrics mirror them)
+#: process-wide counters (per-exec metrics mirror them)
 _STATS = {"hits": 0, "misses": 0, "traces": 0, "trace_time_ns": 0}
 #: dispatch accounting (docs/configs.md "Dispatch accounting"): one entry per
 #: program dispatch through the cache, keyed by program kind ("segment",

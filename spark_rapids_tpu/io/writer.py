@@ -81,14 +81,10 @@ class DataFrameWriter:
         """Yield (partition_index, arrow table) from the physical plan
         (non-file consumers: delta/iceberg transaction logs)."""
         from ..execs.base import TaskContext
-        from ..plan.optimizer import optimize_logical
-        from ..plan.overrides import TpuOverrides
-        from ..plan.planner import plan_physical
+        from ..plan.overrides import plan_query
         session = self._df.session
         conf = session._rapids_conf()
-        optimized, _ = optimize_logical(self._df._plan, conf)
-        cpu_plan = plan_physical(optimized, conf)
-        final = TpuOverrides.apply(cpu_plan, conf)
+        final, _, _ = plan_query(self._df._plan, conf)
         names = [a.name for a in final.output]
         import pyarrow as pa
         for p in range(final.num_partitions()):
@@ -109,14 +105,11 @@ class DataFrameWriter:
         import pyarrow as pa
         from ..execs.base import TaskContext
         from ..execs.write import CpuDataWritingCommandExec, WriteSpec
-        from ..plan.optimizer import optimize_logical
-        from ..plan.overrides import TpuOverrides
-        from ..plan.planner import plan_physical
+        from ..plan.overrides import TpuOverrides, plan_cpu
         self._prepare_dir(path)
         session = self._df.session
         conf = session._rapids_conf()
-        optimized, _ = optimize_logical(self._df._plan, conf)
-        child = plan_physical(optimized, conf)
+        child, _, _ = plan_cpu(self._df._plan, conf)
         bucket_by, num_buckets = self._bucket_by, self._num_buckets
         if num_buckets:
             from ..config import BUCKETING_WRITE_ENABLED

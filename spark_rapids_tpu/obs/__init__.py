@@ -33,7 +33,7 @@ chaos:
   exchange wall attribution (staging/launch/wait/compact), per-chip skew
   and straggler reporting, "why not collective" fallback reasons, and
   the collective watchdog — the distributed layer over the three above
-  (``last_query_profile()['mesh']``, the MULTICHIP bench's
+  (``last_query_profile()['mesh']``, ``parallel/sharded.py``'s
   ``efficiency_attribution``, ``mesh.watchdog_fired``).
 
 Instrumentation sites in execs//shuffle//memory//parallel/ must emit

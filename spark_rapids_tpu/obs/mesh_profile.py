@@ -1,10 +1,9 @@
 """Mesh efficiency profiler: per-exchange wall attribution, skew and
 straggler reporting, and the collective watchdog.
 
-MULTICHIP_r06 measured scaling efficiency 0.05–0.11 on the 8-device mesh
-with collectives only 10–28% of wall — meaning most of the wall was
-UNATTRIBUTED (host staging? launch overhead? compact? partition skew?
-idle chips?). The reference stack treats shuffle-transport visibility as
+A mesh query's collectives can be a small part of its wall, and the rest
+must not stay unattributed (host staging? launch overhead? compact?
+partition skew? idle chips?). The reference stack treats shuffle-transport visibility as
 a first-class subsystem (per-peer/per-block accounting around
 ``RapidsShuffleHeartbeatManager``, SURVEY §2.7); this module is that
 layer for the collective data plane:
@@ -17,9 +16,8 @@ layer for the collective data plane:
   The session folds the profiles recorded during one query into the
   diagnostics bundle's ``mesh`` section (``last_query_profile()``), the
   always-on registry folds the recent ring into
-  ``session.metrics_snapshot()``, and ``parallel/sharded.py`` /
-  ``benchmarks/multichip.py`` turn them into the MULTICHIP round's
-  ``efficiency_attribution`` breakdown.
+  ``session.metrics_snapshot()``, and ``parallel/sharded.py`` turns them
+  into a query's ``efficiency_attribution`` breakdown.
 * **Skew metrics** — per profile: max / median per-chip received rows,
   the imbalance factor (max/median), and the straggler chip id when one
   chip's share exceeds ``spark.rapids.tpu.obs.meshStragglerFactor`` × the

@@ -199,15 +199,15 @@ def _donate(positions: Iterable[int]) -> Tuple[int, ...]:
     return tuple(positions) if jax.default_backend() != "cpu" else ()
 
 
-# collective-launch statistics (bench MULTICHIP stage + the O(exchanges)
+# collective-launch statistics (parallel/sharded.py and the O(exchanges)
 # assertion read these next to opjit calls_by_kind["mesh_collective"]).
 _STATS_LOCK = threading.Lock()
 _STATS = {"launches": 0, "rows_sent": 0, "stage_ns": 0, "launch_ns": 0,
           "wait_ns": 0, "compact_ns": 0,
-          # dictionary-encoded string exchanges (the MULTICHIP summary's
-          # multichip_string_collectives / dict_encode_ms keys)
+          # dictionary-encoded string exchanges (parallel/sharded.py's
+          # string_collectives / dict_encode_ms keys)
           "dict_exchanges": 0, "dict_encode_ns": 0,
-          # staging-pool reuse + segmented-overlap accounting (r07 fused
+          # staging-pool reuse + segmented-overlap accounting (fused
           # dataplane keys: docs/distributed.md "Fused compact & overlap")
           "staging_reuse_hits": 0, "overlap_segments": 0}
 

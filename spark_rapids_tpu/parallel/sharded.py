@@ -23,10 +23,9 @@ tables from `obs/mesh_profile.py`), the per-map "why not collective"
 reasons, and the named-phase `efficiency_attribution` of the profiled
 mesh wall (docs/distributed.md "Diagnosing poor scaling").
 
-Unlike the hand-written q1 step this replaces (`distributed.py`, kept for
-the kernel-level dryrun), nothing here is query-specific: the planner —
-not this runner — decides which exchanges ride the fabric, so any
-session query (TPC-H, TPC-DS, ad-hoc DataFrames) shards the same way.
+Nothing here is query-specific: the planner — not this runner — decides
+which exchanges ride the fabric, so any session query (TPC-H, TPC-DS,
+ad-hoc DataFrames) shards the same way.
 """
 
 from __future__ import annotations
@@ -210,7 +209,7 @@ def run_mesh_query(name: str, build: Callable, *, n_devices: int,
         "string_collectives": col.get("dict_exchanges", 0),
         "dict_encode_ms": round(col.get("dict_encode_ns", 0) / 1e6, 2),
         "collective_rows": col["rows_sent"],
-        # r07 fused dataplane keys: compact fused into the collective
+        # fused dataplane keys: compact fused into the collective
         # dispatch on EVERY profiled exchange, staged pad pieces served
         # from the staging pool, and segments launched by the overlapped
         # path (0 = the correctness-first unsegmented default)
@@ -234,8 +233,8 @@ def attribute_efficiency(record: Dict) -> Dict[str, float]:
     """Named-phase attribution of one query's PROFILED mesh wall
     (staging / launch / collective-wait / compact from the collective
     counters, compute = the residual outside the exchange path) as
-    percentages — the `efficiency_attribution` the MULTICHIP compact line
-    carries so each round explains its own efficiency number. The phase
+    percentages — the `efficiency_attribution` of `summarize`'s compact
+    line. The phase
     walls and the wall come from the SAME collect (run_mesh_query's
     bracketed execution), so the split is exact."""
     wall_ms = record.get("wall_ms_profiled") or record.get("wall_ms_mesh")
@@ -260,13 +259,12 @@ def attribute_efficiency(record: Dict) -> Dict[str, float]:
 
 def summarize(records: List[Dict], n_devices: int,
               input_rows: Dict[str, int]) -> Dict:
-    """The MULTICHIP stage's compact summary (ONE parseable line — the
-    r05 lesson: the driver keeps only the stdout tail). Per-chip rows/s is
-    the mesh run's input-row throughput divided by the chip count; scaling
-    efficiency is speedup-over-1-chip / n_chips. The single collective_ms
-    scalar of r06 is replaced by the per-phase walls + skew summary +
-    efficiency_attribution (obs/mesh_profile.py); the full per-exchange
-    profiles ride the detail records."""
+    """A compact summary of mesh records (ONE parseable line). Per-chip
+    rows/s is the mesh run's input-row throughput divided by the chip
+    count; scaling efficiency is speedup-over-1-chip / n_chips. Collective
+    time is the per-phase walls + skew summary + efficiency_attribution
+    (obs/mesh_profile.py); the full per-exchange profiles ride the detail
+    records."""
     per_query = {}
     total_launches = 0
     total_collective_ms = 0.0
@@ -283,8 +281,7 @@ def summarize(records: List[Dict], n_devices: int,
             "collective_wait": round(r["collective_wait_ms"], 1),
             "compact": round(r.get("collective_compact_ms", 0.0), 1),
         }
-        # compact-line discipline (the r05 lesson: the driver keeps ~2000
-        # chars of stdout): no key whose value is derivable from another —
+        # compact-line discipline: no key whose value is derivable from another —
         # rows/bit_identical/wall_ms_single ride the detail records, the
         # worst-skew summary keeps only the verdict fields
         sk = r.get("skew_worst")
@@ -301,7 +298,7 @@ def summarize(records: List[Dict], n_devices: int,
             "collective_launches": r["collective_launches"],
             "string_collectives": r.get("string_collectives", 0),
             "dict_encode_ms": r.get("dict_encode_ms", 0.0),
-            # r07 fused dataplane keys (ISSUE 16): compact_fused is the
+            # fused dataplane keys (ISSUE 16): compact_fused is the
             # headline invariant (never elided — a False here means a
             # regression back to host compact); the counters elide at zero
             "compact_fused": bool(r.get("compact_fused", False)),
@@ -335,18 +332,14 @@ def summarize(records: List[Dict], n_devices: int,
         "queries": per_query,
         "collective_launches_total": total_launches,
         # string exchanges riding the fabric as dictionary codes + one
-        # broadcast dictionary each (the r06 burndown: q1's agg exchange
-        # and q18's c_name final agg were per_map=string_or_nested_payload)
+        # broadcast dictionary each
         "string_collectives_total": total_string_collectives,
         "dict_encode_ms_total": round(total_dict_encode_ms, 2),
-        # RENAMED from r06's collective_ms_total: the total now includes
-        # the compact phase, and bench_diff gates collective totals
-        # lower-is-better — reusing the old key with a wider composition
-        # would read as a spurious 4–5× regression against r06
+        # the four phases summed, compact included
         "collective_phases_ms_total": round(total_collective_ms, 2),
         "bit_identical_all": all_identical,
         # the fused-compact invariant over the whole round: False means
-        # some exchange fell back to a host-side compact (the r06 wall)
+        # some exchange fell back to a host-side compact
         "compact_fused_all": all(bool(r.get("compact_fused", False))
                                  for r in records),
         "collective_launches_O_exchanges": all_o_exchanges,

@@ -630,6 +630,29 @@ class TpuOverrides:
         return "\n".join(reasons)
 
 
+# ---------------------------------------------------------------------------
+# The planning entry: which passes make a plan, and in which order, is
+# decided here and nowhere else (collect, write, explain, the plan cache).
+# ---------------------------------------------------------------------------
+
+def plan_cpu(logical, conf: RapidsConf):
+    """Logical plan → (CPU physical plan, optimized logical plan, applied
+    optimizer rule names): `optimize_logical`, then `plan_physical`. For
+    callers that put a node of their own over the plan before overriding
+    (a write command) or only report on it (`explain_fallback`)."""
+    from .optimizer import optimize_logical
+    from .planner import plan_physical
+    optimized, rules = optimize_logical(logical, conf)
+    return plan_physical(optimized, conf), optimized, rules
+
+
+def plan_query(logical, conf: RapidsConf):
+    """Logical plan → (executable physical plan, optimized logical plan,
+    applied optimizer rule names): `plan_cpu`, then `TpuOverrides.apply`."""
+    cpu_physical, optimized, rules = plan_cpu(logical, conf)
+    return TpuOverrides.apply(cpu_physical, conf), optimized, rules
+
+
 class TpuTransitionOverrides:
     """reference GpuTransitionOverrides.scala: final boundary fixups + the
     everything-on-TPU test assertion (assertIsOnTheGpu:616)."""

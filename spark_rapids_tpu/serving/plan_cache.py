@@ -435,20 +435,16 @@ def build_or_fetch(session, sched, plan, conf):
     (executable physical plan, "hit"|"miss"|"off"|"uncacheable",
     applied optimizer rule names)."""
     from ..config import PLAN_CACHE_ENABLED
-    from ..plan.optimizer import optimize_logical
-    from ..plan.overrides import TpuOverrides
-    from ..plan.planner import plan_physical
+    from ..plan.overrides import plan_query
 
     cache: Optional[PlanCache] = getattr(sched, "plan_cache", None)
     if not conf.get(PLAN_CACHE_ENABLED) or cache is None:
-        optimized, rules = optimize_logical(plan, conf)
-        final = TpuOverrides.apply(plan_physical(optimized, conf), conf)
+        final, _, rules = plan_query(plan, conf)
         return final, "off", rules
 
     fp = fingerprint(plan, conf)
     if fp is None:
-        optimized, rules = optimize_logical(plan, conf)
-        final = TpuOverrides.apply(plan_physical(optimized, conf), conf)
+        final, _, rules = plan_query(plan, conf)
         cache.count_miss()
         return final, "uncacheable", rules
 
@@ -462,8 +458,7 @@ def build_or_fetch(session, sched, plan, conf):
         return (entry.template.clone_for_execution(rebind or None),
                 "hit", entry.rules)
 
-    optimized, rules = optimize_logical(plan, conf)
-    final = TpuOverrides.apply(plan_physical(optimized, conf), conf)
+    final, _, rules = plan_query(plan, conf)
     entry = PlanCacheEntry(fp, final, plan_relevant_conf(conf), rules)
     cache.insert(entry)
     cache.count_miss(entry.label)

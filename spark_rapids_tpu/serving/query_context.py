@@ -152,8 +152,8 @@ class QueryContext:
         self.cancel_reason: Optional[str] = None
         #: retry-after hint set by QueryScheduler when this query is shed
         self.shed_retry_after_s: Optional[float] = None
-        #: measured admission wait (ms), written at grant time — the
-        #: bench serving stage reads it back per query
+        #: measured admission wait (ms), written at grant time
+        #: (`session.last_admit_wait_ms()`)
         self.admit_wait_ms: Optional[float] = None
         #: net HBM bytes charged by this query's bound threads (lock-free
         #: GIL adds, the metrics-cell idiom: a rare lost update is the

@@ -757,7 +757,7 @@ def execute_plan(session, plan, timeout: Optional[float] = None,
         # concurrent sessions each minting "query-1" would collide in
         # every name-keyed filter (the STRICT mesh-profile query filter
         # would bleed one tenant's exchanges into another's bundle).
-        # Tagged names stay `<tag>-<n>` — the bench artifact contract.
+        # Tagged names stay `<tag>-<n>`, as `trace.tag` documents.
         sid_n = session._session_id.rsplit("-", 1)[-1]
         qname = f"query-s{sid_n}-{session._query_seq}"
     else:

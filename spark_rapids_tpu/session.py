@@ -18,8 +18,7 @@ from .config import RapidsConf
 from .expressions.base import (Alias, AttributeReference, Expression, Literal,
                                UnresolvedAttribute, output_name)
 from .plan import logical as L
-from .plan.overrides import TpuOverrides
-from .plan.planner import plan_physical
+from .plan.overrides import TpuOverrides, plan_cpu, plan_query
 
 
 class Column:
@@ -570,8 +569,6 @@ class DataFrame:
         host round trip for device-resident stages."""
         from .execs.base import TaskContext
         from .execs.transitions import DeviceToHostExec
-        from .plan.overrides import TpuOverrides
-        from .plan.planner import plan_physical
         from .columnar.batch import TpuColumnarBatch
         if self.session._stopped:
             # same contract as _execute: a stopped session must not
@@ -580,9 +577,7 @@ class DataFrame:
             raise RuntimeError(
                 f"TpuSession {self.session._session_id} is stopped")
         conf = self.session._rapids_conf()
-        from .plan.optimizer import optimize_logical
-        optimized, _ = optimize_logical(self._plan, conf)
-        final = TpuOverrides.apply(plan_physical(optimized, conf), conf)
+        final, _, _ = plan_query(self._plan, conf)
         # strip the final device→host transition: the caller wants device data
         while isinstance(final, DeviceToHostExec):
             final = final.children[0]
@@ -660,7 +655,7 @@ class DataFrame:
             return self.session.explain("metrics")
         conf = self.session._rapids_conf()
         from .config import PLAN_CACHE_ENABLED
-        from .plan.optimizer import explain_logical, optimize_logical
+        from .plan.optimizer import explain_logical
         from .serving.plan_cache import fingerprint
         from .serving.scheduler import QueryScheduler
         status = "off"
@@ -672,9 +667,7 @@ class DataFrame:
                 inst = QueryScheduler.peek()
                 status = ("hit" if inst is not None
                           and inst.plan_cache.peek(fp.key) else "miss")
-        optimized, rules = optimize_logical(self._plan, conf)
-        cpu_plan = plan_physical(optimized, conf)
-        final = TpuOverrides.apply(cpu_plan, conf)
+        final, optimized, rules = plan_query(self._plan, conf)
         lines = [f"planCache={status}"]
         if rules:
             lines.append(f"appliedRules={', '.join(rules)}")
@@ -688,10 +681,8 @@ class DataFrame:
 
     def explain_fallback(self) -> str:
         """reference ExplainPlan: report what would not run on TPU."""
-        from .plan.optimizer import optimize_logical
         conf = self.session._rapids_conf()
-        optimized, _ = optimize_logical(self._plan, conf)
-        cpu_plan = plan_physical(optimized, conf)
+        cpu_plan, _, _ = plan_cpu(self._plan, conf)
         return TpuOverrides.explain_plan(cpu_plan, conf)
 
 
@@ -1151,7 +1142,7 @@ class TpuSession:
     def last_admit_wait_ms(self) -> Optional[float]:
         """Admission-queue wait of this session's last executed query in
         milliseconds (None before any query, or when the last query was
-        rejected/shed while still queued). The bench serving stage reads
+        rejected/shed while still queued). The benchmark's tenant cell reads
         this per query; the process-wide distribution is the
         sched.class_admit_wait_ms histogram."""
         return getattr(self, "_last_admit_wait_ms", None)

@@ -483,9 +483,8 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
         base = f"TpuShuffleExchange[{self.partitioning}, n={self._n_out}"
         # "why not collective" surfaced where the plan is read
         # (explain("metrics"), the bundle's plan tree): a mesh-session
-        # exchange that rode the per-map path says why — MULTICHIP_r06's
-        # q1 showed `collective_launches: 0` with the reason buried in a
-        # code comment (obs/mesh_profile.py)
+        # exchange that rode the per-map path says why
+        # (obs/mesh_profile.py)
         reason = getattr(self, "_collective_reason", None)
         if reason and not getattr(self, "_collective", False):
             return f"{base}, per_map={reason}]"

@@ -7,7 +7,7 @@ with a driver-coordinated heartbeat discovering peers
 (RapidsShuffleHeartbeatManager, Plugin.scala:436-447).
 
 TPU re-design: within one mesh/slice the data plane is XLA's `all_to_all`
-over ICI (parallel/distributed.py `ici_all_to_all_exchange` — the compiler
+over ICI (parallel/mesh.py's collective exchange — the compiler
 schedules the interconnect transfers, replacing hand-written UCX
 transactions). At the exec layer, ICI mode keeps every shuffle block as a
 *spillable device batch* in this catalog — no Arrow serialization, no disk

@@ -4,7 +4,8 @@ blocks on local storage.
 Reference: RapidsShuffleInternalManagerBase.scala MULTITHREADED mode
 (RapidsShuffleThreadedWriterBase:238, ...ReaderBase:569, BytesInFlightLimiter:529).
 The ICI mode (device-resident exchange over the interconnect, UCX analogue)
-lives in parallel/distributed.py and is selected via spark.rapids.shuffle.mode.
+lives in shuffle/ici.py and parallel/mesh.py and is selected via
+spark.rapids.shuffle.mode.
 """
 
 from __future__ import annotations

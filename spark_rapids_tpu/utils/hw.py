@@ -108,8 +108,8 @@ def configure_compile_cache() -> str:
     `JAX_COMPILATION_CACHE_DIR`, when set, is read by JAX itself and nothing
     is done here. Otherwise the cache lives at `<checkout>/.jax_cache`
     (git-ignored): a fixed path, because the path is part of what a cache
-    entry is found by. Call before the first compile; entry points that
-    measure (chip_smoke.py, bench.py, benchmarks/multichip.py) do."""
+    entry is found by. Call before the first compile, as chip_smoke.py does
+    (chipbench places its own the same way)."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

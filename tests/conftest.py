@@ -1,6 +1,5 @@
 """Test bootstrap: force JAX onto a virtual 8-device CPU platform BEFORE jax
-initializes, so sharding/mesh tests run without TPU hardware (the driver's
-dryrun_multichip uses the same mechanism)."""
+initializes, so sharding/mesh tests run without TPU hardware."""
 
 import os
 

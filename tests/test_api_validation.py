@@ -25,6 +25,27 @@ def test_api_validation_passes():
     assert violations == []
 
 
+def test_key_spelled_only_in_prose_is_reported_dead(monkeypatch):
+    """A declared key that only a docstring spells — this one spells
+    `spark.rapids.tpu.test.plantedProseOnlyKey` — is read by no code and
+    is reported dead; the key beside it, set like a session conf, is not."""
+    from spark_rapids_tpu.config import REGISTRY, ConfEntry
+    settings = {"spark.rapids.tpu.test.plantedLiveKey": "true"}
+    entries = dict(REGISTRY.entries)
+    # joined here so that no literal of this file is the prose-only key
+    for key in ("spark.rapids.tpu.test." + "plantedProseOnlyKey", *settings):
+        entries[key] = ConfEntry(key, "planted", None, str, internal=True)
+    monkeypatch.setattr(REGISTRY, "entries", entries)
+    sys.path.insert(0, TOOLS)
+    try:
+        import api_validation
+        dead = [v for v in api_validation.conf_consistency()
+                if "dead conf" in v]
+    finally:
+        _remove_tools_path()
+    assert len(dead) == 1 and "plantedProseOnlyKey" in dead[0], dead
+
+
 def test_docs_not_drifted():
     """docs/configs.md and docs/supported_ops.md must match the registries
     (reference: generated-docs drift is a premerge failure)."""

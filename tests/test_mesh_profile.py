@@ -396,7 +396,7 @@ def test_sharded_attribution_covers_mesh_wall():
         "attributed_pct"}
     assert 90.0 <= q["efficiency_attribution"]["attributed_pct"] <= 110.0
     assert "collective_phases_ms_total" in summary
-    assert "collective_ms_total" not in summary  # r06 key retired (renamed)
+    assert "collective_ms_total" not in summary  # an older name of it
     assert set(q["phases_ms"]) == {"staging", "launch", "collective_wait",
                                    "compact"}
     assert q["skew"] is not None and "imbalance" in q["skew"]

@@ -20,7 +20,6 @@ recorder + postmortem bundles.
 
 import glob
 import json
-import os
 import threading
 import time
 
@@ -396,237 +395,6 @@ def test_chaos_injected_retry_oom_does_not_spam_postmortems(tmp_path):
         HbmBudget.reset_for_tests()
     handle_task_failure(ei.value, conf, exit_on_fatal=False)
     assert not _postmortems(tmp_path, "hbm_oom")
-
-
-def test_bench_diff_gates_regressions_including_zero_endpoints():
-    """tools/bench_diff.py: throughput drops beyond the threshold regress;
-    zero endpoints gate by DIRECTION (overhead appearing from zero or
-    throughput collapsing to zero is a regression, never 'unchanged')."""
-    from tools.bench_diff import diff, extract_metrics
-    old = {"value": 100.0, "summary": {"q3_general_rows_s": 1000.0,
-                                       "dispatch_overhead_ms": 0.0}}
-    new = {"value": 100.0, "summary": {"q3_general_rows_s": 850.0,
-                                       "dispatch_overhead_ms": 45.0}}
-    # rows_per_s-shaped keys picked up, non-metrics ignored
-    assert "summary.q3_general_rows_s" in extract_metrics(old)
-    reg, imp, unch, only_old, only_new = diff(old, new, 0.10)
-    assert [r[0] for r in reg] == ["summary.q3_general_rows_s"]
-    reg, _imp, _unch, _, _ = diff(old, new, 0.10, include_overhead=True)
-    assert {r[0] for r in reg} == {"summary.q3_general_rows_s",
-                                   "summary.dispatch_overhead_ms"}
-    # throughput collapsing to zero regresses; recovering from zero is an
-    # improvement
-    reg, imp, _u, _, _ = diff({"a_rows_per_s": 10.0}, {"a_rows_per_s": 0.0},
-                              0.10)
-    assert [r[0] for r in reg] == ["a_rows_per_s"]
-    reg, imp, _u, _, _ = diff({"a_rows_per_s": 0.0}, {"a_rows_per_s": 10.0},
-                              0.10)
-    assert not reg and [r[0] for r in imp] == ["a_rows_per_s"]
-    # within threshold passes
-    reg, _i, unch, _, _ = diff({"a_rows_per_s": 100.0},
-                               {"a_rows_per_s": 95.0}, 0.10)
-    assert not reg and unch
-
-
-def test_bench_diff_multichip_payloads():
-    """tools/bench_diff.py MULTICHIP awareness (ISSUE 13): the stub r05
-    round (no parsed payload) exits 2 instead of reporting "ok";
-    scaling_efficiency / per_chip_rows_per_s gate higher-is-better and
-    the mesh profiler's phase walls gate LOWER-is-better by default —
-    no --include-overhead needed."""
-    import copy
-    from tools.bench_diff import diff, extract_metrics, load_parsed, main
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    r05 = os.path.join(root, "MULTICHIP_r05.json")
-    r06 = os.path.join(root, "MULTICHIP_r06.json")
-    # r05 is the stub round: a driver record without a parsed summary
-    # must be an explicit failure (exit 2), never a silent "no metrics"
-    with pytest.raises(ValueError):
-        load_parsed(r05)
-    assert main([r05, r06]) == 2
-    old = load_parsed(r06)
-    assert old["metric"] == "multichip_sharded_execution"
-    # identical rounds diff clean
-    assert main([r06, r06]) == 0
-    # a degraded copy: efficiency halved, per-chip throughput halved
-    new = copy.deepcopy(old)
-    new["queries"]["tpch_q3"]["scaling_efficiency"] /= 2
-    new["queries"]["tpch_q3"]["per_chip_rows_per_s"] /= 2
-    reg, _imp, _unch, _, _ = diff(old, new, 0.10)
-    assert {r[0] for r in reg} == {
-        "queries.tpch_q3.scaling_efficiency",
-        "queries.tpch_q3.per_chip_rows_per_s"}
-    # phase walls (r07+ schema): lower-is-better BY DEFAULT for
-    # multichip payloads — a wall growing 50% regresses, one shrinking
-    # improves
-    o7 = {"metric": "multichip_sharded_execution",
-          "queries": {"q": {"per_chip_rows_per_s": 100.0,
-                            "phases_ms": {"staging": 10.0, "launch": 4.0,
-                                          "collective_wait": 20.0,
-                                          "compact": 2.0}}},
-          "collective_phases_ms_total": 36.0}
-    n7 = copy.deepcopy(o7)
-    n7["queries"]["q"]["phases_ms"]["collective_wait"] = 30.0
-    n7["queries"]["q"]["phases_ms"]["compact"] = 1.0
-    reg, imp, _u, _, _ = diff(o7, n7, 0.10)
-    assert [r[0] for r in reg] == [
-        "queries.q.phases_ms.collective_wait"]
-    assert [r[0] for r in imp] == ["queries.q.phases_ms.compact"]
-    # phase walls are NOT gated for non-multichip payloads without the
-    # overhead opt-in
-    plain = {"summary": {"phases_ms": {"staging": 10.0}}}
-    assert extract_metrics(plain) == {}
-    # r06 (per-query collective_ms) vs an r07-schema payload: renamed
-    # keys report as only-old/only-new, never a spurious regression
-    reg, _i, _u, only_old, only_new = diff(old, o7, 0.10)
-    assert not reg
-    assert any(k.endswith(".collective_ms") for k in only_old)
-    assert any(k.endswith(".collective_wait") for k in only_new)
-
-
-def test_bench_diff_fused_dataplane_keys_neutral():
-    """ISSUE 16: the fused-dataplane counters (staging_reuse_hits scales
-    with exchange volume, overlap_segments echoes config) NEVER gate in
-    either direction, while the compact/staging phase walls the fusion
-    targets keep gating lower-is-better against the real r06 round."""
-    import copy
-    from tools.bench_diff import diff, extract_metrics, load_parsed
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    r06 = load_parsed(os.path.join(root, "MULTICHIP_r06.json"))
-
-    def r07(reuse, segs):
-        return {
-            "metric": "multichip_sharded_execution",
-            "queries": {"tpch_q3": {
-                "per_chip_rows_per_s": 100.0,
-                "compact_fused": True,
-                "staging_reuse_hits": reuse,
-                "overlap_segments": segs,
-                "phases_ms": {"staging": 5.0, "launch": 2.0,
-                              "collective_wait": 10.0, "compact": 1.0},
-            }},
-            "staging_reuse_hits_total": reuse,
-        }
-
-    # neutral: never extracted as metrics, so a knob change (overlap off
-    # → on) or a longer round (more reuse hits) can't fake a regression
-    m = extract_metrics(r07(100, 4))
-    assert not any("staging_reuse_hits" in k or "overlap_segments" in k
-                   for k in m)
-    assert "queries.tpch_q3.compact_fused" not in m  # bools never walk
-    reg, _i, _u, _oo, _on = diff(r07(1000, 0), r07(0, 4), 0.10)
-    assert not reg
-    # the walls the fusion burns down still gate lower-is-better within
-    # the r07 era...
-    worse = copy.deepcopy(r07(10, 2))
-    worse["queries"]["tpch_q3"]["phases_ms"]["compact"] = 50.0
-    worse["queries"]["tpch_q3"]["phases_ms"]["staging"] = 20.0
-    reg, _i, _u, _oo, _on = diff(r07(10, 2), worse, 0.10)
-    assert {r[0] for r in reg} == {
-        "queries.tpch_q3.phases_ms.compact",
-        "queries.tpch_q3.phases_ms.staging"}
-    # ...and against the real r06 round (older collective_ms schema — the
-    # r07 phases report only-new) the neutral counters never surface
-    om = extract_metrics(r06)
-    assert any(k.endswith(".collective_ms") for k in om)
-    reg, _i, _u, _oo, only_new = diff(r06, r07(10, 2), 0.10)
-    assert any(k.endswith("phases_ms.compact") for k in only_new)
-    assert not any("staging_reuse_hits" in r[0] or "overlap_segments" in r[0]
-                   for r in reg)
-    assert not any("staging_reuse_hits" in k for k in only_new)
-
-
-def test_bench_diff_serving_keys():
-    """ISSUE 19: the serving stage's SLO keys gate — rows_per_s drops
-    regress (higher-is-better like every throughput key), interactive
-    p95 RISING regresses BY DEFAULT (no --include-overhead; the latency
-    SLO is the point of the stage), and shed_total is neutral in both
-    directions (the shed count tracks timing jitter, not quality)."""
-    from tools.bench_diff import diff, extract_metrics
-
-    def round_(rows_s, p95, sheds):
-        return {"summary": {"serving_n1_rows_per_s": 5000.0,
-                            "serving_n16_rows_per_s": rows_s,
-                            "serving_n16_interactive_p95_ms": p95,
-                            "serving_n16_shed_total": sheds}}
-
-    old = round_(1000.0, 40.0, 2)
-    m = extract_metrics(old)
-    # p95 gated lower-is-better WITHOUT the overhead opt-in; shed_total
-    # never extracted at all
-    assert m["summary.serving_n16_rows_per_s"] == (1000.0, True)
-    assert m["summary.serving_n16_interactive_p95_ms"] == (40.0, False)
-    assert not any("shed_total" in k for k in m)
-    # throughput drop + p95 rise both regress in the default gate
-    reg, _i, _u, _, _ = diff(old, round_(800.0, 80.0, 30), 0.10)
-    assert {r[0] for r in reg} == {
-        "summary.serving_n16_rows_per_s",
-        "summary.serving_n16_interactive_p95_ms"}
-    # p95 falling is an improvement; a shed-count swing alone (either
-    # direction) never surfaces as regression OR improvement
-    reg, imp, _u, _, _ = diff(old, round_(1000.0, 20.0, 0), 0.10)
-    assert not reg
-    assert [r[0] for r in imp] == ["summary.serving_n16_interactive_p95_ms"]
-    reg, imp, _u, _, _ = diff(old, round_(1000.0, 40.0, 500), 0.10)
-    assert not reg and not imp
-
-
-def test_bench_diff_planning_keys():
-    """ISSUE 20: the hot_repeat planning keys gate lower-is-better in
-    EVERY payload (the planning tax the plan cache exists to eliminate),
-    hit/miss volume counters stay neutral, hit_rate gates higher — and
-    against a real pre-plan-cache round the new keys report only-new,
-    never a spurious regression."""
-    import copy
-    from tools.bench_diff import diff, extract_metrics, load_parsed
-
-    def round_(share, wall, warm, hits, misses, rate):
-        return {"summary": {"hot_repeat_planning_share_pct": share,
-                            "hot_repeat_planning_wall_ms": wall,
-                            "hot_repeat_warm_p50_ms": warm,
-                            "hot_repeat_plan_cache_hits": hits,
-                            "hot_repeat_plan_cache_misses": misses,
-                            "hot_repeat_hit_rate": rate}}
-
-    m = extract_metrics(round_(4.0, 12.0, 25.0, 10, 2, 10 / 12))
-    # lower-is-better planning keys gate WITHOUT --include-overhead and
-    # without a multichip payload marker
-    assert m["summary.hot_repeat_planning_share_pct"] == (4.0, False)
-    assert m["summary.hot_repeat_planning_wall_ms"] == (12.0, False)
-    assert m["summary.hot_repeat_warm_p50_ms"] == (25.0, False)
-    assert m["summary.hot_repeat_hit_rate"][1] is True
-    # volume counters scale with how many submissions a round ran — they
-    # must never be extracted as gated metrics
-    assert not any("plan_cache_hits" in k or "plan_cache_misses" in k
-                   for k in m)
-    # planning share doubling + warm p50 doubling regress; a longer round
-    # (more hits AND more misses) alone cannot fail the diff
-    reg, imp, _u, _, _ = diff(round_(4.0, 12.0, 25.0, 10, 2, 10 / 12),
-                              round_(9.0, 30.0, 60.0, 100, 20, 10 / 12),
-                              0.10)
-    assert {r[0] for r in reg} == {"summary.hot_repeat_planning_share_pct",
-                                   "summary.hot_repeat_planning_wall_ms",
-                                   "summary.hot_repeat_warm_p50_ms"}
-    # hit_rate collapsing regresses too (higher-is-better)
-    reg, _i, _u, _, _ = diff(round_(4.0, 12.0, 25.0, 10, 2, 0.9),
-                             round_(4.0, 12.0, 25.0, 10, 2, 0.4), 0.10)
-    assert [r[0] for r in reg] == ["summary.hot_repeat_hit_rate"]
-    # vs a REAL earlier round: planning keys are new — only-new, no
-    # regression, and the old round's metrics all still extract
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    r07 = load_parsed(os.path.join(root, "MULTICHIP_r07.json"))
-    r_new = copy.deepcopy(r07)
-    r_new["hot_repeat_planning_share_pct"] = 3.0
-    r_new["hot_repeat_planning_wall_ms"] = 9.0
-    r_new["hot_repeat_warm_p50_ms"] = 20.0
-    r_new["hot_repeat_plan_cache_hits"] = 22
-    r_new["hot_repeat_hit_rate"] = 22 / 24
-    reg, _i, _u, only_old, only_new = diff(r07, r_new, 0.10)
-    assert not reg and not only_old
-    assert set(only_new) == {"hot_repeat_planning_share_pct",
-                             "hot_repeat_planning_wall_ms",
-                             "hot_repeat_warm_p50_ms",
-                             "hot_repeat_hit_rate"}
 
 
 def test_flight_ring_is_bounded_and_ordered():

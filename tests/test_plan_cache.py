@@ -9,6 +9,7 @@ import threading
 
 import pytest
 
+import benchmarks.tpch as tpch
 import spark_rapids_tpu.functions as F
 from spark_rapids_tpu.memory.cleaner import MemoryCleaner
 from spark_rapids_tpu.memory.hbm import HbmBudget
@@ -364,6 +365,57 @@ def test_concurrent_sessions_race_soak_no_leaks():
 
 
 # ---------------------------------------------------------------------------
+# one planning entry: every way to a physical plan builds the same tree
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def tpch_tables():
+    s = tpch.make_session(tpu=True)
+    return s, tpch.load_tables(s, 400, parts=2)
+
+
+@pytest.mark.parametrize("name", [f"q{i}" for i in range(1, 23)])
+def test_every_planning_entry_builds_the_same_tree(name, tpch_tables):
+    """`plan_query`, the scheduler's planning step (plan cache off, a miss,
+    the hit's re-bound clone) and `DataFrame.explain()` give one physical
+    tree, and it is the tree of the three passes in the order spelled
+    here — the only place outside `plan/overrides.py` that spells it.
+    Planning only: nothing executes."""
+    from spark_rapids_tpu.config import RapidsConf
+    from spark_rapids_tpu.plan.optimizer import optimize_logical
+    from spark_rapids_tpu.plan.overrides import (TpuOverrides, plan_cpu,
+                                                 plan_query)
+    from spark_rapids_tpu.plan.planner import plan_physical
+    from spark_rapids_tpu.serving.plan_cache import build_or_fetch
+    s, tables = tpch_tables
+    df = tpch.QUERIES[name](s, tables)
+    conf = s._rapids_conf()
+    optimized, rules = optimize_logical(df._plan, conf)
+    cpu_plan = plan_physical(optimized, conf)
+    cpu_tree = cpu_plan.tree_string()
+    want = TpuOverrides.apply(cpu_plan, conf).tree_string()
+    assert "Tpu" in want and want != cpu_tree  # the override pass ran
+
+    cpu_plan, _, cpu_rules = plan_cpu(df._plan, conf)
+    assert (cpu_plan.tree_string(), cpu_rules) == (cpu_tree, rules)
+    final, _, got_rules = plan_query(df._plan, conf)
+    assert (final.tree_string(), got_rules) == (want, rules)
+
+    sched = QueryScheduler.get()
+    off = RapidsConf({**s._settings,
+                      "spark.rapids.tpu.plan.cache.enabled": "false"})
+    for status, c in (("off", off), ("miss", conf), ("hit", conf)):
+        plan, got_status, got_rules = build_or_fetch(s, sched, df._plan, c)
+        assert got_status == status
+        assert (plan.tree_string(), got_rules) == (want, rules), status
+
+    explained = df.explain()  # planCache=…, then the tree or, with rules,
+    # the rules and the logical plan before it under a heading
+    _, heading, tree = explained.partition("== Physical Plan ==\n")
+    assert (tree if heading else explained.split("\n", 1)[1]) == want
+
+
+# ---------------------------------------------------------------------------
 # bit-identity across the TPC-H sweep (cached vs fresh)
 # ---------------------------------------------------------------------------
 
@@ -371,12 +423,6 @@ def test_tpch_sweep_cached_bit_identical():
     """q1/q3/q6/q18 + a dictionary-coded string query: the second (cached)
     run of each is bit-identical to the first, and both match a
     cache-off cold plan."""
-    import os
-    import sys
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    import benchmarks.tpch as tpch
     s = tpch.make_session(tpu=True)
     tables = tpch.load_tables(s, 2_000, parts=2)
     queries = {name: tpch.QUERIES[name] for name in

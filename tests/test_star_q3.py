@@ -250,14 +250,12 @@ def test_laps_survive_a_consumer_that_leaves_early():
     """A limit, a cancel or a sibling's error closes an operator's generator
     before its end: the laps taken until then are flushed all the same."""
     from spark_rapids_tpu.execs.base import TaskContext
-    from spark_rapids_tpu.plan.optimizer import optimize_logical
+    from spark_rapids_tpu.plan.overrides import plan_query
     from spark_rapids_tpu.serving.query_context import QueryContext, bind
-    from spark_rapids_tpu.session import TpuOverrides, plan_physical
     s = _session()
     df = _q3(s, _columns(7, 1 << 12), 1 << 12, parts=3)
     conf = s._rapids_conf()
-    final = TpuOverrides.apply(
-        plan_physical(optimize_logical(df._plan, conf)[0], conf), conf)
+    final, _, _ = plan_query(df._plan, conf)
     # the lowest join, CUSTOMER broadcast into filtered ORDERS: no exchange
     # and no other segment below it
     seg = next(n for n in final.collect_nodes() if n.node_desc()

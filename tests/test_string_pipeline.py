@@ -5,8 +5,6 @@ the dictionary-coded group keys (string-keyed agg keeps the ONE-launch
 traced sort phase).
 """
 
-import os
-
 import numpy as np
 import pyarrow as pa
 import pyarrow.parquet as pq
@@ -354,48 +352,3 @@ def test_encode_group_keys_consumes_dict_encoding():
     for i in range(6):
         for j in range(6):
             assert same(hv, i, j) == same(dv, i, j), (i, j)
-
-
-# ---------------------------------------------------------------------------
-# bench_diff: the widened r07 MULTICHIP payload diffs cleanly against r06
-# ---------------------------------------------------------------------------
-
-
-def test_bench_diff_r07_widened_payload():
-    """The r07 summary's new keys (string_collectives, dict_encode_ms*)
-    appear as only-new against the real r06 round — never a spurious
-    regression — and dict_encode_ms gates LOWER-is-better between two
-    r07-era rounds."""
-    from tools.bench_diff import diff, extract_metrics, load_parsed
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    r06 = load_parsed(os.path.join(root, "MULTICHIP_r06.json"))
-
-    def r07(encode_ms):
-        return {
-            "metric": "multichip_sharded_execution",
-            "n_devices": 8,
-            "queries": {"tpch_q1": {
-                "per_chip_rows_per_s": 7000.0,
-                "scaling_efficiency": 0.11,
-                "exchanges": 1, "collective_launches": 1,
-                "string_collectives": 1, "dict_encode_ms": encode_ms,
-                "phases_ms": {"staging": 3.0, "launch": 1.0,
-                              "collective_wait": 5.0, "compact": 20.0},
-            }},
-            "collective_launches_total": 19,
-            "string_collectives_total": 4,
-            "dict_encode_ms_total": encode_ms,
-            "collective_phases_ms_total": 400.0,
-        }
-
-    regressions, _imp, _unch, _only_old, only_new = diff(
-        r06, r07(20.0), threshold=0.10)
-    assert not [r for r in regressions
-                if "dict_encode" in r[0] or "string_collectives" in r[0]]
-    assert any("dict_encode_ms_total" in k for k in only_new)
-    # dict_encode_ms is a lower-is-better gate within the r07 era
-    m = extract_metrics(r07(20.0))
-    assert m["queries.tpch_q1.dict_encode_ms"][1] is False
-    regressions, _imp, _unch, _oo, _on = diff(
-        r07(20.0), r07(40.0), threshold=0.10)
-    assert any("dict_encode" in r[0] for r in regressions)

@@ -138,32 +138,6 @@ def _calls(log, module_suffix):
 
 
 # ---------------------------------------------------------------------------
-# the hand-fused Q1 kernels at the smoke's 2^24 rows
-# ---------------------------------------------------------------------------
-
-def _q1_shapes(n, sharding):
-    from spark_rapids_tpu.kernels.q1 import make_example_batch
-    batch, _ = make_example_batch(8)
-    return jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct((n,), a.dtype, sharding=sharding),
-        batch), jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)
-
-
-def test_q1_pallas_kernel_compiles_for_v5e_at_2_24_rows(one_chip):
-    from spark_rapids_tpu.kernels.q1_pallas import q1_step_pallas
-    batch, cutoff = _q1_shapes(1 << 24, one_chip)
-    compiled = q1_step_pallas.lower(batch, cutoff).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_q1_xla_step_compiles_for_v5e_at_2_24_rows(one_chip):
-    from spark_rapids_tpu.kernels.q1 import q1_step
-    batch, cutoff = _q1_shapes(1 << 24, one_chip)
-    compiled = q1_step.lower(batch, cutoff).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
-
-
-# ---------------------------------------------------------------------------
 # scan + compiled aggregation stage over one row group
 # ---------------------------------------------------------------------------
 

@@ -119,6 +119,33 @@ def _config_constant_names():
     return out, root
 
 
+def _conf_reads(tree, constants):
+    """What one module's CODE does with conf keys: (every key a string
+    mentions, the keys it reads, the config constants it references).
+
+    A key is read where a string literal IS the key — the argument of a
+    conf `get` / `set`, a key of a settings dict, in the dotted spelling or
+    the tests' `spark__rapids__…` keyword one — or where its config
+    constant is referenced. Prose does not read: a docstring, or a message
+    that spells the key among other words, keeps no option alive."""
+    prose = {id(n.value) for n in ast.walk(tree)
+             if isinstance(n, ast.Expr) and isinstance(n.value, ast.Constant)}
+    mentioned, read, consts = set(), set(), set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Constant) and isinstance(node.value, str):
+            mentioned.update(_conf_keys_in_text(node.value))
+            if id(node) not in prose and node.value.startswith("spark"):
+                read.add(node.value.replace("__", "."))
+        elif isinstance(node, ast.keyword) and (node.arg or "").startswith(
+                "spark__"):
+            read.add(node.arg.replace("__", "."))
+        elif isinstance(node, ast.Name) and node.id in constants:
+            consts.add(node.id)
+        elif isinstance(node, ast.Attribute) and node.attr in constants:
+            consts.add(node.attr)
+    return mentioned, read, consts
+
+
 def conf_consistency():
     """Conf-consistency check (the tracelint-adjacent registry contract):
 
@@ -129,9 +156,10 @@ def conf_consistency():
     * every registered key must appear in the regenerated docs/configs.md;
     * every key documented in the configs.md TABLE must be registered (no
       documented-but-dead keys);
-    * every registered tpu/shuffle key must actually be READ somewhere
-      outside config.py — via its config constant or its literal key —
-      in the package, tests, or benchmarks (no declared-but-dead keys).
+    * every registered tpu/shuffle key must actually be READ by code
+      outside config.py (`_conf_reads`) in the package, tests,
+      benchmarks/, chipbench/ or chip_smoke.py (no declared-but-dead
+      keys); a comment or docstring that spells the key reads nothing.
     """
     from spark_rapids_tpu.config import REGISTRY
     registered = set(REGISTRY.entries)
@@ -143,47 +171,37 @@ def conf_consistency():
         key_to_consts.setdefault(key, set()).add(name)
     violations = []
 
-    used_keys = set()
+    read_literals = set()
     used_consts = set()
     repo_root = os.path.dirname(pkg_root)
-    scan_roots = [pkg_root,
-                  os.path.join(repo_root, "tests"),
-                  os.path.join(repo_root, "benchmarks")]
-    for root in scan_roots:
+    paths = [os.path.join(repo_root, "chip_smoke.py")]
+    for root in (pkg_root, *(os.path.join(repo_root, d)
+                             for d in ("tests", "benchmarks", "chipbench"))):
         for dirpath, _dirs, files in os.walk(root):
-            if "__pycache__" in dirpath:
-                continue
-            for fname in files:
-                if not fname.endswith(".py"):
-                    continue
-                path = os.path.join(dirpath, fname)
-                in_pkg = path.startswith(pkg_root)
-                is_config = in_pkg and fname == "config.py" \
-                    and dirpath == pkg_root
-                with open(path) as f:
-                    src = f.read()
-                try:
-                    tree = ast.parse(src)
-                except SyntaxError:
-                    continue
-                rel = os.path.relpath(path, repo_root)
-                for node in ast.walk(tree):
-                    if isinstance(node, ast.Constant) \
-                            and isinstance(node.value, str):
-                        for key in _conf_keys_in_text(node.value):
-                            if not is_config:
-                                used_keys.add(key)
-                            if in_pkg and not is_config \
-                                    and key not in registered \
-                                    and not any(r.startswith(key + ".")
-                                                for r in registered):
-                                violations.append(
-                                    f"conf: {rel} reads undeclared key "
-                                    f"{key!r} — declare it in config.py "
-                                    f"(and regenerate docs/configs.md)")
-                    elif isinstance(node, ast.Name) and not is_config \
-                            and node.id in constants:
-                        used_consts.add(node.id)
+            if "__pycache__" not in dirpath:
+                paths.extend(os.path.join(dirpath, f) for f in files
+                             if f.endswith(".py"))
+    config_path = os.path.join(pkg_root, "config.py")
+    for path in paths:
+        if path == config_path:
+            continue
+        with open(path) as f:
+            src = f.read()
+        try:
+            tree = ast.parse(src)
+        except SyntaxError:
+            continue
+        mentioned, read, consts = _conf_reads(tree, constants)
+        read_literals |= read
+        used_consts |= consts
+        if path.startswith(pkg_root):
+            rel = os.path.relpath(path, repo_root)
+            for key in sorted(mentioned - registered):
+                if not any(r.startswith(key + ".") for r in registered):
+                    violations.append(
+                        f"conf: {rel} reads undeclared key "
+                        f"{key!r} — declare it in config.py "
+                        f"(and regenerate docs/configs.md)")
 
     # registry ↔ docs
     docs_path = os.path.join(repo_root, "docs", "configs.md")
@@ -206,12 +224,13 @@ def conf_consistency():
             f"conf: docs/configs.md documents {key!r} but config.py does "
             f"not declare it (documented-but-dead)")
 
-    # declared-but-dead: no literal use and no constant use anywhere
-    for key in sorted(scoped - used_keys):
+    # declared-but-dead: no code reads the literal or the constant
+    for key in sorted(scoped - read_literals):
         if not (key_to_consts.get(key, set()) & used_consts):
             violations.append(
                 f"conf: key {key!r} is declared in config.py but read "
-                f"nowhere (package, tests, benchmarks) — dead conf")
+                f"nowhere (package, tests, benchmarks, chipbench, "
+                f"chip_smoke.py) — dead conf")
     return violations
 
 
