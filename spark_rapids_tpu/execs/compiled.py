@@ -18,8 +18,11 @@ Eligibility (anything else falls back to the general sort-based aggregate):
     repeated runs stay fully on device;
   * key domains are small (≤ spark.rapids.tpu.agg.compiled.maxGroups after
     combining); integral domains come from per-column min/max stats
-    (memoized on the column), with in-trace out-of-range detection that
-    triggers a transparent re-run on the general path;
+    (memoized on the column), checked batch by batch as the source is
+    pulled, with in-trace out-of-range detection behind them. A stage that
+    gives up on what it reads in its input HANDS what it pulled to the
+    general path (TpuStageSourceExec): its source never produces a batch a
+    second time;
   * aggregates are sum/count/avg/min/max over fixed-width non-decimal,
     non-bool inputs;
   * every filter/project expression is device-pure (its rule is not
@@ -41,6 +44,7 @@ compiled program instead of re-tracing.
 
 from __future__ import annotations
 
+import itertools
 import threading
 from typing import Dict, Iterator, List, Optional, Tuple
 
@@ -688,7 +692,65 @@ def _np_finalize(fn: AggregateFunction, st: Optional[Dict], idx: np.ndarray):
 
 
 class _StageFallback(Exception):
-    """Internal: abandon the compiled path, run the original subtree."""
+    """Internal: abandon the compiled path for a reason read from the
+    stage's own input; the general aggregate takes over what was pulled."""
+
+
+class TpuStageSourceExec(TpuExec):
+    """Stands where a compiled stage's source stood in the stage's FALLBACK
+    subtree, over the same source node as the stage (the rewriting passes'
+    id-memos and `clone_for_execution`'s keep it the same). When the stage
+    gives up it hands this node what pass 1 pulled: a partition it pulled
+    yields the held batches, closing each as it is consumed, and a
+    partition it never started runs the source. So the general aggregate
+    above reads the stage's own source output and no source partition is
+    executed twice. With nothing handed (the memory-pressure rerun) it is
+    the source."""
+
+    def __init__(self, source: PhysicalPlan):
+        super().__init__([source])
+        # {source partition: its batches, held spillable} between a give-up
+        # and the end of the fallback's run; None otherwise (a template
+        # never runs, so a clone starts from None)
+        self._handed: Optional[Dict[int, List]] = None
+
+    @property
+    def output(self):
+        return self.children[0].output
+
+    def node_desc(self) -> str:
+        return "TpuStageSource"
+
+    def hand(self, pulled: Dict[int, List]) -> None:
+        self._handed = pulled
+
+    def release(self) -> None:
+        """Close what the fallback did not consume (a limit above it, an
+        error, a cancel)."""
+        handed, self._handed = self._handed, None
+        for held in (handed or {}).values():
+            _close_all(held)
+
+    def internal_do_execute_columnar(self, idx: int,
+                                     ctx: TaskContext) -> Iterator:
+        # pop: map tasks of an exchange above pull partitions from pool
+        # threads, each partition once
+        held = self._handed.pop(idx, None) if self._handed else None
+        if held is None:
+            yield from self.children[0].execute_partition(idx, ctx)
+            return
+        try:
+            while held:
+                with held.pop(0) as sb:
+                    b = sb.get_batch()
+                yield b
+        finally:
+            _close_all(held)
+
+
+def _close_all(held: List) -> None:
+    while held:
+        held.pop().close()
 
 
 class TpuCompiledAggStageExec(TpuExec):
@@ -698,6 +760,8 @@ class TpuCompiledAggStageExec(TpuExec):
                  max_groups: int):
         super().__init__([spec.source])
         self.spec = spec
+        # the original aggregate, over a TpuStageSourceExec where the
+        # source stood (compile_agg_stages)
         self.fallback = fallback
         self.max_groups = max_groups
 
@@ -711,7 +775,7 @@ class TpuCompiledAggStageExec(TpuExec):
     def collect_nodes(self):
         # the fallback subtree holds the exchanges whose shuffle state the
         # session releases at query end — it MUST stay reachable here, or
-        # every fallback rerun leaks its shuffle blocks in the catalog
+        # every fallback run leaks its shuffle blocks in the catalog
         out = super().collect_nodes()
         seen = {id(n) for n in out}
         out.extend(n for n in self.fallback.collect_nodes()
@@ -724,29 +788,48 @@ class TpuCompiledAggStageExec(TpuExec):
 
     def additional_metrics(self):
         return {"stageTime": "MODERATE", "numGroups": "DEBUG",
-                "fallbackReruns": "DEBUG"}
+                "fallbackHandoffs": "DEBUG", "fallbackReruns": "DEBUG"}
 
     def query_counters(self):
-        return [("stage.fallback_reruns", self.metrics["fallbackReruns"])]
+        return [("stage.fallback_handoffs", self.metrics["fallbackHandoffs"]),
+                ("stage.fallback_reruns", self.metrics["fallbackReruns"])]
+
+    def _fallback_source(self) -> TpuStageSourceExec:
+        # found, not kept: the rewriting passes copy the nodes they change,
+        # and a kept link would be one more to keep in step
+        node = self.fallback
+        while not isinstance(node, TpuStageSourceExec):
+            node = node.children[0]
+        return node
 
     def internal_do_execute_columnar(self, idx: int,
                                      ctx: TaskContext) -> Iterator:
         from ..memory.hbm import TpuRetryOOM, TpuSplitAndRetryOOM
+        handoff = self._fallback_source()
+        result = None
         try:
-            result = self._run_compiled(ctx)
-        except (_StageFallback, TpuRetryOOM, TpuSplitAndRetryOOM):
-            # ineligible at runtime OR memory pressure: the general path has
-            # the full spill/retry/split machinery
-            result = None
-        if result is None:
-            # transparent re-run on the general (sort-based) path
+            result = self._run_compiled(ctx, handoff)
+        except _StageFallback:
+            # decided from what the stage saw of its input: the general
+            # aggregate reads on from where pass 1 stopped
+            self.metrics["fallbackHandoffs"].add(1)
+        except (TpuRetryOOM, TpuSplitAndRetryOOM):
+            # memory pressure: a source iterator that raised cannot be
+            # resumed and what is held is best let go, so the general path,
+            # with the full spill/retry/split machinery, runs the source
+            # again (nothing is handed)
             self.metrics["fallbackReruns"].add(1)
+        if result is not None:
+            yield result
+            return
+        try:
             for p in range(self.fallback.num_partitions()):
                 yield from self.fallback.execute_partition(p, ctx)
-            return
-        yield result
+        finally:
+            handoff.release()
 
-    def _run_compiled(self, ctx: TaskContext) -> TpuColumnarBatch:
+    def _run_compiled(self, ctx: TaskContext,
+                      handoff: TpuStageSourceExec) -> TpuColumnarBatch:
         from ..memory.spill import SpillableColumnarBatch
         spec = self.spec
         # pull through the plan-tree link, NOT the spec's captured source:
@@ -754,72 +837,87 @@ class TpuCompiledAggStageExec(TpuExec):
         # fusion, coalescing) rewrite children[0], and executing the stale
         # spec.source would silently run the pre-fusion operator chain
         src = self.children[0]
-        held: List[SpillableColumnarBatch] = []
+        pulled: Dict[int, List[SpillableColumnarBatch]] = {}
         domains = [_KeyDomain(g.dtype) for g in spec.grouping]
         carries = []
         oob_flags = []
         try:
             # pass 1: collect batches (spillable) + key statistics; stats are
-            # memoized on the column objects so cached relations pay once
+            # memoized on the column objects so cached relations pay once.
+            # The domains only grow, so the batch that takes them over
+            # maxGroups decides: the partition it came in is pulled to its
+            # end without statistics (a blocking sync a key a batch), so
+            # that its iterator and task context close here, and no other
+            # partition is started
             with _obs.phase("stage.collect"):
+                fits = True
                 for p in range(src.num_partitions()):
+                    held = pulled[p] = []
                     pctx = TaskContext(p, ctx.conf)
                     try:
                         for b in src.execute_partition(p, pctx):
-                            if b.num_rows:
-                                self._update_domains(b, domains)
-                                held.append(SpillableColumnarBatch(b))
+                            if fits and not b.num_rows:
+                                continue
+                            held.append(SpillableColumnarBatch(b))
+                            fits = fits and self._grow_domains(b, domains)
                     finally:
                         pctx.complete()
-            G = 1
-            for d in domains:
-                G *= d.size
-            if G > self.max_groups:
-                raise _StageFallback()
+                    if not fits:
+                        raise _StageFallback()
             # pass 2: one fused program per batch shape. Dispatches are
             # async; the ONLY sync is a single device_get of every carry +
             # the oob flags at the end (high-latency links pay one round
             # trip per query, like the hand-fused kernel)
             with self.metrics["stageTime"].timed():
                 laps = _obs.PhaseLaps()  # per batch: clock reads only
-                for sb in held:
-                    with laps.lap("stage.launch"):
-                        b = sb.get_batch()
-                        out = self._run_batch(b, domains, ctx)
-                        oob_flags.append(out[0])
-                        carries.append(out[1:])
-                laps.flush()
+                try:
+                    for sb in itertools.chain.from_iterable(pulled.values()):
+                        with laps.lap("stage.launch"):
+                            b = sb.get_batch()
+                            out = self._run_batch(b, domains, ctx)
+                            oob_flags.append(out[0])
+                            carries.append(out[1:])
+                finally:
+                    laps.flush()
                 from ..columnar.vector import audited_device_get
                 with _obs.phase("stage.fetch", cat="wait"):
                     host = audited_device_get((oob_flags, carries), "stage")
                 oob_np, carries_np = host
                 if oob_np and bool(np.any(np.stack(oob_np))):
                     raise _StageFallback()
+        except _StageFallback:
+            handoff.hand(pulled)
+            pulled = {}
+            raise
         finally:
-            for sb in held:
-                sb.close()
+            for held in pulled.values():
+                _close_all(held)
         with _obs.phase("stage.assemble"):
             return self._assemble(domains, carries_np, ctx)
 
-    def _update_domains(self, b: TpuColumnarBatch,
-                        domains: List[_KeyDomain]) -> None:
+    def _grow_domains(self, b: TpuColumnarBatch,
+                      domains: List[_KeyDomain]) -> bool:
+        """Grow the key domains by one batch. False as soon as their
+        product (a string key's dictionary alone, too) passes maxGroups or
+        a key column is one the stage cannot take: the stage gives up."""
+        G = 1
         for k, o in enumerate(self.spec.key_source_ordinals):
             d = domains[k]
             col = b.columns[o]
             if isinstance(d.dtype, StringType):
                 _string_codes(col, d)  # grows the global dictionary
-                if len(d.values) + 1 > self.max_groups:
-                    raise _StageFallback()
-            elif isinstance(d.dtype, BooleanType):
-                pass
-            else:
+            elif not isinstance(d.dtype, BooleanType):
                 if col.offsets is not None or col.host_data is not None \
                         or col.children is not None:
-                    raise _StageFallback()
+                    return False
                 lo, hi = _int_stats(col)
                 if lo is not None:
                     d.lo = lo if d.lo is None else min(d.lo, lo)
                     d.hi = hi if d.hi is None else max(d.hi, hi)
+            G *= d.size
+            if G > self.max_groups:
+                return False
+        return True
 
     def _run_batch(self, b: TpuColumnarBatch, domains: List[_KeyDomain],
                    ctx: TaskContext):
@@ -947,6 +1045,12 @@ def compile_agg_stages(plan: PhysicalPlan, conf) -> PhysicalPlan:
     def rewrite(node: PhysicalPlan) -> PhysicalPlan:
         spec = try_extract_stage(node)
         if spec is not None:
+            # the fallback is the aggregate itself, re-based: the node that
+            # pulled the source pulls a TpuStageSourceExec over it
+            above = node
+            while above.children[0] is not spec.source:
+                above = above.children[0]
+            above.children = [TpuStageSourceExec(spec.source)]
             return TpuCompiledAggStageExec(spec, node, max_groups)
         node.children = [rewrite(c) for c in node.children]
         return node

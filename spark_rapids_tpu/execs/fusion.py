@@ -914,8 +914,9 @@ def fuse_stage_segments(plan: PhysicalPlan, conf: RapidsConf) -> PhysicalPlan:
     are rewritten too (q3's near-unique group keys trip the agg stage's
     fallback on every run, so the fallback path IS the general path there);
     an id-memo keeps subtrees shared between a stage's children and its
-    fallback pointing at the SAME fused nodes, so exchanges still
-    materialize once."""
+    fallback pointing at the SAME fused nodes: the agg stage's fallback
+    stands over the stage's own source (TpuStageSourceExec, which takes
+    over what the stage pulled), and exchanges materialize once."""
     if not (conf.get(OPJIT_ENABLED) and conf.get(OPJIT_FUSE_STAGES)):
         return plan
     return _fuse(plan, bool(conf.get(OPJIT_FUSE_JOINS)),

@@ -237,3 +237,157 @@ def test_stage_result_feeds_downstream_sort_limit():
     a = [r["k"] for r in q(TpuSession({})).collect()]
     b = [r["k"] for r in q(_cpu()).collect()]
     assert a == b
+
+
+# ---------------------------------------------------------------------------
+# the hand-off: a stage that gives up never makes its source produce a batch
+# a second time (ISSUE 30)
+# ---------------------------------------------------------------------------
+
+_SMALL_BATCHES = {"spark.rapids.sql.batchSizeRows": "1000"}
+_FEW_GROUPS = {"spark.rapids.tpu.agg.compiled.maxGroups": "64"}
+
+
+def _wide_first(n):
+    return pa.table({"k": pa.array(range(0, 997 * n, 997), pa.int64()),
+                     "v": pa.array([float(i % 7) for i in range(n)])})
+
+
+def _wide_last(n):
+    """Ten keys everywhere but in the very last row."""
+    return pa.table({"k": pa.array([i % 10 for i in range(n - 1)] + [10**6],
+                                   pa.int64()),
+                     "v": pa.array([float(i % 7) for i in range(n)])})
+
+
+def _wide_second_of_five(n):
+    """Ten keys but for one row of the second of five partitions."""
+    keys = [i % 10 for i in range(n)]
+    keys[n // 5 + 1] = 10**6
+    return pa.table({"k": pa.array(keys, pa.int64()),
+                     "v": pa.array([float(i % 7) for i in range(n)])})
+
+
+def _dictionary_grows(n):
+    """Three strings in the first third, two hundred from there on."""
+    return pa.table({"k": pa.array([f"s{i % 3}" if i < n // 3
+                                    else f"t{i % 200}" for i in range(n)]),
+                     "v": pa.array([float(i % 7) for i in range(n)])})
+
+
+def _tag_host_data(batch, name="k"):
+    """The batch with column `name` marked as the host-side columns are:
+    the same values, and a layout the stage's program cannot read."""
+    import dataclasses
+    from spark_rapids_tpu.columnar.batch import TpuColumnarBatch
+    i = batch.names.index(name)
+    col = batch.columns[i]
+    cols = list(batch.columns)
+    cols[i] = dataclasses.replace(col, host_data=col.to_arrow(),
+                                  host_capacity=col.capacity)
+    return TpuColumnarBatch(cols, batch.num_rows, batch.names)
+
+
+def _live_spillables():
+    from spark_rapids_tpu.memory.cleaner import MemoryCleaner
+    return [r.kind for r in MemoryCleaner.get().live_resources()
+            if r.kind.startswith("SpillableColumnarBatch")]
+
+
+def _source_rows(session):
+    """Rows the stage's source put out in the last query, from its own
+    metrics: the host→device scans the plan holds (one; one a side under a
+    union)."""
+    scans = [m for op, m in session.last_query_metrics("DEBUG").items()
+             if op.split(":")[1] == "HostToDeviceExec"]
+    assert scans, session.last_query_metrics("DEBUG")
+    return sum(m.get("numOutputRows", 0) for m in scans)
+
+
+@pytest.mark.parametrize("case,make,conf,parts", [
+    ("int_key_over_in_the_first_batch", _wide_first, _SMALL_BATCHES, 3),
+    ("int_key_over_in_the_last_batch_of_the_last_partition", _wide_last,
+     _SMALL_BATCHES, 3),
+    ("string_dictionary_overflows_mid_stream", _dictionary_grows,
+     {**_SMALL_BATCHES, **_FEW_GROUPS}, 3),
+    ("key_column_the_stage_cannot_take", _wide_last, _SMALL_BATCHES, 2),
+    ("several_partitions_one_batch_each", _wide_first, {}, 5),
+    ("an_empty_partition_among_them", _wide_last, {}, 3),
+    # a map task a partition: the exchange's pool threads pull the hand-off,
+    # two partitions of it held and three still to run
+    ("map_tasks_on_pool_threads", _wide_second_of_five,
+     {**_SMALL_BATCHES, "spark.rapids.tpu.dispatch.partitionBatch": "1"}, 5),
+    ("memory_pressure_reruns", _wide_last, _SMALL_BATCHES, 3),
+])
+def test_stage_hands_its_input_to_the_fallback(case, make, conf, parts,
+                                               monkeypatch):
+    from spark_rapids_tpu.execs import compiled as C
+    from spark_rapids_tpu.execs.transitions import HostToDeviceExec
+    n = 6000
+    t = make(n)
+    grew = []
+    real_grow = C.TpuCompiledAggStageExec._grow_domains
+
+    def grow(self, b, domains):
+        grew.append(real_grow(self, b, domains))
+        return grew[-1]
+    monkeypatch.setattr(C.TpuCompiledAggStageExec, "_grow_domains", grow)
+
+    if case == "key_column_the_stage_cannot_take":
+        t = t.slice(0, n - 1)  # ten keys: only the column's layout is at fault
+        real = HostToDeviceExec.internal_do_execute_columnar
+
+        def tagged(self, idx, ctx):
+            for i, b in enumerate(real(self, idx, ctx)):
+                yield _tag_host_data(b) if (idx, i) == (1, 1) else b
+        monkeypatch.setattr(HostToDeviceExec, "internal_do_execute_columnar",
+                            tagged)
+    if case == "memory_pressure_reruns":
+        from spark_rapids_tpu.memory.hbm import TpuRetryOOM
+        t = t.slice(0, n - 1)  # the stage would answer, but for the pressure
+
+        def pressed(self, b, domains, ctx):
+            raise TpuRetryOOM("injected")
+        monkeypatch.setattr(C.TpuCompiledAggStageExec, "_run_batch", pressed)
+
+    def q(s):
+        df = s.createDataFrame(t, num_partitions=parts)
+        if case == "an_empty_partition_among_them":
+            nothing = s.createDataFrame(t.slice(0, 0), num_partitions=1)
+            df = nothing.union(df.union(nothing))
+        return df.groupBy("k").agg(F.sum(F.col("v")).alias("sv"),
+                                   F.count(F.col("v")).alias("c"))
+
+    before = _live_spillables()
+    s = TpuSession(conf)
+    df = q(s)
+    assert _uses_stage(df)
+    got = df.collect()
+    want = q(_cpu()).collect()
+    assert sorted(map(repr, got)) == sorted(map(repr, want))
+    assert _live_spillables() == before
+    counters = s.last_query_phases()["counters"]
+    if case == "memory_pressure_reruns":
+        # pass 1 pulled the whole source, the pressure came in pass 2, and
+        # the general path ran the source again
+        assert (counters["stage.fallback_handoffs"],
+                counters["stage.fallback_reruns"]) == (0, 1)
+        assert _source_rows(s) == 2 * t.num_rows
+        return
+    assert (counters["stage.fallback_handoffs"],
+            counters["stage.fallback_reruns"]) == (1, 0)
+    # every source partition executed once: each row left the scan once
+    assert _source_rows(s) == t.num_rows
+    # statistics stop with the batch that decides
+    assert grew.count(False) == 1 and grew[-1] is False
+    if case == "int_key_over_in_the_first_batch":
+        assert grew == [False]
+    elif case in ("int_key_over_in_the_last_batch_of_the_last_partition",
+                  "an_empty_partition_among_them"):
+        assert len(grew) == (6 if conf else parts), grew
+    elif case == "string_dictionary_overflows_mid_stream":
+        assert 2 < len(grew) < 6, grew
+    elif case == "key_column_the_stage_cannot_take":
+        assert len(grew) == 3 + 2, grew     # partition 0's three, then (1, 1)
+    elif case == "map_tasks_on_pool_threads":
+        assert len(grew) == 2 + 1, grew     # partition 0's two, then (1, 0)

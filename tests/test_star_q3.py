@@ -140,9 +140,10 @@ def test_q3_phases_cover_the_query_and_count_the_joins():
     inside = [n for n in ph if n != "sched.admit_wait"]
     assert sum(_self_ns(ph, n) for n in inside) == ph["query"]["wall_ns"]
     assert all(_self_ns(ph, n) >= 0 for n in inside), ph
-    # the stage collects the whole join, finds l_orderkey's domain too wide
-    # and runs its fallback subtree: both joins run once more
-    assert counters["stage.fallback_reruns"] == 1
+    # the stage finds l_orderkey's domain too wide in the join's first batch
+    # and hands what it pulled to the general aggregate: each join runs once
+    assert counters["stage.fallback_handoffs"] == 1
+    assert counters["stage.fallback_reruns"] == 0
     # a fallback the plan's nodes can take is in the summary, zero or not;
     # the plan holds no compiled join stage, so that one is not
     assert counters["join.subpartitioned"] == counters["agg.sort_fallback"] == 0
@@ -153,14 +154,64 @@ def test_q3_phases_cover_the_query_and_count_the_joins():
     open_order = (orders["o_orderdate"] < q3.DATE) & building[orders["o_custkey"]]
     late = li["l_shipdate"] > q3.DATE
     joined = int(open_order.sum()) + int((late & open_order[li["l_orderkey"]]).sum())
-    assert counters["join.rows_out"] == 2 * joined
-    # what the joins' children put out, the source's run and the fallback's
-    # (no exchange keeps the lower join's rows at this size): a child that
-    # both top joins share is one metric and counts once
+    assert counters["join.rows_out"] == joined
+    # what the two joins' children put out, once
     assert counters["join.rows_left"] == \
-        2 * (int((orders["o_orderdate"] < q3.DATE).sum()) + int(late.sum()))
+        int((orders["o_orderdate"] < q3.DATE).sum()) + int(late.sum())
     assert counters["join.rows_right"] == \
-        2 * (int(building.sum()) + int(open_order.sum()))
+        int(building.sum()) + int(open_order.sum())
+
+
+def test_q3_hand_off_pulls_the_lower_joins_build_side_once(monkeypatch):
+    """At 2^12 rows over 3 partitions the stage's child is the top join's
+    segment and its fallback the aggregate over that same segment
+    (`TpuStageSource`), where the fallback held a top join of its own: one
+    `require_single` coalesce over the lower join's segment, and each of
+    its partitions pulled once a query."""
+    from spark_rapids_tpu.execs.compiled import (TpuCompiledAggStageExec,
+                                                 TpuStageSourceExec)
+    from spark_rapids_tpu.plan.overrides import plan_query
+    rows = 1 << 12
+    cols = _columns(7, rows)
+    s = _session()
+    df = _q3(s, cols, rows, parts=3)
+    final, _, _ = plan_query(df._plan, s._rapids_conf())
+    nodes = final.collect_nodes()
+    stage = next(n for n in nodes if isinstance(n, TpuCompiledAggStageExec))
+    assert stage.children[0].node_desc() == "TpuFusedSegment[BroadcastHashJoin]"
+    assert stage.fallback.node_desc() == \
+        "TpuFusedSegment[Project+Project+HashAggregate]"
+    leaves = [n for n in stage.fallback.collect_nodes()
+              if isinstance(n, TpuStageSourceExec)]
+    assert len(leaves) == 1 and leaves[0].children[0] is stage.children[0]
+    assert "HashJoin" not in stage.fallback.node_desc()
+    # the same holds of the clone a query runs
+    run = next(n for n in final.clone_for_execution().collect_nodes()
+               if isinstance(n, TpuCompiledAggStageExec))
+    assert run is not stage
+    assert run._fallback_source().children[0] is run.children[0]
+
+    # counted where a node is entered, whichever link led there (the
+    # absorbed broadcast join pulls its build through its own child link,
+    # PERF.md section 7): the lower join's segment under the top join's
+    # build side, and each table's scan under it all
+    from spark_rapids_tpu.execs.fusion import TpuFusedSegmentExec
+    from spark_rapids_tpu.execs.transitions import TpuDeviceScanExec
+    lower = "TpuFusedSegment[BroadcastHashJoin+Project]"
+    pulls = []
+    for cls in (TpuFusedSegmentExec, TpuDeviceScanExec):
+        def counted(self, idx, ctx, real=cls.execute_partition):
+            pulls.append((self.node_desc(), idx))
+            return real(self, idx, ctx)
+        monkeypatch.setattr(cls, "execute_partition", counted)
+    _same(df.collect(), _reference(cols, rows))
+    counters = s.last_query_phases()["counters"]
+    assert (counters["stage.fallback_handoffs"],
+            counters["stage.fallback_reruns"]) == (1, 0)
+    assert sorted(i for d, i in pulls if d == lower) == [0, 1, 2], pulls
+    # LINEITEM, the top join's probe side, was read twice a partition
+    scans = sorted((d, i) for d, i in pulls if d.startswith("TpuDeviceScan"))
+    assert len(scans) == 9 and len(set(scans)) == 9, scans
 
 
 def _read_metric(name, n_queries):
