@@ -395,7 +395,7 @@ def run_four_chips(args, platform: str) -> None:
                          f"{len(jax.devices())}")
     rows = args.rows
     extra = {"spark.rapids.sql.batchSizeRows": str(max(rows, 1 << 16))}
-    for q in ("q3", "q18"):
+    for q in args.queries.split(","):
         name = f"tpch_{q}"
 
         def build(s, query=tpch.QUERIES[q]):
@@ -440,6 +440,9 @@ def main() -> int:
     ap.add_argument("--rows", type=int, default=None,
                     help="lineitem rows (default 2^20; 2^16 with --chips 4)")
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
+    ap.add_argument("--queries", default="q3,q18",
+                    help="--chips 4 only: the mesh phase's queries (each "
+                         "compiles its programs for all four chips)")
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="debug the script on a backend that is not a TPU; "
                          "prints no result line and exits 3")

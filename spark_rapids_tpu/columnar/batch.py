@@ -175,7 +175,6 @@ class TpuColumnarBatch:
         `to_device=False` keeps numpy buffers (valid column payloads — jax
         ops upload them implicitly on first use): right for tiny result
         tables that are usually collected straight back to the host."""
-        import jax
         import pyarrow as pa
 
         from .vector import _keep_host
@@ -197,41 +196,9 @@ class TpuColumnarBatch:
                                     list(table.column_names))
 
         # single upload of every numpy buffer across all columns
-        leaves: List[np.ndarray] = []
-
-        def collect(c: TpuColumnVector):
-            for buf in (c.data, c.validity, c.offsets):
-                if isinstance(buf, np.ndarray):
-                    leaves.append(buf)
-            if c.child is not None:
-                collect(c.child)
-            if c.children is not None:
-                for k in c.children:
-                    collect(k)
-
-        for c in cols:
-            collect(c)
-        uploaded = iter(jax.device_put(leaves)) if leaves else iter(())
-
-        def rebuild(c: TpuColumnVector) -> TpuColumnVector:
-            data, validity, offsets = c.data, c.validity, c.offsets
-            if isinstance(data, np.ndarray):
-                data = next(uploaded)
-            if isinstance(validity, np.ndarray):
-                validity = next(uploaded)
-            if isinstance(offsets, np.ndarray):
-                offsets = next(uploaded)
-            child = rebuild(c.child) if c.child is not None else None
-            kids = ([rebuild(k) for k in c.children]
-                    if c.children is not None else None)
-            return TpuColumnVector(c.dtype, data, validity, c.num_rows,
-                                   offsets=offsets, child=child,
-                                   host_data=c.host_data,
-                                   host_capacity=c.host_capacity,
-                                   children=kids)
-
-        cols = [rebuild(c) for c in cols]
-        return TpuColumnarBatch(cols, table.num_rows, list(table.column_names))
+        return batch_to_device(
+            TpuColumnarBatch(cols, table.num_rows, list(table.column_names)),
+            None)
 
     @staticmethod
     def from_pydict(data: Dict[str, Sequence],
@@ -253,6 +220,54 @@ class TpuColumnarBatch:
     def rename(self, names: List[str]) -> "TpuColumnarBatch":
         # rows_lazy: renaming a deferred batch must not force its count
         return TpuColumnarBatch(self.columns, self.rows_lazy, list(names))
+
+
+def batch_to_device(batch: TpuColumnarBatch, device) -> TpuColumnarBatch:
+    """`batch` with every buffer on `device`: ONE device_put of all its
+    arrays — numpy buffers upload, arrays already there are passed through.
+    A device (a mesh session's chip) commits them to it; None is the
+    default device, uncommitted (`from_arrow`'s upload). A deferred row
+    count moves with it; a dictionary encoding, a cache that need not
+    follow, drops."""
+    import dataclasses
+
+    import jax
+    leaves: List = []
+
+    def collect(c: TpuColumnVector) -> None:
+        for buf in (c.data, c.validity, c.offsets):
+            if buf is not None:
+                leaves.append(buf)
+        if c.child is not None:
+            collect(c.child)
+        for k in c.children or ():
+            collect(k)
+
+    for c in batch.columns:
+        collect(c)
+    rows = batch.rows_lazy
+    if not isinstance(rows, (int, np.integer)):
+        leaves.append(rows)
+    placed = iter(jax.device_put(leaves, device)) if leaves else iter(())
+
+    def rebuild(c: TpuColumnVector) -> TpuColumnVector:
+        data, validity, offsets = (
+            None if buf is None else next(placed)
+            for buf in (c.data, c.validity, c.offsets))
+        return dataclasses.replace(
+            c, data=data, validity=validity, offsets=offsets,
+            child=None if c.child is None else rebuild(c.child),
+            children=None if c.children is None
+            else [rebuild(k) for k in c.children],
+            dict_encoding=None)
+
+    cols = [rebuild(c) for c in batch.columns]
+    if not isinstance(rows, (int, np.integer)):
+        rows = next(placed)
+        for c in cols:
+            if not isinstance(c.num_rows, (int, np.integer)):
+                c.num_rows = rows
+    return TpuColumnarBatch(cols, rows, batch.names)
 
 
 def _repad(col: TpuColumnVector, capacity: int) -> TpuColumnVector:

@@ -71,7 +71,16 @@ class TpuMetric:
         # fold outside the lock: one stacked device-side sum (an async
         # dispatch, NOT a blocking sync) frees the parked buffers
         import jax.numpy as jnp
-        folded = jnp.sum(jnp.stack([jnp.asarray(p) for p in pending]))
+        pending = [jnp.asarray(p) for p in pending]
+        if len({frozenset(p.devices()) for p in pending}) > 1:
+            # a mesh session's scalars from several chips cannot stack, and
+            # a fold a chip would compile a program a group size: read them
+            # (one sync a _FOLD_AT deferred batches)
+            from ..columnar.vector import audited_device_get
+            got = audited_device_get(pending, "metric")
+            self.add(sum(int(x) for x in got))
+            return
+        folded = jnp.sum(jnp.stack(pending))
         with self._lock:
             self._pending.append(folded)
 
@@ -116,6 +125,9 @@ class TpuMetric:
         self._lock = threading.Lock()
 
 
+_UNSET = object()
+
+
 class TaskContext:
     """Per-task execution context (partition id, conf, metric sink).
     Reference analogue: Spark TaskContext + GpuTaskMetrics."""
@@ -126,6 +138,17 @@ class TaskContext:
         self.eval_ctx = EvalContext(self.conf, partition_id=partition_id)
         self.task_metrics: Dict[str, int] = {}
         self._completion_listeners = []
+        self._chips = _UNSET
+
+    @property
+    def chips(self) -> Optional[tuple]:
+        """The session's chips in mesh order, None outside a mesh session
+        (parallel/mesh.py "Placement"): partition `p` belongs to chip
+        `p % len(chips)`."""
+        if self._chips is _UNSET:
+            from ..parallel.mesh import session_chips
+            self._chips = session_chips(self.conf)
+        return self._chips
 
     def add_completion_listener(self, cb) -> None:
         """Register a callback run at task end (reference ScalableTaskCompletion)."""
@@ -259,6 +282,43 @@ class PhysicalPlan:
     def additional_metrics(self) -> Dict[str, str]:
         return {}
 
+    def mesh_metric(self, name: str) -> TpuMetric:
+        """A metric only a mesh session feeds, made at first use (so that
+        a one-chip query's summary carries none): `meshRowsMoved`,
+        `meshBytesMoved`, `meshReplicatedBytes`, `chipRows.<r>`, ..."""
+        m = self.metrics.get(name)
+        if m is None:
+            m = self.metrics.setdefault(name, TpuMetric(name, DEBUG))
+        return m
+
+    def move_to_chip(self, batch, chip):
+        """`batch` committed to `chip`, counted as rows and bytes that
+        changed chip (`mesh.rows_moved`, `mesh.bytes_moved`)."""
+        from ..columnar.batch import batch_to_device
+        self.mesh_metric("meshRowsMoved").add_lazy(batch.rows_lazy)
+        self.mesh_metric("meshBytesMoved").add(batch.device_memory_size())
+        return batch_to_device(batch, chip)
+
+    def mesh_counters(self) -> List[Tuple[str, TpuMetric]]:
+        """The `mesh.*` counters of its query's summary this node fed
+        (docs/observability.md "Mesh counters"), beside `query_counters`:
+        rows and bytes that changed chip when a task pulled a partition of
+        another chip through this node, bytes it held on more than one
+        chip, and what a subclass adds."""
+        return [(counter, self.metrics[key]) for key, counter in (
+            ("meshRowsMoved", "mesh.rows_moved"),
+            ("meshBytesMoved", "mesh.bytes_moved"),
+            ("meshReplicatedBytes", "mesh.replicated_bytes"))
+            if key in self.metrics]
+
+    def chip_rows_counters(self) -> List[Tuple[str, TpuMetric]]:
+        """`mesh.task_rows.chip<r>`: the rows this node put out on chip r —
+        named by the nodes whose input is a partition task's intake (a
+        scan its own, a join its probe side's)."""
+        return [("mesh.task_rows.chip" + key.split(".", 1)[1], m)
+                for key, m in list(self.metrics.items())
+                if key.startswith("chipRows.")]
+
     def query_counters(self) -> List[Tuple[str, TpuMetric]]:
         """The counters of its query's summary this node feeds
         (docs/observability.md "Span model"), each with the metric that
@@ -360,6 +420,7 @@ class PhysicalPlan:
         if "_broadcast_done" in d:       # broadcast build-side memo
             d["_broadcast_done"] = False
             d["_broadcast_batch"] = None
+            d["_broadcast_on"] = {}
         if "_values" in d:               # subquery value memo
             d["_values"] = None
         if "_dims_built" in d:           # compiled-join dim-side memo
@@ -435,6 +496,9 @@ class TpuExec(PhysicalPlan):
         keep_last = bool(dump)
         self._last_batch = None  # don't attribute a prior partition's batch
         it = self.internal_do_execute_columnar(idx, ctx)
+        chips = ctx.chips
+        if chips is not None:
+            it = self._placed(it, chips, idx)
         # the query tracer (obs) rides the same slow path as xprof tracing:
         # the untraced hot loop below stays free of per-batch span setup.
         # thread_traced: tracing is per-query now — a query that is NOT
@@ -483,6 +547,26 @@ class TpuExec(PhysicalPlan):
             if keep_last:
                 self._last_batch = batch
             yield batch
+
+    def _placed(self, it: Iterator, chips: tuple, idx: int) -> Iterator:
+        """Mesh session: partition `idx` is computed on chip `idx % n`.
+        A task that runs there already (`run_chip_tasks`) pulls straight
+        through. A task of ANOTHER chip that pulls this partition — an
+        operator that collects all its child's partitions: a broadcast
+        build, a top-N, a stage — gets it computed where it lives and the
+        batches moved to its own chip: rows and bytes that changed chip."""
+        from ..parallel.mesh import chip_iter, current_chip
+        r = idx % len(chips)
+        rows = self.mesh_metric(f"chipRows.{r}")
+        here = current_chip()
+        if here is None or here == chips[r]:
+            for batch in it:
+                rows.add_lazy(batch.rows_lazy)
+                yield batch
+            return
+        for batch in chip_iter(it, chips[r]):
+            rows.add_lazy(batch.rows_lazy)
+            yield self.move_to_chip(batch, here)
 
     def _dump_on_failure(self, ctx: TaskContext) -> None:
         """Dump the operator's last good output batch for offline repro when

@@ -38,6 +38,8 @@ class TpuBroadcastHashJoinExec(TpuShuffledHashJoinExec):
         self._broadcast_lock = threading.Lock()
         self._broadcast_batch: Optional[TpuColumnarBatch] = None
         self._broadcast_done = False
+        #: mesh session: the build's copy on each chip that probes it
+        self._broadcast_on: dict = {}
 
     def node_desc(self) -> str:
         return f"TpuBroadcastHashJoin[{self.join_type}]"
@@ -48,7 +50,13 @@ class TpuBroadcastHashJoinExec(TpuShuffledHashJoinExec):
     def _build_side(self, ctx: TaskContext) -> Optional[TpuColumnarBatch]:
         with self._broadcast_lock:
             if not self._broadcast_done:
-                with _obs.phase("join.collect"):
+                # mesh session: collected on the mesh's first chip whichever
+                # chip's task asks first, so that no program's chip hangs on
+                # the order the chips arrive in (a new chip is a compile)
+                from ..parallel.mesh import on_chip
+                chips = ctx.chips
+                with _obs.phase("join.collect"), \
+                        on_chip(chips[0] if chips else None):
                     batches = []
                     child = self.children[1]
                     for p in range(child.num_partitions()):
@@ -56,7 +64,36 @@ class TpuBroadcastHashJoinExec(TpuShuffledHashJoinExec):
                     self._broadcast_batch = concat_batches(batches) \
                         if batches else None
                 self._broadcast_done = True
-            return self._broadcast_batch
+            return self._on_probing_chip(self._broadcast_batch)
+
+    def _on_probing_chip(self, build: Optional[TpuColumnarBatch]):
+        """Mesh session: every partition needs the build, so it is the one
+        operand replicated — collected on the mesh's first chip, a copy on
+        each other chip that probes, made once (under the broadcast lock)
+        and counted as
+        `mesh.broadcast_bytes`, apart from `mesh.replicated_bytes`."""
+        from ..parallel.mesh import current_chip
+        here = current_chip()
+        if build is None or here is None:
+            return build
+        got = self._broadcast_on.get(here)
+        if got is None:
+            if not build.columns or here in build.columns[0].data.devices():
+                got = build
+            else:
+                from ..columnar.batch import batch_to_device
+                got = batch_to_device(build, here)
+                self.mesh_metric("meshBroadcastBytes").add(
+                    got.device_memory_size())
+            self._broadcast_on[here] = got
+        return got
+
+    def mesh_counters(self):
+        out = super().mesh_counters()
+        if "meshBroadcastBytes" in self.metrics:
+            out.append(("mesh.broadcast_bytes",
+                        self.metrics["meshBroadcastBytes"]))
+        return out
 
     def internal_do_execute_columnar(self, idx: int, ctx: TaskContext) -> Iterator:
         right = self._build_side(ctx)

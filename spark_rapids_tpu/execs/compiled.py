@@ -44,7 +44,6 @@ compiled program instead of re-tracing.
 
 from __future__ import annotations
 
-import itertools
 import threading
 from typing import Dict, Iterator, List, Optional, Tuple
 
@@ -831,6 +830,7 @@ class TpuCompiledAggStageExec(TpuExec):
     def _run_compiled(self, ctx: TaskContext,
                       handoff: TpuStageSourceExec) -> TpuColumnarBatch:
         from ..memory.spill import SpillableColumnarBatch
+        from ..parallel.mesh import on_chip, run_chip_tasks
         spec = self.spec
         # pull through the plan-tree link, NOT the spec's captured source:
         # passes that run after stage compilation (whole-stage segment
@@ -848,20 +848,46 @@ class TpuCompiledAggStageExec(TpuExec):
             # maxGroups decides: the partition it came in is pulled to its
             # end without statistics (a blocking sync a key a batch), so
             # that its iterator and task context close here, and no other
-            # partition is started
+            # partition is started.
+            # In a mesh session a round is a partition a chip, pulled at
+            # once (`run_chip_tasks`); the statistics then follow in
+            # partition order on this thread, so which batch decides — and
+            # on which chip its statistics' programs run — does not hang on
+            # which chip answered first. A round that a give-up ends is
+            # handed over whole.
             with _obs.phase("stage.collect"):
-                fits = True
-                for p in range(src.num_partitions()):
-                    held = pulled[p] = []
+                n_parts = src.num_partitions()
+                chips = ctx.chips
+                step = len(chips) if chips else 1
+                if chips:
+                    from ..shuffle.exchange import materialize_exchanges
+                    materialize_exchanges(src, ctx)
+
+                def pull(p: int) -> None:
+                    held = pulled[p] = []    # closed below whatever ends it
                     pctx = TaskContext(p, ctx.conf)
                     try:
                         for b in src.execute_partition(p, pctx):
-                            if fits and not b.num_rows:
-                                continue
                             held.append(SpillableColumnarBatch(b))
-                            fits = fits and self._grow_domains(b, domains)
                     finally:
                         pctx.complete()
+
+                fits = True
+                for lo in range(0, n_parts, step):
+                    ids = range(lo, min(lo + step, n_parts))
+                    run_chip_tasks(ctx.conf, ids, pull)
+                    for p in ids:
+                        empty = []
+                        with on_chip(chips[p % step] if chips else None):
+                            for sb in pulled[p]:
+                                b = sb.get_batch()
+                                if fits and not b.num_rows:
+                                    empty.append(sb)
+                                    continue
+                                fits = fits and self._grow_domains(b, domains)
+                        pulled[p] = [sb for sb in pulled[p]
+                                     if sb not in empty]
+                        _close_all(empty)
                     if not fits:
                         raise _StageFallback()
             # pass 2: one fused program per batch shape. Dispatches are
@@ -871,12 +897,16 @@ class TpuCompiledAggStageExec(TpuExec):
             with self.metrics["stageTime"].timed():
                 laps = _obs.PhaseLaps()  # per batch: clock reads only
                 try:
-                    for sb in itertools.chain.from_iterable(pulled.values()):
-                        with laps.lap("stage.launch"):
-                            b = sb.get_batch()
-                            out = self._run_batch(b, domains, ctx)
-                            oob_flags.append(out[0])
-                            carries.append(out[1:])
+                    for p in sorted(pulled):
+                        # a mesh session's batch is launched where it is
+                        chip = None if ctx.chips is None \
+                            else ctx.chips[p % len(ctx.chips)]
+                        for sb in pulled[p]:
+                            with laps.lap("stage.launch"), on_chip(chip):
+                                b = sb.get_batch()
+                                out = self._run_batch(b, domains, ctx)
+                                oob_flags.append(out[0])
+                                carries.append(out[1:])
                 finally:
                     laps.flush()
                 from ..columnar.vector import audited_device_get

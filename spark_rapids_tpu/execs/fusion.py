@@ -222,6 +222,16 @@ class TpuFusedSegmentExec(TpuExec):
                      join.metrics["subPartitionedJoins"])]
         return out
 
+    def mesh_counters(self):
+        # the probe side a chip's task took in, beside the node's own
+        # and an absorbed broadcast join's copies of its build
+        out = super().mesh_counters() \
+            + [c for op in self._ops for c in op.mesh_counters()
+               if c[0] == "mesh.broadcast_bytes"]
+        if self._has_join:
+            out += self.children[0].chip_rows_counters()
+        return out
+
     # --- execution --------------------------------------------------------
     def _input_partitions(self, idx: int):
         if self._collapses:

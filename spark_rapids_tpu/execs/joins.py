@@ -251,6 +251,10 @@ class TpuShuffledHashJoinExec(TpuExec):
                 ("join.rows_out", rows[2]),
                 ("join.subpartitioned", self.metrics["subPartitionedJoins"])]
 
+    def mesh_counters(self):
+        # the probe side a chip's task took in, beside the node's own
+        return super().mesh_counters() + self.children[0].chip_rows_counters()
+
     def _collect_side(self, child: PhysicalPlan, ctx, idx: int) -> Optional[TpuColumnarBatch]:
         """Pull one input whole and concatenate it: phase `join.collect`."""
         with _obs.phase("join.collect"):

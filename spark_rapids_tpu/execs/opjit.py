@@ -66,6 +66,9 @@ _CACHE: "OrderedDict[Tuple, Any]" = OrderedDict()
 #: doomed trace attempt per batch (own generous FIFO bound).
 _EAGER_PINS: "OrderedDict[Tuple, None]" = OrderedDict()
 _EAGER_PIN_MAX = 4096
+#: programs on their first dispatch, not yet entries: threads that miss the
+#: same key at once take the one jit object from here
+_BUILDING: Dict[Tuple, Any] = {}
 _FAILED = object()  # call outcome: run the eager fallback
 
 #: process-wide counters (per-exec metrics mirror them)
@@ -109,6 +112,7 @@ def cache_len() -> int:
 def clear_cache() -> None:
     with _LOCK:
         _CACHE.clear()
+        _BUILDING.clear()
         _EAGER_PINS.clear()
 
 
@@ -191,7 +195,14 @@ def _cached_call(key: Tuple, build, args: Tuple, eval_ctx, metrics,
         _KIND_CALLS[key[0]] = _KIND_CALLS.get(key[0], 0) + 1
     if _obs._ACTIVE:
         _obs.dispatch_event(key[0], cache="miss", source="opjit")
-    fn = jax.jit(build(), donate_argnums=donate_argnums)
+    # threads that miss one key at once (a mesh session's chips, each with
+    # operands on its own chip) share ONE jit object: each compiles it for
+    # its chip now, and the next query finds all of them behind the entry
+    with _LOCK:
+        fn = _BUILDING.get(key)
+        if fn is None:
+            fn = _BUILDING[key] = jax.jit(build(),
+                                          donate_argnums=donate_argnums)
     t0 = time.perf_counter_ns()
     try:
         out = _dispatch(fn, args, eval_ctx, key[0],
@@ -199,15 +210,21 @@ def _cached_call(key: Tuple, build, args: Tuple, eval_ctx, metrics,
     except _TRACE_FAILURES:
         # not traceable (host sync / host-assisted / ANSI check): pin eager
         with _LOCK:
+            _BUILDING.pop(key, None)
             _EAGER_PINS[key] = None
             while len(_EAGER_PINS) > _EAGER_PIN_MAX:
                 _EAGER_PINS.popitem(last=False)
         return _FAILED
+    except BaseException:
+        with _LOCK:
+            _BUILDING.pop(key, None)
+        raise
     dt = time.perf_counter_ns() - t0
     _note(metrics, "opJitTraceTime", dt)
     with _LOCK:
         _STATS["traces"] += 1
         _STATS["trace_time_ns"] += dt
+        _BUILDING.pop(key, None)
         _CACHE[key] = fn
         _evict(eval_ctx)
     return out

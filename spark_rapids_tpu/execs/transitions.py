@@ -73,10 +73,11 @@ class CpuDeviceScanExec(CpuExec):
         return max(1, len(self.batches))
 
     def node_desc(self) -> str:
-        return f"CpuDeviceScan[{len(self.batches)} batches]"
+        held = sum(b is not None for b in self.batches)
+        return f"CpuDeviceScan[{held} batches]"
 
     def execute_partition(self, idx: int, ctx: TaskContext) -> Iterator:
-        if idx < len(self.batches):
+        if idx < len(self.batches) and self.batches[idx] is not None:
             yield self.batches[idx].to_arrow()
 
 
@@ -98,13 +99,22 @@ class TpuDeviceScanExec(TpuExec):
         return max(1, len(self.batches))
 
     def node_desc(self) -> str:
-        rows = sum(b.num_rows for b in self.batches)
-        return f"TpuDeviceScan[{len(self.batches)} batches, {rows} rows]"
+        held = [b for b in self.batches if b is not None]
+        rows = sum(b.num_rows for b in held)
+        return f"TpuDeviceScan[{len(held)} batches, {rows} rows]"
+
+    def mesh_counters(self):
+        return super().mesh_counters() + self.chip_rows_counters()
 
     def internal_do_execute_columnar(self, idx: int, ctx: TaskContext) -> Iterator:
         names = [a.name for a in self._output]
-        if idx < len(self.batches):
-            yield self.batches[idx].rename(names)
+        if idx < len(self.batches) and self.batches[idx] is not None:
+            batch = self.batches[idx]
+            if ctx.chips is not None:
+                from ..parallel.mesh import replicated_bytes
+                self.mesh_metric("meshReplicatedBytes").add(
+                    replicated_bytes(batch))
+            yield batch.rename(names)
 
 
 class DeviceToHostExec(CpuExec):

@@ -150,17 +150,43 @@ def _invalidate_cached_plans_for(relation) -> None:
         inst.plan_cache.invalidate_relation(id(relation))
 
 
+def shard_over_chips(table, chips, batch_rows: int) -> List:
+    """A mesh session's cached relation: chip r holds rows
+    [r * q, (r + 1) * q) of `table`, q = ceil(rows / n), in batches of at
+    most `batch_rows`, each committed to that chip alone. The list is
+    laid out for the scan, one partition an entry: entry k * n + r is chip
+    r's k-th batch (so partition p is on chip p % n), None where a chip
+    has fewer batches than the fullest. Read chip by chip in partition
+    order the rows come back in the table's order."""
+    from ..columnar.batch import TpuColumnarBatch, batch_to_device
+    n = len(chips)
+    q = -(-table.num_rows // n)
+    per_chip = []
+    for r, chip in enumerate(chips):
+        lo, hi = min(r * q, table.num_rows), min((r + 1) * q, table.num_rows)
+        per_chip.append([
+            batch_to_device(TpuColumnarBatch.from_arrow(
+                table.slice(start, min(batch_rows, hi - start)),
+                to_device=False), chip)
+            for start in range(lo, hi, batch_rows)])
+    depth = max(len(bs) for bs in per_chip)
+    return [per_chip[r][k] if k < len(per_chip[r]) else None
+            for k in range(depth) for r in range(n)]
+
+
 class DeviceCachedRelation(LogicalPlan):
     """Device-resident cache: the materialized result is held as
     TpuColumnarBatch partitions in HBM (reference GpuInMemoryTableScanExec
     over the cache serializer). Repeated queries skip the host→device upload
     AND keep per-column memoized stats (group-by dictionaries/ranges), which
-    is what lets the compiled aggregation stage hit its compile cache."""
+    is what lets the compiled aggregation stage hit its compile cache.
+    One scan partition an entry of `batches`; an entry is None where a mesh
+    session's layout has no batch (`shard_over_chips`)."""
 
     def __init__(self, batches: List, output):
         self._batches = list(batches)
         self._output = list(output)
-        self.num_rows = sum(b.num_rows for b in batches)
+        self.num_rows = sum(b.num_rows for b in batches if b is not None)
 
     @property
     def output(self) -> List[AttributeReference]:
@@ -170,5 +196,5 @@ class DeviceCachedRelation(LogicalPlan):
         return self._batches
 
     def node_desc(self) -> str:
-        return (f"DeviceCachedRelation[{self.num_rows} rows, "
-                f"{len(self._batches)} batches]")
+        held = sum(b is not None for b in self._batches)
+        return f"DeviceCachedRelation[{self.num_rows} rows, {held} batches]"

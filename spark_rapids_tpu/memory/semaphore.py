@@ -24,6 +24,11 @@ class TpuSemaphore:
         self.permits = permits
         self._sem = threading.BoundedSemaphore(permits)
         self._holders: Dict[int, int] = {}  # task id -> acquire depth
+        #: mesh session: the permits are a chip's (the reference's semaphore
+        #: is its executor's GPU's) — a task placed on a chip
+        #: (parallel/mesh.py::on_chip) draws from that chip's own
+        self._chip_sems: Dict[object, threading.BoundedSemaphore] = {}
+        self._held_from: Dict[int, threading.BoundedSemaphore] = {}
         self._shared: set = set()  # task ids riding another task's permit
         self._state_lock = threading.Lock()
         self.total_waits_ns = 0
@@ -57,8 +62,9 @@ class TpuSemaphore:
             if tid in self._holders:
                 self._holders[tid] += 1
                 return
+        sem = self._sem_here()
         t0 = time.perf_counter_ns()
-        self._sem.acquire()
+        sem.acquire()
         waited = time.perf_counter_ns() - t0
         from ..obs import metrics as _metrics
         from ..obs import tracer as _obs
@@ -72,10 +78,23 @@ class TpuSemaphore:
             self.total_waits_ns += waited
             if tid in self._holders:  # lost the first-acquire race
                 self._holders[tid] += 1
-                self._sem.release()
+                sem.release()
                 return
             self._holders[tid] = 1
+            self._held_from[tid] = sem
         ctx.add_completion_listener(lambda: self.release_if_necessary(ctx))
+
+    def _sem_here(self) -> threading.BoundedSemaphore:
+        from ..parallel.mesh import current_chip
+        chip = current_chip()
+        if chip is None:
+            return self._sem
+        with self._state_lock:
+            sem = self._chip_sems.get(chip)
+            if sem is None:
+                sem = self._chip_sems[chip] = threading.BoundedSemaphore(
+                    self.permits)
+            return sem
 
     def adopt(self, parent_ctx, child_ctx) -> None:
         """Batched multi-partition dispatch (spark.rapids.tpu.dispatch.
@@ -105,4 +124,5 @@ class TpuSemaphore:
             if tid not in self._holders:
                 return
             del self._holders[tid]
-        self._sem.release()
+            sem = self._held_from.pop(tid, self._sem)
+        sem.release()

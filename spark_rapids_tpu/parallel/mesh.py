@@ -28,16 +28,23 @@ Static-shape strategy (XLA cannot size buffers data-dependently):
      received slot (src s, pos p) scatters straight to its final row
      `bases[s] + p` (`bases` = exclusive cumsum of this shard's receive
      counts), reproducing bit-for-bit the (src asc, stable) order the old
-     host-side compact produced, with zero host round-trips. The per-reduce
-     output blocks leave the program replicated, so downstream consumers
-     mix blocks freely.
+     host-side compact produced, with zero host round-trips. Reduce block
+     `r` leaves the program as chip `r`'s shard of a sharded output and
+     stays there: nothing is gathered, and the partition task that reads
+     it runs on that chip (`run_chip_tasks`).
 
-Staging is donation-friendly: the concatenated global inputs are DONATED
-to the exchange program (`donate_argnums`, gated off on the CPU backend
-exactly like execs/opjit._donate) so XLA reuses their HBM for the outputs,
-and constant pad pieces (empty-shard columns, destination fills) come from
-a small process-wide staging pool keyed by (kind, capacity, dtype, fill) —
-`mesh.staging_reuse_hits` counts the copies that no longer happen.
+Placement (docs/distributed.md "Placement and the task model"): in a mesh
+session partition `p` belongs to chip `p % n` — its cached batches live
+there (`DataFrame.device_cache`), its task runs there on the chip's worker
+thread under `jax.default_device`, and the collective's global inputs are
+assembled from the per-chip arrays where they already are
+(`jax.make_array_from_single_device_arrays`: no concatenation on chip 0,
+no re-sharding). Only the fresh destination ids are DONATED to the
+exchange program: the column arrays may be a cached relation's own
+buffers. Constant pad pieces (empty-shard columns, destination fills) come
+from a small process-wide staging pool keyed by (kind, capacity, dtype,
+fill, chip) — `mesh.staging_reuse_hits` counts the copies that no longer
+happen.
 
 Exchange/compute overlap (`spark.rapids.tpu.exchange.overlap.*`, default
 OFF — correctness first): the payload splits into K segments along the
@@ -61,9 +68,11 @@ per-chip send-row breakdown and the stage/launch/wait timing split
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
-from typing import Dict, Iterable, List, NamedTuple, Optional, Sequence, Tuple
+from typing import (Callable, Dict, Iterable, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -124,6 +133,134 @@ def mesh_session_active(conf) -> Optional[Mesh]:
     return MeshContext.get(conf)
 
 
+def session_chips(conf) -> Optional[Tuple]:
+    """The chips of this session's mesh in mesh order (chip `r` owns
+    partitions `p % n == r`), or None outside a mesh session."""
+    mesh = mesh_session_active(conf)
+    return None if mesh is None else tuple(mesh.devices.flat)
+
+
+class _Placement(threading.local):
+    """The chip this thread's work is placed on (None: not placed)."""
+    chip = None
+
+
+_placement = _Placement()
+
+
+def current_chip():
+    """The chip the calling thread computes on inside `on_chip`, else None."""
+    return _placement.chip
+
+
+@contextlib.contextmanager
+def on_chip(device):
+    """The calling thread's work is placed on `device` for the scope:
+    fresh arrays and programs over uncommitted operands land there
+    (`jax.default_device`, thread-local) and `current_chip()` says so — a
+    pull of another chip's partition inside the scope is computed there and
+    moved here (`TpuExec._placed`). A no-op for None."""
+    if device is None:
+        yield
+        return
+    prev = _placement.chip
+    _placement.chip = device
+    try:
+        with jax.default_device(device):
+            yield
+    finally:
+        _placement.chip = prev
+
+
+def chip_iter(it, device):
+    """Drive iterator `it` with every `next` under `on_chip(device)`: the
+    placement never leaks into the consumer between yields."""
+    while True:
+        with on_chip(device):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item
+
+
+def replicated_bytes(batch: TpuColumnarBatch) -> int:
+    """Bytes of `batch` held on more than one chip: each fixed-width or
+    offsets buffer's size times the chips it is on beyond the first
+    (sharding metadata, no sync). 0 for a batch on one chip alone."""
+    total = 0
+    for c in batch.columns:
+        for buf in (c.data, c.validity, c.offsets):
+            if isinstance(buf, jax.Array):
+                total += int(buf.nbytes) * (len(buf.devices()) - 1)
+    return total
+
+
+def run_chip_tasks(conf, ids: Sequence[int], task: Callable[[int], object],
+                   ) -> Dict[int, object]:
+    """`{i: task(i)}` for the partitions `ids`. In a mesh session partition
+    `i`'s task runs on the worker thread of chip `i % n` under
+    `on_chip(chip)` — one task slot a chip (the reference's executor
+    model), the chips at once; a chip's partitions run in id order.
+    Outside one, or when a single chip has all the work, the tasks run in
+    order on the calling thread.
+
+    Why threads and not one SPMD program per stage: a partition's work is
+    driven by host decisions on its own data (a join's pair count sizes its
+    output, a batch's capacity picks its program), so four partitions do
+    not share shapes, and each chip's programs are the one-chip session's
+    own, launched on operands committed to that chip.
+
+    A worker binds the caller's query (cancellation, the phase table, HBM
+    charges), its tracer span and its sync-ledger scope. The first failure
+    stops the chips' remaining partitions and is raised once every worker
+    has ended."""
+    ids = list(ids)
+    chips = session_chips(conf)
+    if chips is None:
+        return {i: task(i) for i in ids}
+    n = len(chips)
+    by_chip: Dict[int, List[int]] = {}
+    for i in ids:
+        by_chip.setdefault(i % n, []).append(i)
+    if len(by_chip) <= 1:
+        out = {}
+        for i in ids:
+            with on_chip(chips[i % n]):
+                out[i] = task(i)
+        return out
+    from .. import profiling
+    from ..serving import query_context as qlc
+    qctx = qlc.current()
+    parent = obs.current_span()
+    scope = profiling.current_sync_scope()
+    results: Dict[int, object] = {}
+    errors: List[BaseException] = []
+    stop = threading.Event()
+
+    def work(r: int) -> None:
+        try:
+            with qlc.bind(qctx), obs.inherit(parent), \
+                    profiling.sync_scope(scope), on_chip(chips[r]):
+                for i in by_chip[r]:
+                    if stop.is_set():
+                        return
+                    results[i] = task(i)
+        except BaseException as e:  # noqa: BLE001 — re-raised by the caller
+            stop.set()
+            errors.append(e)
+
+    threads = [threading.Thread(target=work, args=(r,), name=f"chip-{r}")
+               for r in sorted(by_chip)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    if errors:
+        raise errors[0]
+    return results
+
+
 def collective_payload(output, conf) -> Optional[str]:
     """Payload classification for the collective data plane (shared by the
     planner's exchange selection and the runtime eligibility check):
@@ -169,14 +306,18 @@ _STAGING_POOL: Dict[Tuple, jax.Array] = {}
 _STAGING_POOL_MAX = 256
 
 
-def _pooled_fill(kind: str, cap: int, dtype, fill) -> Tuple[jax.Array, int]:
-    """A pooled constant array (cap,) of `fill`; returns (array, hit)."""
-    key = (kind, int(cap), str(jnp.dtype(dtype)), fill)
+def _pooled_fill(kind: str, cap: int, dtype, fill,
+                 device=None) -> Tuple[jax.Array, int]:
+    """A pooled constant array (cap,) of `fill` on `device` (committed
+    there; None: the default device); returns (array, hit)."""
+    key = (kind, int(cap), str(jnp.dtype(dtype)), fill, device)
     with _POOL_LOCK:
         arr = _STAGING_POOL.get(key)
     if arr is not None:
         return arr, 1
     arr = jnp.full((cap,), fill, dtype)
+    if device is not None:
+        arr = jax.device_put(arr, device)
     with _POOL_LOCK:
         if len(_STAGING_POOL) < _STAGING_POOL_MAX:
             _STAGING_POOL[key] = arr
@@ -189,13 +330,15 @@ def reset_staging_pool() -> None:
 
 
 def _donate(positions: Iterable[int]) -> Tuple[int, ...]:
-    """Buffer-donation argnums for the staged collective inputs: XLA may
-    reuse their HBM for the program's outputs instead of allocating fresh
-    buffers. The CPU backend does not implement donation (it warns and
-    copies) — same gate as execs/opjit._donate. Donated staging is never
-    retried in place: a faulted exchange re-stages from the spillables
-    (with_device_retry around run_collective), so a donated buffer is
-    consumed at most once."""
+    """Buffer-donation argnums for collective inputs this module made
+    itself (the destination ids, the overlap path's send buffers and
+    accumulators) — never the column arrays, which since the global inputs
+    are assembled in place may be a cached relation's own buffers. The CPU
+    backend does not implement donation (it warns and copies) — same gate
+    as execs/opjit._donate. Donated staging is never retried in place: a
+    faulted exchange re-stages from the spillables (with_device_retry
+    around run_collective), so a donated buffer is consumed at most
+    once."""
     return tuple(positions) if jax.default_backend() != "cpu" else ()
 
 
@@ -265,6 +408,12 @@ class MeshExchangeResult(NamedTuple):
     #: range: AQE skew splitting slices on these (map_block_sizes)
     src_rows: Optional[List[List[int]]] = None
     row_bytes: int = 0               # device bytes per row (fixed layout)
+    #: rows that changed chip: the collective's off-diagonal counts, plus
+    #: rows of an input piece that was not on its chip when staged
+    rows_moved: int = 0
+    #: bytes of output blocks held on more than one chip (0: block r is on
+    #: chip r alone)
+    replicated_bytes: int = 0
 
 
 def _build_exchange(mesh: Mesh, n_dev: int, slot_cap: int,
@@ -273,8 +422,8 @@ def _build_exchange(mesh: Mesh, n_dev: int, slot_cap: int,
     validity AND the fused post-collective compact — received slot (src s,
     pos p) scatters to final row `bases[s] + p` under the host-known
     per-source counts, so the outputs need no host-side compact at all.
-    Returns the per-reduce blocks lane-major (`n_lanes * n_dev` outputs,
-    each replicated so downstream consumers mix blocks across partitions).
+    Returns one array a lane, sharded over the mesh: chip r's shard is
+    reduce block r's lane and stays on chip r (no all-gather).
     `sig` is ((dtype_str, has_validity), ...)."""
     key = (mesh, n_dev, slot_cap, sig)
     with _CACHE_LOCK:
@@ -341,20 +490,31 @@ def _build_exchange(mesh: Mesh, n_dev: int, slot_cap: int,
                    in_specs=tuple([spec] * (2 + n_flat)),
                    out_specs=tuple([spec] * n_lanes), check_vma=False)
 
-    def whole(dest, counts, *flat):
-        outs = sm(dest, counts, *flat)
-        blocks = []
-        for arr in outs:
-            for r in range(n_dev):
-                blocks.append(arr[r * local:(r + 1) * local])
-        return tuple(blocks)
+    def mesh_exchange(dest, counts, *flat):
+        # the device program's name in a trace: jit_mesh_exchange
+        return sm(dest, counts, *flat)
 
-    fn = jax.jit(whole, out_shardings=NamedSharding(mesh, P()),
-                 donate_argnums=_donate((0,) + tuple(
-                     range(2, 2 + n_flat))))
+    # no out_shardings: lane `l` leaves as ONE array sharded over the mesh,
+    # whose shard on chip r IS reduce block r (`_chip_blocks`)
+    fn = jax.jit(mesh_exchange, donate_argnums=_donate((0,)))
     with _CACHE_LOCK:
         _EXCHANGE_CACHE[key] = fn
     return fn
+
+
+def _chip_blocks(mesh: Mesh, arr: jax.Array) -> List[jax.Array]:
+    """The per-chip shards of a lane sharded over the mesh, in mesh order:
+    single-device arrays, each on its own chip, nothing copied."""
+    by_dev = {sh.device: sh.data for sh in arr.addressable_shards}
+    return [by_dev[d] for d in mesh.devices.flat]
+
+
+def _global(mesh: Mesh, pieces: List[jax.Array]) -> jax.Array:
+    """One array sharded over the mesh from per-chip pieces of one shape,
+    piece r already on chip r: assembled where the pieces are."""
+    n = pieces[0].shape[0]
+    return jax.make_array_from_single_device_arrays(
+        (n * len(pieces),), NamedSharding(mesh, P(_AXIS)), pieces)
 
 
 def _build_overlap(mesh: Mesh, n_dev: int, slot_cap: int, k_seg: int,
@@ -370,10 +530,8 @@ def _build_overlap(mesh: Mesh, n_dev: int, slot_cap: int, k_seg: int,
                    segment into DONATED accumulators at the same final
                    rows the unsegmented program uses (bit-identical at
                    any K);
-    * ``fin``    — replicate-and-slice the accumulators into per-reduce
-                   blocks (same output layout as `_build_exchange`).
-
-    Returns (prep, a2a, comp, fin, seg_cap)."""
+    The accumulators leave sharded over the mesh, as `_build_exchange`'s
+    lanes do. Returns (prep, a2a, comp, seg_cap)."""
     key = (mesh, n_dev, slot_cap, k_seg, sig, "overlap")
     with _CACHE_LOCK:
         progs = _EXCHANGE_CACHE.get(key)
@@ -441,20 +599,12 @@ def _build_overlap(mesh: Mesh, n_dev: int, slot_cap: int, k_seg: int,
         return tuple(acc.at[out_idx].set(seg, mode="drop")
                      for acc, seg in zip(accs, segs))
 
-    def finalize(*accs):
-        blocks = []
-        for acc in accs:
-            for r in range(n_dev):
-                blocks.append(acc[r * local:(r + 1) * local])
-        return tuple(blocks)
-
     spec = P(_AXIS)
-    rep = NamedSharding(mesh, P())
     prep = jax.jit(
         jax.shard_map(prepare, mesh=mesh,
                   in_specs=tuple([spec] * (1 + n_flat)),
                   out_specs=tuple([spec] * n_lanes), check_vma=False),
-        donate_argnums=_donate(range(1 + n_flat)))
+        donate_argnums=_donate((0,)))
     a2a = jax.jit(
         jax.shard_map(seg_a2a, mesh=mesh,
                   in_specs=(P(),) + tuple([spec] * n_lanes),
@@ -464,9 +614,7 @@ def _build_overlap(mesh: Mesh, n_dev: int, slot_cap: int, k_seg: int,
                   in_specs=(P(), spec) + tuple([spec] * (2 * n_lanes)),
                   out_specs=tuple([spec] * n_lanes), check_vma=False),
         donate_argnums=_donate(range(2, 2 + 2 * n_lanes)))
-    fin = jax.jit(finalize, out_shardings=rep,
-                  donate_argnums=_donate(range(n_lanes)))
-    progs = (prep, a2a, comp, fin, seg_cap)
+    progs = (prep, a2a, comp, seg_cap)
     with _CACHE_LOCK:
         _EXCHANGE_CACHE[key] = progs
     return progs
@@ -525,92 +673,106 @@ def mesh_hash_exchange(mesh: Mesh,
     _cancel_checkpoint(f"mesh.collective s{shuffle_id}")
     n_dev = mesh.devices.size
     assert len(group_batches) == n_dev
+    chips = list(mesh.devices.flat)
     t_stage0 = time.perf_counter_ns()
-    ref = next(b for b in group_batches if b is not None)
-    dtypes = [c.dtype for c in ref.columns]
-    cap = bucket_capacity(max([b.capacity for b in group_batches
-                               if b is not None] + [1]))
+    with obs.phase("mesh.stage"):
+        ref = next(b for b in group_batches if b is not None)
+        dtypes = [c.dtype for c in ref.columns]
+        cap = bucket_capacity(max([b.capacity for b in group_batches
+                                   if b is not None] + [1]))
 
-    # per-(shard, dest) counts -> slot capacity AND the exchange's partition
-    # statistics (ONE audited host sync for all shards' pid arrays; a
-    # per-shard np.asarray loop would pay one round trip each on
-    # high-latency links)
-    live = [(d, b, p) for d, (b, p) in enumerate(zip(group_batches,
-                                                     pids_list))
-            if b is not None and b.num_rows]
-    fetched = audited_device_get([p for _d, _b, p in live], "mesh_counts") \
-        if live else []
-    max_count = 1
-    counts_m = np.zeros((n_dev, n_dev), np.int64)
-    for (shard, b, _p), pids_np in zip(live, fetched):
-        counts = np.bincount(np.asarray(pids_np)[: b.num_rows],
-                             minlength=n_dev)
-        max_count = max(max_count, int(counts.max()))
-        counts_m[shard] += counts
-    recv_rows = counts_m.sum(axis=0)
-    send_rows = counts_m.sum(axis=1)
-    slot_cap = bucket_capacity(max_count)
-    overlap_k = _overlap_segments(conf, slot_cap)
+        # per-(shard, dest) counts -> slot capacity AND the exchange's
+        # partition statistics (ONE audited host sync for all shards' pid
+        # arrays; a per-shard np.asarray loop would pay one round trip
+        # each on high-latency links)
+        live = [(d, b, p) for d, (b, p) in enumerate(zip(group_batches,
+                                                         pids_list))
+                if b is not None and b.num_rows]
+        fetched = audited_device_get([p for _d, _b, p in live],
+                                     "mesh_counts") if live else []
+        max_count = 1
+        counts_m = np.zeros((n_dev, n_dev), np.int64)
+        for (shard, b, _p), pids_np in zip(live, fetched):
+            counts = np.bincount(np.asarray(pids_np)[: b.num_rows],
+                                 minlength=n_dev)
+            max_count = max(max_count, int(counts.max()))
+            counts_m[shard] += counts
+        recv_rows = counts_m.sum(axis=0)
+        send_rows = counts_m.sum(axis=1)
+        slot_cap = bucket_capacity(max_count)
+        overlap_k = _overlap_segments(conf, slot_cap)
 
-    # stack per-shard arrays into globally sharded [n_dev * cap] inputs;
-    # constant pad pieces (empty shards, destination fills) come from the
-    # staging pool — the copies they replace are the "staging" wall
-    sharding = NamedSharding(mesh, P(_AXIS))
-    reuse_hits = 0
+        # the global [n_dev * cap] inputs, assembled from per-chip pieces where
+        # they are: piece r is shard group r's array, padded on chip r.
+        # Constant pad pieces (empty shards, destination fills) come from the
+        # staging pool, a copy a chip.
+        reuse_hits = 0
+        restaged_rows = 0
 
-    def pad(kind: str, dtype, fill):
-        nonlocal reuse_hits
-        arr, hit = _pooled_fill(kind, cap, dtype, fill)
-        reuse_hits += hit
-        return arr
+        def pad(kind: str, dtype, fill, r: int):
+            nonlocal reuse_hits
+            arr, hit = _pooled_fill(kind, cap, dtype, fill, chips[r])
+            reuse_hits += hit
+            return arr
 
-    sig = []
-    col_data: List[List[jnp.ndarray]] = []
-    col_valid: List[List[jnp.ndarray]] = []
-    has_valid = [any(b is not None and b.columns[i].validity is not None
-                     for b in group_batches)
-                 for i in range(len(dtypes))]
-    for i, dt in enumerate(dtypes):
-        carrier = ref.columns[i].data.dtype
-        sig.append((str(carrier), has_valid[i]))
-        datas, valids = [], []
-        for b in group_batches:
-            if b is None:
-                datas.append(pad("zeros", carrier, 0))
-                valids.append(pad("mask", jnp.bool_, False))
-            else:
-                c = _repad(b.columns[i], cap)
-                datas.append(c.data)
-                valids.append(c.validity if c.validity is not None
-                              else row_mask(b.num_rows, cap))
-        col_data.append(datas)
-        col_valid.append(valids)
-    dests = []
-    for b, pids in zip(group_batches, pids_list):
-        if b is None or not b.num_rows:
-            dests.append(pad("dest", jnp.int32, n_dev))
+        def place(arr, r: int, rows: int = 0):
+            """`arr` as chip r's piece: itself when it is there (the placed
+            session's case), else moved — rows that changed chip."""
+            nonlocal restaged_rows
+            if arr.committed and arr.devices() == {chips[r]}:
+                return arr
+            restaged_rows += rows
+            return jax.device_put(arr, chips[r])
+
+        sig = []
+        col_data: List[List[jnp.ndarray]] = []
+        col_valid: List[List[jnp.ndarray]] = []
+        has_valid = [any(b is not None and b.columns[i].validity is not None
+                         for b in group_batches)
+                     for i in range(len(dtypes))]
+        for i, dt in enumerate(dtypes):
+            carrier = ref.columns[i].data.dtype
+            sig.append((str(carrier), has_valid[i]))
+            datas, valids = [], []
+            for r, b in enumerate(group_batches):
+                if b is None:
+                    datas.append(pad("zeros", carrier, 0, r))
+                    valids.append(pad("mask", jnp.bool_, False, r))
+                    continue
+                with on_chip(chips[r]):
+                    c = _repad(b.columns[i], cap)
+                    datas.append(place(c.data, r, b.num_rows if i == 0 else 0))
+                    valids.append(place(
+                        c.validity if c.validity is not None
+                        else row_mask(b.num_rows, cap), r))
+            col_data.append(datas)
+            col_valid.append(valids)
+        dests = []
+        for r, (b, pids) in enumerate(zip(group_batches, pids_list)):
+            if b is None or not b.num_rows:
+                dests.append(pad("dest", jnp.int32, n_dev, r))
+                continue
+            with on_chip(chips[r]):
+                p = place(jnp.asarray(pids), r)[:cap].astype(jnp.int32)
+                if p.shape[0] < cap:
+                    p = jnp.concatenate(
+                        [p, jnp.full((cap - p.shape[0],), n_dev, jnp.int32)])
+                # a fresh array (it is donated): never the caller's pids
+                dests.append(place(
+                    jnp.where(row_mask(b.num_rows, cap), p, n_dev), r))
+
+        dest_g = _global(mesh, dests)
+        counts_g = jax.device_put(
+            np.ascontiguousarray(counts_m.T.astype(np.int32)).reshape(-1),
+            NamedSharding(mesh, P(_AXIS)))
+        flat = [_global(mesh, col_data[i]) for i in range(len(dtypes))] + \
+               [_global(mesh, col_valid[i]) for i in range(len(dtypes))]
+        input_devices = len({sh.device for a in (dest_g, *flat)
+                             for sh in a.addressable_shards})
+        if overlap_k:
+            ovl = _build_overlap(mesh, n_dev, slot_cap, overlap_k, tuple(sig))
         else:
-            p = jnp.asarray(pids)[:cap].astype(jnp.int32)
-            if p.shape[0] < cap:
-                p = jnp.concatenate(
-                    [p, jnp.full((cap - p.shape[0],), n_dev, jnp.int32)])
-            dests.append(jnp.where(row_mask(b.num_rows, cap), p, n_dev))
-
-    def shard(arrs):
-        return jax.device_put(jnp.concatenate(arrs), sharding)
-
-    dest_g = shard(dests)
-    counts_g = shard([jnp.asarray(counts_m[:, r].astype(np.int32))
-                      for r in range(n_dev)])
-    flat = [shard(col_data[i]) for i in range(len(dtypes))] + \
-           [shard(col_valid[i]) for i in range(len(dtypes))]
-    # read before the launch donates the staged buffers
-    input_devices = len({sh.device for a in (dest_g, *flat)
-                         for sh in a.addressable_shards})
-    if overlap_k:
-        ovl = _build_overlap(mesh, n_dev, slot_cap, overlap_k, tuple(sig))
-    else:
-        fn = _build_exchange(mesh, n_dev, slot_cap, tuple(sig))
+            fn = _build_exchange(mesh, n_dev, slot_cap, tuple(sig))
     t_launch0 = time.perf_counter_ns()
     # pre-allocated profile seq: the span args and the consumer read's
     # flow events reference the profile before it is recorded
@@ -629,11 +791,17 @@ def mesh_hash_exchange(mesh: Mesh,
                   n_dev=n_dev, slot_cap=slot_cap, exchange_seq=seq,
                   staging_ms=round((t_launch0 - t_stage0) / 1e6, 3),
                   overlap_segments=overlap_k,
-                  per_chip_rows=[int(x) for x in send_rows]):
-        with mprof.collective_watchdog(shuffle_id, n_dev) as wd:
+                  per_chip_rows=[int(x) for x in send_rows]), \
+            obs.phase("mesh.collective"):
+        # launched under the mesh's first chip as default device whichever
+        # thread materializes the exchange: jit keys its programs by that
+        # context too, and a chip's worker that got here first would
+        # otherwise compile the collective anew
+        with mprof.collective_watchdog(shuffle_id, n_dev) as wd, \
+                jax.default_device(chips[0]):
             if overlap_k:
                 outs = _launch_overlapped(ovl, overlap_k, mesh, n_dev,
-                                          slot_cap, tuple(sig), sharding,
+                                          slot_cap, tuple(sig),
                                           dest_g, counts_g, flat,
                                           shuffle_id)
             else:
@@ -646,37 +814,34 @@ def mesh_hash_exchange(mesh: Mesh,
             # accounting stays exact)
             from ..profiling import record_sync
             record_sync("collective_wait")
-            jax.block_until_ready(outs)
+            with obs.phase("mesh.wait", cat="wait"):
+                jax.block_until_ready(outs)
             t_end = time.perf_counter_ns()
         opjit.record_external_dispatch("mesh_collective")
 
-        # assemble per-reduce batches from the program's replicated block
-        # outputs (lane-major). The compact already happened INSIDE the
-        # dispatch: rows [0, recv_rows[r]) are final, the tail is padding
-        # (zeros, validity False) — no host compact, no per-partition
-        # sync (the counts were host-known from the sizing sync).
-        local = n_dev * slot_cap
+        # reduce block r's lanes are chip r's shards of the program's
+        # outputs, where the collective left them. The compact already
+        # happened INSIDE the dispatch: rows [0, recv_rows[r]) are final,
+        # the tail is padding (zeros, validity False) — no host compact,
+        # no per-partition sync (the counts were host-known from the
+        # sizing sync).
         row_bytes = _fixed_row_bytes(ref, has_valid)
-        lane_of: List[Tuple[int, Optional[int]]] = []
-        li = 0
-        for i in range(len(dtypes)):
-            d_li, li = li, li + 1
-            v_li = None
-            if has_valid[i]:
-                v_li, li = li, li + 1
-            lane_of.append((d_li, v_li))
+        lanes = [_chip_blocks(mesh, arr) for arr in outs]
         results: List[TpuColumnarBatch] = []
         sizes: List[int] = []
         for r in range(n_dev):
             cols = []
+            li = 0
             for i, dt in enumerate(dtypes):
-                d_li, v_li = lane_of[i]
-                v = outs[v_li * n_dev + r] if v_li is not None else None
-                cols.append(TpuColumnVector(dt, outs[d_li * n_dev + r], v,
-                                            int(recv_rows[r])))
+                data, li = lanes[li][r], li + 1
+                v = None
+                if has_valid[i]:
+                    v, li = lanes[li][r], li + 1
+                cols.append(TpuColumnVector(dt, data, v, int(recv_rows[r])))
             results.append(TpuColumnarBatch(cols, int(recv_rows[r]),
                                             list(names)))
             sizes.append(int(recv_rows[r]) * row_bytes)
+        replicated = sum(replicated_bytes(b) for b in results)
         t_compact_end = time.perf_counter_ns()
         profile = mprof.record_exchange(
             seq, shuffle_id, partitioning, n_dev,
@@ -702,14 +867,15 @@ def mesh_hash_exchange(mesh: Mesh,
                    overlap_segments=overlap_k)
     src_rows = [[int(counts_m[s][r]) for s in range(n_dev)]
                 for r in range(n_dev)]
+    moved = int(counts_m.sum() - np.trace(counts_m)) + restaged_rows
     return MeshExchangeResult(results, [int(x) for x in recv_rows], sizes,
-                              profile, src_rows, row_bytes)
+                              profile, src_rows, row_bytes, moved,
+                              replicated)
 
 
 def _launch_overlapped(progs, k_seg: int, mesh: Mesh, n_dev: int,
                        slot_cap: int, sig: Tuple[Tuple[str, bool], ...],
-                       sharding, dest_g, counts_g, flat,
-                       shuffle_id: int) -> Tuple:
+                       dest_g, counts_g, flat, shuffle_id: int) -> Tuple:
     """Double-buffered segmented exchange: segment k+1's all_to_all is
     dispatched BEFORE segment k's fused compact, so the fabric moves the
     next segment while the compact consumes the current one. Every segment
@@ -719,18 +885,18 @@ def _launch_overlapped(progs, k_seg: int, mesh: Mesh, n_dev: int,
     mid-flight and the caller re-stages — nothing is applied twice."""
     from ..chaos import inject
     from ..execs import opjit
-    prep, a2a, comp, fin, _seg_cap = progs
+    prep, a2a, comp, _seg_cap = progs
     sends = prep(dest_g, *flat)
-    # fresh (never pooled) accumulators: comp donates them each segment
+    # fresh (never pooled) accumulators, made sharded: comp donates them
+    # each segment
+    sharding = NamedSharding(mesh, P(_AXIS))
     accs = []
     for dt, has_v in sig:
-        accs.append(jax.device_put(
-            jnp.zeros((n_dev * n_dev * slot_cap,), jnp.dtype(dt)),
-            sharding))
+        accs.append(jnp.zeros((n_dev * n_dev * slot_cap,), jnp.dtype(dt),
+                              device=sharding))
         if has_v:
-            accs.append(jax.device_put(
-                jnp.zeros((n_dev * n_dev * slot_cap,), jnp.bool_),
-                sharding))
+            accs.append(jnp.zeros((n_dev * n_dev * slot_cap,), jnp.bool_,
+                                  device=sharding))
     accs = tuple(accs)
     seg = a2a(jnp.int32(0), *sends)
     for k in range(k_seg):
@@ -741,7 +907,7 @@ def _launch_overlapped(progs, k_seg: int, mesh: Mesh, n_dev: int,
         inject("mesh.link", detail=f"s{shuffle_id}seg{k}")
         accs = comp(jnp.int32(k), counts_g, *accs, *seg)
         seg = nxt
-    return fin(*accs)
+    return accs
 
 
 def mesh_single_exchange(mesh: Mesh,
@@ -763,9 +929,11 @@ def mesh_single_exchange(mesh: Mesh,
     funnels this serves (payloads are per-shard partial STATES, already
     reduced); a ragged gather / all_gather layout is the follow-up if a
     row-heavy single exchange ever rides it (ROADMAP item 2)."""
-    pids = [None if b is None
-            else jnp.zeros((b.capacity,), jnp.int32)
-            for b in group_batches]
+    pids = []
+    for chip, b in zip(mesh.devices.flat, group_batches):
+        with on_chip(chip):
+            pids.append(None if b is None
+                        else jnp.zeros((b.capacity,), jnp.int32))
     return mesh_hash_exchange(mesh, group_batches, pids, names,
                               shuffle_id=shuffle_id, partitioning="single",
                               conf=conf)

@@ -282,7 +282,7 @@ def plan_query_counters(plan) -> Dict[str, int]:
     out: Dict[str, int] = {}
     seen = set()
     for node in plan.collect_nodes():
-        for name, metric in node.query_counters():
+        for name, metric in node.query_counters() + node.mesh_counters():
             if (name, id(metric)) not in seen:
                 seen.add((name, id(metric)))
                 out[name] = out.get(name, 0) + metric.value

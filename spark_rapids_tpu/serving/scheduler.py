@@ -854,7 +854,7 @@ def _run_query(session, plan_fn, conf, qname: str, tables: List,
     from .. import obs
     from ..config import (TRACE_BUFFER_EVENTS, TRACE_CATEGORIES,
                           TRACE_ENABLED)
-    from ..parallel.mesh import mesh_session_active
+    from ..parallel.mesh import run_chip_tasks, session_chips
     from ..profiling import (SyncLedger, TaskMetricsRegistry,
                              plan_query_counters, snapshot_plan_metrics)
     task_metrics_before = TaskMetricsRegistry.get().snapshot()
@@ -894,34 +894,54 @@ def _run_query(session, plan_fn, conf, qname: str, tables: List,
             plan_ns = time.perf_counter_ns() - t_plan0
             ph.annotate(cache=session._last_plan_cache)
         obs.metrics.histogram_observe("plan.build_ms", plan_ns / 1e6)
-        # mesh session (docs/distributed.md): the root pull drives ALL
-        # partitions through the multi-partition entry point in one group,
-        # so the top whole-stage segment (between the last exchange and the
-        # result) executes every chip's partition in a single grouped
-        # launch — the same batched dispatch the exchange map side uses
+        # mesh session (docs/distributed.md "Placement and the task
+        # model"): result partition p is chip p % n's task — the chips at
+        # once, each driving its partitions through the multi-partition
+        # entry point, so a pure row-wise top segment still runs a chip's
+        # partitions as one grouped launch
         n_parts = final.num_partitions()
         names = [a.name for a in final.output]
-        group_pull = n_parts > 1 and mesh_session_active(conf) is not None
+        chips = session_chips(conf)
         # what runs after planning: every operator pull (the stage's own
         # phases nest inside), the final sort, device→host, Arrow tables
         with obs.phase("result.drain"):
-            if group_pull:
-                ids = list(range(n_parts))
+            if chips is not None:
                 ctxs: Dict[int, TaskContext] = {}
+                ctx_lock = threading.Lock()
 
                 def ctx_of(i):
-                    c = ctxs.get(i)
-                    if c is None:
-                        c = ctxs[i] = TaskContext(i, conf)
-                    return c
+                    with ctx_lock:
+                        c = ctxs.get(i)
+                        if c is None:
+                            c = ctxs[i] = TaskContext(i, conf)
+                        return c
+
+                def chip_task(r: int):
+                    ids = list(range(r, n_parts, len(chips)))
+                    checkpoint(f"task.group chip{r} {ids}")
+                    try:
+                        with obs.span(f"chip {r} partitions {ids}",
+                                      cat="task", partitions=len(ids)):
+                            return [(p, t.rename_columns(names)) for p, t
+                                    in final.execute_partitions(ids, ctx_of)
+                                    if t.num_rows]
+                    finally:
+                        # this chip's tasks are over: their permits go back
+                        # while the other chips still run
+                        for i in ids:
+                            ctx_of(i).complete()
 
                 try:
-                    checkpoint(f"task.group 0-{ids[-1]}")
-                    with obs.span(f"partition group 0-{ids[-1]}", cat="task",
-                                  partitions=n_parts):
-                        for _p, t in final.execute_partitions(ids, ctx_of):
-                            if t.num_rows:
-                                tables.append(t.rename_columns(names))
+                    if n_parts > 1:
+                        from ..parallel.mesh import on_chip
+                        from ..shuffle.exchange import materialize_exchanges
+                        with on_chip(chips[0]):
+                            materialize_exchanges(final, ctx_of(0))
+                    done = run_chip_tasks(
+                        conf, range(min(n_parts, len(chips))), chip_task)
+                    tables.extend(t for _p, t in sorted(
+                        (pt for r in sorted(done) for pt in done[r]),
+                        key=lambda pt: pt[0]))
                 except BaseException as exc:
                     from ..config import FATAL_ERROR_EXIT
                     from ..failure import handle_task_failure

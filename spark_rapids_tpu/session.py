@@ -528,8 +528,18 @@ class DataFrame:
         batch serializer). Column objects are stable across runs, so
         per-column memoized statistics (group-by dictionaries, key ranges)
         and the compiled-stage program cache stay warm."""
-        from .io.cache import DeviceCachedRelation
-        batches = self.to_device_batches()
+        from .io.cache import DeviceCachedRelation, shard_over_chips
+        from .parallel.mesh import session_chips
+        conf = self.session._rapids_conf()
+        chips = session_chips(conf)
+        if chips is None:
+            batches = self.to_device_batches()
+        else:
+            # mesh session: a quarter of the rows on each chip
+            # (docs/distributed.md "Placement and the task model")
+            from .config import BATCH_SIZE_ROWS
+            batches = shard_over_chips(self.to_arrow(), chips,
+                                       int(conf.get(BATCH_SIZE_ROWS)))
         return DataFrame(DeviceCachedRelation(batches, self._plan.output),
                          self.session)
 
@@ -582,18 +592,25 @@ class DataFrame:
         while isinstance(final, DeviceToHostExec):
             final = final.children[0]
         out: List = []
+        # mesh session: the caller gets one device's arrays (it may
+        # concatenate them), so each partition is computed on its chip and
+        # handed over on the mesh's first (`TpuExec._placed`)
+        from .parallel.mesh import on_chip, session_chips
+        chips = session_chips(conf)
         try:
-            for p in range(final.num_partitions()):
-                ctx = TaskContext(p, conf)
-                try:
-                    for b in final.execute_partition(p, ctx):
-                        if isinstance(b, TpuColumnarBatch):
-                            out.append(b)
-                        else:  # CPU-resident plan: upload (reference
-                            # InternalColumnarRddConverter host→device path)
-                            out.append(TpuColumnarBatch.from_arrow(b))
-                finally:
-                    ctx.complete()
+            with on_chip(chips[0] if chips else None):
+                for p in range(final.num_partitions()):
+                    ctx = TaskContext(p, conf)
+                    try:
+                        for b in final.execute_partition(p, ctx):
+                            if isinstance(b, TpuColumnarBatch):
+                                out.append(b)
+                            else:  # CPU-resident plan: upload (reference
+                                # InternalColumnarRddConverter host→device
+                                # path)
+                                out.append(TpuColumnarBatch.from_arrow(b))
+                    finally:
+                        ctx.complete()
         finally:
             # same end-of-query shuffle release as _execute; the returned
             # batches keep their arrays alive independently of the catalog

@@ -34,6 +34,17 @@ class _DictionaryOverflow(Exception):
     with reason ``dictionary_overflow``."""
 
 
+def materialize_exchanges(plan: PhysicalPlan, ctx: TaskContext) -> None:
+    """Mesh session: before the chips' tasks fan out over `plan`
+    (`parallel/mesh.py::run_chip_tasks`), the exchanges in it materialize
+    from the calling thread. A chip's task then finds its blocks there:
+    none drives a collective of its own while its siblings wait, and which
+    thread stages an exchange does not hang on which chip got there first."""
+    for node in plan.collect_nodes():
+        if isinstance(node, _ExchangeBase):
+            node._ensure_materialized(ctx)
+
+
 class _ExchangeBase:
     """Shared map-side materialization (runs once, guarded)."""
 
@@ -568,31 +579,41 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
                 # mesh-session exchange routed per-map: count the reason
                 # (mesh.per_map_exchange{reason}) for the multichip
                 # summary / explain("metrics") — obs/mesh_profile.py
-                from ..obs import mesh_profile as _mprof
-                _mprof.record_fallback(sid, reason)
+                self._count_fallback(sid, reason)
             return False
         from ..columnar.batch import concat_batches
         from ..failure import with_device_retry
         from ..memory.hbm import TpuOOM
         from ..memory.spill import SpillableColumnarBatch
-        from ..parallel.mesh import mesh_hash_exchange, mesh_single_exchange
+        from ..parallel.mesh import (mesh_hash_exchange, mesh_single_exchange,
+                                     on_chip, run_chip_tasks)
         from ..profiling import sync_scope
         from .ici import IciShuffleCatalog
         n_dev = mesh.devices.size
+        chips = list(mesh.devices.flat)
         child = self.children[0]
+        materialize_exchanges(child, ctx)
         # collect per-shard groups as SPILLABLE batches so HBM pressure from
         # later map partitions can evict earlier outputs (the per-map ICI path
-        # gets this from the catalog; the collective must provide it itself)
+        # gets this from the catalog; the collective must provide it itself).
+        # Map partition m runs on chip m % n (`run_chip_tasks`: the chips at
+        # once) and its batches stay there: group r is appended to by chip
+        # r's task alone.
         groups: List[List[SpillableColumnarBatch]] = [[] for _ in range(n_dev)]
+
+        def pull(m: int) -> None:
+            qlc.checkpoint(f"exchange.map s{sid}m{m}")
+            mctx = TaskContext(m, ctx.conf)
+            try:
+                for b in child.execute_partition(m, mctx):
+                    if b.num_rows:
+                        groups[m % n_dev].append(SpillableColumnarBatch(b))
+            finally:
+                mctx.complete()
+
         try:
-            for m in range(child.num_partitions()):
-                mctx = TaskContext(m, ctx.conf)
-                try:
-                    for b in child.execute_partition(m, mctx):
-                        if b.num_rows:
-                            groups[m % n_dev].append(SpillableColumnarBatch(b))
-                finally:
-                    mctx.complete()
+            with obs.phase("mesh.stage"):
+                run_chip_tasks(ctx.conf, range(child.num_partitions()), pull)
             if not any(groups):
                 IciShuffleCatalog.get().mark_map_complete(sid, 0)
                 self._collective = True
@@ -603,6 +624,21 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
                 self._collective_row_bytes = 0
                 return True
 
+            def stage(r: int):
+                """Shard group r as one batch on chip r, and its rows'
+                destination ids."""
+                if not groups[r]:
+                    return None, None
+                got = [sb.get_batch() for sb in groups[r]]
+                b = concat_batches(got) if len(got) > 1 else got[0]
+                # partition ids hash the ORIGINAL key values (a dictionary
+                # code is exchange-local; hashing it would break
+                # co-partitioning with sibling exchanges)
+                pids = hash_partition_ids(b, self.keys, n_dev, ctx,
+                                          metrics=self.metrics) \
+                    if self.partitioning == "hash" else None
+                return b, pids
+
             def run_collective():
                 # idempotent: a transient fault on the fabric (chaos
                 # mesh.link) re-stages from the still-open spillables —
@@ -611,35 +647,20 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
                 # function of the still-open map outputs)
                 with self.metrics["partitionTime"].timed(), \
                         sync_scope(self.node_name()):
-                    batches = []
-                    for g in groups:
-                        if not g:
-                            batches.append(None)
-                            continue
-                        got = [sb.get_batch() for sb in g]
-                        batches.append(concat_batches(got) if len(got) > 1
-                                       else got[0])
+                    with obs.phase("mesh.stage"):
+                        staged = run_chip_tasks(ctx.conf, range(n_dev), stage)
+                    batches = [staged[r][0] for r in range(n_dev)]
                     names = [a.name for a in self.output]
-                    pids = None
-                    if self.partitioning == "hash":
-                        # partition ids hash the ORIGINAL key values (a
-                        # dictionary code is exchange-local; hashing it
-                        # would break co-partitioning with sibling
-                        # exchanges)
-                        pids = [hash_partition_ids(b, self.keys, n_dev,
-                                                   ctx,
-                                                   metrics=self.metrics)
-                                if b is not None else None
-                                for b in batches]
                     if getattr(self, "_dict_payload", False):
-                        batches = self._encode_dict_payload(batches, ctx)
+                        batches = self._encode_dict_payload(batches, ctx,
+                                                            chips)
                     if self.partitioning == "single":
                         return mesh_single_exchange(mesh, batches, names,
                                                     shuffle_id=sid,
                                                     conf=ctx.conf)
-                    return mesh_hash_exchange(mesh, batches, pids, names,
-                                              shuffle_id=sid,
-                                              conf=ctx.conf)
+                    return mesh_hash_exchange(
+                        mesh, batches, [staged[r][1] for r in range(n_dev)],
+                        names, shuffle_id=sid, conf=ctx.conf)
 
             result = with_device_retry(run_collective, ctx.conf)
         except _DictionaryOverflow:
@@ -647,8 +668,7 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
             # or >2^31 distinct bytes — beyond int32 offsets): the per-map
             # device-resident path carries raw strings natively
             self._collective_reason = "dictionary_overflow"
-            from ..obs import mesh_profile as _mprof
-            _mprof.record_fallback(sid, "dictionary_overflow")
+            self._count_fallback(sid, "dictionary_overflow")
             IciShuffleCatalog.get().cleanup(sid)
             self._close_dicts()
             return False
@@ -657,8 +677,7 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
             # has the full incremental-spill discipline; drop any partial
             # state for this shuffle id and let the caller run per-map
             self._collective_reason = "staging_oom"
-            from ..obs import mesh_profile as _mprof
-            _mprof.record_fallback(sid, "staging_oom")
+            self._count_fallback(sid, "staging_oom")
             IciShuffleCatalog.get().cleanup(sid)
             self._close_dicts()
             return False
@@ -671,8 +690,15 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
             blk = result.batches[r]
             if result.rows[r]:
                 catalog.put_block(sid, 0, r, blk, owner="mesh-collective")
+                self._count_block(result.rows[r], result.bytes[r])
         catalog.mark_map_complete(sid, 0)
         self._collective = True
+        self.mesh_metric("meshExchanges").add(1)
+        self.mesh_metric("meshPerMapFallbacks")
+        self.mesh_metric("meshRowsMoved").add(result.rows_moved)
+        self.mesh_metric("meshBytesMoved").add(
+            result.rows_moved * result.row_bytes)
+        self.mesh_metric("meshReplicatedBytes").add(result.replicated_bytes)
         # device-side partition statistics: exact per-reduce row/byte counts
         # from the exchange's sizing counters — partition_sizes (AQE) serves
         # these without fetching (or unspilling) a single block
@@ -690,6 +716,26 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
         self._collective_seq = (result.profile or {}).get("seq")
         return True
 
+    def _count_fallback(self, sid: int, reason: str) -> None:
+        """A mesh-session exchange routed per-map: the process-wide
+        `mesh.per_map_exchange{reason}` (obs/mesh_profile.py, for the
+        multichip summary and explain("metrics")) and this query's
+        `mesh.per_map_fallbacks`, whole and by reason."""
+        from ..obs import mesh_profile as _mprof
+        _mprof.record_fallback(sid, reason)
+        self.mesh_metric("meshPerMapFallbacks").add(1)
+        self.mesh_metric(f"meshPerMapFallbacks.{reason}").add(1)
+
+    def mesh_counters(self):
+        out = super().mesh_counters()
+        for key, m in list(self.metrics.items()):
+            if key == "meshExchanges":
+                out.append(("mesh.exchanges", m))
+            elif key.startswith("meshPerMapFallbacks"):
+                out.append(("mesh.per_map_fallbacks"
+                            + key[len("meshPerMapFallbacks"):], m))
+        return out
+
     def _close_dicts(self) -> None:
         dcols = getattr(self, "_dict_cols", None)
         if dcols:
@@ -697,7 +743,7 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
                 sb.close()
         self._dict_cols = None
 
-    def _encode_dict_payload(self, batches, ctx: TaskContext):
+    def _encode_dict_payload(self, batches, ctx: TaskContext, chips=None):
         """Map-side dictionary-encode pass of the collective exchange:
         build ONE dictionary per string/binary column across ALL shards'
         map outputs, replace each column with its int32 codes (nulls ride
@@ -757,10 +803,11 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
                                 zero_copy_only=False)).astype(np.int32)
                         validity = (np.asarray(codes.is_valid())
                                     if codes.null_count else None)
-                        codes_by_shard.setdefault(shard, {})[o] = \
-                            TpuColumnVector.from_numpy(
-                                IntegerType(), vals, validity,
-                                capacity=b.capacity)
+                        with _mesh.on_chip(chips[shard] if chips else None):
+                            codes_by_shard.setdefault(shard, {})[o] = \
+                                TpuColumnVector.from_numpy(
+                                    IntegerType(), vals, validity,
+                                    capacity=b.capacity)
         except BaseException:
             for sb in dict_cols.values():
                 sb.close()
@@ -784,13 +831,17 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
         string columns via the device ragged gather, with the codes kept
         as each column's `dict_encoding` so a string-keyed downstream
         aggregation consumes them directly."""
-        dcols = getattr(self, "_dict_cols", None)
-        if not dcols or not getattr(self, "_collective", False):
-            return b
+        # under the materialization lock: a sibling chip's task that lost a
+        # shard may be re-running the collective, which closes these
+        # dictionaries and parks the same ones anew
+        with self._mat_lock:
+            dcols = getattr(self, "_dict_cols", None)
+            if not dcols or not getattr(self, "_collective", False):
+                return b
+            dicts = {o: sb.get_batch().columns[0] for o, sb in dcols.items()}
         from ..columnar.batch import decode_dictionary_column
         cols = list(b.columns)
-        for o, sb in dcols.items():
-            dcol = sb.get_batch().columns[0]
+        for o, dcol in dicts.items():
             cols[o] = decode_dictionary_column(dcol, cols[o], b.num_rows,
                                                b.capacity)
         return TpuColumnarBatch(cols, b.num_rows, b.names)
@@ -1086,7 +1137,8 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
                 if b.num_rows:
                     # dictionary-encoded collective blocks decode on read
                     # (codes + broadcast dictionary → device strings)
-                    yield self._decode_dict_block(b).rename(names)
+                    yield self._decode_dict_block(
+                        self._on_readers_chip(b)).rename(names)
             return
         # pipelined read (reference RapidsShuffleThreadedReaderBase): blocks
         # stream from the reader pool in map order while the NEXT block's
@@ -1099,6 +1151,19 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
         mgr = TpuShuffleManager.get(ctx.conf)
         yield from _pipelined_upload(self, self._fetch_tables(idx, ctx, mgr),
                                      names, ctx)
+
+    def _on_readers_chip(self, b: TpuColumnarBatch) -> TpuColumnarBatch:
+        """Mesh session: a collective block read by its own partition's
+        task is on that task's chip already. A per-map block (the fallback)
+        is where its MAP task ran, and a skew slice or a coalesced group
+        (AQE) may be read by another partition's task: the reader takes it
+        over — rows and bytes that changed chip."""
+        from ..parallel.mesh import current_chip
+        here = current_chip()
+        if here is None or not b.columns \
+                or here in b.columns[0].data.devices():
+            return b
+        return self.move_to_chip(b, here)
 
     def execute_partition_maps(self, idx: int, map_ids: Sequence[int],
                                ctx: TaskContext) -> Iterator:
@@ -1132,7 +1197,8 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
             for b in blocks:  # exactly one fused block per reduce part
                 if b.num_rows:
                     full = self._decode_dict_block(b).rename(names)
-                    yield slice_batch(full, start, length)
+                    yield self._on_readers_chip(
+                        slice_batch(full, start, length))
             return
         if self._shuffle_mode(ctx) == "ICI":
             from ..failure import with_device_retry
@@ -1145,7 +1211,8 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
                 ctx.conf)
             for b in blocks:
                 if b.num_rows:
-                    yield self._decode_dict_block(b).rename(names)
+                    yield self._decode_dict_block(
+                        self._on_readers_chip(b)).rename(names)
             return
         mgr = TpuShuffleManager.get(ctx.conf)
         yield from _pipelined_upload(

@@ -420,7 +420,8 @@ def test_chaos_mesh_soak(seed):
     assert IciShuffleCatalog.get().block_count() == blocks_before
     sem = TpuSemaphore._instance
     if sem is not None:
-        assert sem._sem._value == sem.permits
+        assert all(s._value == sem.permits
+                   for s in (sem._sem, *sem._chip_sems.values()))
     TpuSemaphore.reset_for_tests()
 
 
@@ -568,7 +569,8 @@ def test_chaos_mesh_soak_overlap():
     assert IciShuffleCatalog.get().block_count() == blocks_before
     sem = TpuSemaphore._instance
     if sem is not None:
-        assert sem._sem._value == sem.permits
+        assert all(s._value == sem.permits
+                   for s in (sem._sem, *sem._chip_sems.values()))
     TpuSemaphore.reset_for_tests()
 
 

@@ -249,7 +249,7 @@ def test_mesh_exchange_program_compiles_for_four_v5e_chips(monkeypatch, topo):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from spark_rapids_tpu.parallel import mesh as pm
-    # compile the variant the chip runs: staged inputs donated
+    # compile the variant the chip runs: the destination ids donated
     monkeypatch.setattr(pm, "_donate", lambda positions: tuple(positions))
     n_dev, cap, slot_cap = 4, 1 << 12, 1 << 10
     mesh = Mesh(np.array(topo.devices), (pm._AXIS,))
@@ -269,4 +269,8 @@ def test_mesh_exchange_program_compiles_for_four_v5e_chips(monkeypatch, topo):
         with pm._CACHE_LOCK:
             pm._EXCHANGE_CACHE.clear()
     assert "all-to-all" in compiled.as_text()
+    # every lane leaves sharded over the mesh (chip r's shard is reduce
+    # block r): nothing is gathered to all chips
+    assert "all-gather" not in compiled.as_text()
+    assert all(o == sharded for o in compiled.output_shardings)
     _assert_fits_hbm(compiled)
