@@ -221,9 +221,10 @@ cache tracks it:
   map side's hash-partition encode+split pair in one launch), `pids`
   (hash partitioner alone, e.g. under the mesh collective), `aggsort` /
   `aggreduce` (the sort-based aggregate's two phases), plus the
-  whole-stage and partition-grouped kinds: `joinprobe` / `joinemit` (a
-  fused segment's streamed-side join probe and pair-emit+downstream
-  halves), `aggstage` (the grouped aggregate's whole update as one
+  whole-stage and partition-grouped kinds: `joinbuild` (a fused
+  segment's join build prepared once: sort + bucket directory),
+  `joinprobe` / `joinemit` (its streamed-side join probe and
+  pair-emit+downstream halves a batch), `aggstage` (the grouped aggregate's whole update as one
   launch), `segmentg` (one fused segment over a GROUP of partitions'
   batches) and `exchsplitg` (the hash encode+split of a whole partition
   group with one bounds readback).
@@ -243,8 +244,10 @@ toggle, all default-on:
   stage segment absorbs a streamed-side inner equi-join at its bottom. The
   build side materializes ONCE per partition — segment build children get
   the `RequireSingleBatch` coalesce goal (or arrive host-concatenated from
-  an exchange read) — and each probe batch runs exactly TWO launches
-  (`joinprobe`: upstream chain + key encode + hash-range probe; `joinemit`:
+  an exchange read) and is prepared ONCE a build (`joinbuild`: key encode +
+  hash + sort + the bucket directory over the sorted hashes) — and each
+  probe batch runs exactly TWO launches (`joinprobe`: upstream chain + key
+  encode + directory look-up of each lane's candidate range; `joinemit`:
   pair expansion + verification + both-side gather + the flattened
   downstream chain + one compaction), split only at the inherent
   candidate-count sync. String keys, non-inner join types, oversized

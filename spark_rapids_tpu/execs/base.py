@@ -421,6 +421,7 @@ class PhysicalPlan:
             d["_broadcast_done"] = False
             d["_broadcast_batch"] = None
             d["_broadcast_on"] = {}
+            d["_broadcast_prepared"] = {}
         if "_values" in d:               # subquery value memo
             d["_values"] = None
         if "_dims_built" in d:           # compiled-join dim-side memo

@@ -40,6 +40,8 @@ class TpuBroadcastHashJoinExec(TpuShuffledHashJoinExec):
         self._broadcast_done = False
         #: mesh session: the build's copy on each chip that probes it
         self._broadcast_on: dict = {}
+        #: a fused probe's prepared form of the build (a copy's, by chip)
+        self._broadcast_prepared: dict = {}
 
     def node_desc(self) -> str:
         return f"TpuBroadcastHashJoin[{self.join_type}]"
@@ -87,6 +89,18 @@ class TpuBroadcastHashJoinExec(TpuShuffledHashJoinExec):
                     got.device_memory_size())
             self._broadcast_on[here] = got
         return got
+
+    def prepared_build(self, prepare):
+        """What `prepare()` makes of the build (a fused segment's
+        joins.PreparedBuild), once a query like the build itself — in a mesh
+        session once for each chip's copy, by that chip's own task, so the
+        program runs where its build lives."""
+        from ..parallel.mesh import current_chip
+        here = current_chip()
+        with self._broadcast_lock:
+            if here not in self._broadcast_prepared:
+                self._broadcast_prepared[here] = prepare()
+            return self._broadcast_prepared[here]
 
     def mesh_counters(self):
         out = super().mesh_counters()

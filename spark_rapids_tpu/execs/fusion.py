@@ -28,14 +28,18 @@ Beyond project/filter chains, a segment can absorb two more operator kinds
 * **A streamed-side inner equi-join** (spark.rapids.tpu.opjit.fuseJoins):
   the join terminates the chain bottom-wards — its build side becomes an
   extra segment child, materialized ONCE per partition through the PR 5
-  `require_single` coalesce goal — and each probe batch runs TWO launches
-  (opjit.join_probe_program / join_emit_program) split at the inherent
-  candidate-count sync: key encode + hash-range probe, then pair
-  expansion + verification + both-side gather + the entire flattened
-  downstream projection/filter chain + one compaction. Both programs call
-  the very traced functions the standalone join runs
-  (joins._join_probe_ranges/_join_emit_pairs/_compact_pairs_device), so
-  results are bit-identical. String keys, non-inner join types, oversized
+  `require_single` coalesce goal and prepared ONCE a build
+  (opjit.join_build_program: key encode, hash, sort, and a directory of
+  hash-prefix buckets over the sorted rows; a broadcast build's is made
+  once a query and shared by every probe partition) — and each probe batch
+  runs TWO launches (opjit.join_probe_program / join_emit_program) split
+  at the inherent candidate-count sync: key encode + a directory look-up
+  of each lane's candidate range, then pair expansion + verification +
+  both-side gather + the entire flattened downstream projection/filter
+  chain + one compaction. All three programs call the very traced
+  functions the standalone join runs (joins._join_prepare_build/
+  _join_probe_ranges/_join_emit_pairs/_compact_pairs_device), so results
+  are bit-identical. String keys, non-inner join types, oversized
   build sides (which need sub-partitioning) and host-assisted expressions
   delegate the partition to the original join operator unchanged.
 * **A trailing grouped aggregate** (spark.rapids.tpu.opjit.fuseAggs): a
@@ -199,7 +203,8 @@ class TpuFusedSegmentExec(TpuExec):
         return {"opFusedBatches": "DEBUG", "opFusedFallbackOps": "DEBUG",
                 "opFusedJoinBatches": "DEBUG", "opFusedGroupedBatches": "DEBUG",
                 "buildTime": "MODERATE", "numPairs": "DEBUG",
-                "joinOutputRows": "DEBUG"}
+                "joinOutputRows": "DEBUG", "buildsIndexed": "DEBUG",
+                "probesIndexed": "DEBUG"}
 
     def query_counters(self):
         # an absorbed aggregate's own, and the join's from the nodes the
@@ -207,6 +212,7 @@ class TpuFusedSegmentExec(TpuExec):
         # later pass (coalesce) wraps and the join operator's links pass
         # over, but the broadcast operator's own build child
         from .broadcast import TpuBroadcastHashJoinExec
+        from .joins import index_counters
         out = [c for op in self._ops[self._has_join:]
                for c in op.query_counters()]
         if self._has_join:
@@ -220,6 +226,8 @@ class TpuFusedSegmentExec(TpuExec):
                     ("join.rows_out", self.metrics["joinOutputRows"]),
                     ("join.subpartitioned",
                      join.metrics["subPartitionedJoins"])]
+            # the fused probes' and a delegated partition's
+            out += index_counters(self.metrics) + index_counters(join.metrics)
         return out
 
     def mesh_counters(self):
@@ -381,6 +389,7 @@ class TpuFusedSegmentExec(TpuExec):
         fused probe (join_state then carries the materialized build)."""
         from ..config import BATCH_SIZE_ROWS
         from . import opjit
+        from .broadcast import TpuBroadcastHashJoinExec
         join = self._ops[0]
         fuse = (opjit.enabled(ctx.eval_ctx)
                 and bool(ctx.conf.get(OPJIT_FUSE_JOINS))
@@ -400,14 +409,26 @@ class TpuFusedSegmentExec(TpuExec):
             # oversized build: the original operator's sub-partitioning
             # machinery (GpuSubPartitionHashJoin analogue) handles it
             return join.execute_partition(idx, ctx)
-        key_cols = None
+        prepared = None
         if build is not None:
-            key_cols = opjit.eval_exprs(
-                join.right_keys, [k.dtype for k in join.right_keys], build,
-                ctx.eval_ctx, self.metrics)
-            if not all(opjit.plain_device_col(c) for c in key_cols):
+            def prepare():
+                key_cols = opjit.eval_exprs(
+                    join.right_keys, [k.dtype for k in join.right_keys],
+                    build, ctx.eval_ctx, self.metrics)
+                if not all(opjit.plain_device_col(c) for c in key_cols):
+                    return None
+                out = opjit.join_build_program(key_cols, build.rows_arg,
+                                               ctx.eval_ctx, self.metrics)
+                if out is not None:
+                    self.metrics["buildsIndexed"].add(1)
+                return out
+            # once a build: the broadcast operator keeps its build's beside
+            # the build, for every probe partition of the query
+            prepared = join.prepared_build(prepare) \
+                if isinstance(join, TpuBroadcastHashJoinExec) else prepare()
+            if prepared is None:
                 return join.execute_partition(idx, ctx)
-        join_state["state"] = (build, key_cols)
+        join_state["state"] = (build, prepared)
         return None
 
     def _planned_join_run(self, batch: TpuColumnarBatch, bstate,
@@ -433,7 +454,7 @@ class TpuFusedSegmentExec(TpuExec):
         from ..expressions.base import AttributeReference
         from . import opjit
         join = self._ops[0]
-        build, key_cols = bstate
+        build = bstate[0]
         if not opjit.segment_inputs_ok(join.left_keys, batch):
             return None
         n_l = len(join.children[0].output)
@@ -539,13 +560,14 @@ class TpuFusedSegmentExec(TpuExec):
         from ..config import DEFERRED_COMPACTION
         from . import opjit
         join = self._ops[0]
-        build, key_cols = bstate
+        build, prepared = bstate
         res = opjit.join_probe_program(
-            [], [], [], join.left_keys, batch, key_cols, build.rows_arg,
-            ctx.eval_ctx, self.metrics)
+            [], [], [], join.left_keys, batch, prepared, ctx.eval_ctx,
+            self.metrics)
         if res is None:
             return None
         state, _ = res
+        self.metrics["probesIndexed"].add(1)
         # host sync: candidate-pair count sizes the static emit shape — the
         # same inherent sync the standalone join pays (joins._device_equi_join)
         total = audited_sync_int(state["total"], "pairs")

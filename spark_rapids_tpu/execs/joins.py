@@ -1,4 +1,4 @@
-"""Join execs: TPU equi-join (sorted-build + searchsorted probe) and CPU oracle.
+"""Join execs: TPU equi-join (sorted build + bucket-directory probe) and CPU oracle.
 
 Reference: GpuShuffledHashJoinExec + GpuHashJoin trait (execution/GpuHashJoin.scala:994,
 gather-map iterators :259-985), GpuBroadcastNestedLoopJoinExec, GpuSortMergeJoinMeta
@@ -8,12 +8,21 @@ TPU algorithm (XLA-static-shape friendly — cuDF's dynamic hash table does not
 map to TPU):
   1. composite 64-bit mix of the equi-key columns on both sides (null keys never
      match: rows with any null key are excluded from candidates)
-  2. sort the build side by hash; probe via two searchsorted calls → per-row
-     candidate ranges (hash collisions included)
-  3. expand ranges into candidate pairs (one host sync for the pair count →
+  2. ONCE A BUILD (_join_prepare_build): sort the build side by hash and count
+     its valid rows into a directory of 2^k buckets by the hash's top k bits,
+     k from the build's capacity alone (16 buckets a build lane, at most
+     2^24); a prefix sum makes row t of the directory the sorted positions
+     [start, end) of bucket t
+  3. a probe lane reads its bucket's row: one gather of two 32-bit words
+     gives its candidate range, every build row of its bucket — the
+     equal-hash run and at most a sixteenth of a false candidate a lane
+     beside it, contiguous in the same sorted order
+  4. expand ranges into candidate pairs (one host sync for the pair count →
      bucketed output capacity, like the reference's gather-map sizing)
-  4. verify true key equality per pair (collision + null filtering)
-  5. join-type specific assembly: inner gathers both sides; left/right/full add
+  5. verify true key equality per pair (bucket neighbours, hash collisions
+     and nulls filtered: the verified pairs and their order are those of an
+     equal-hash search)
+  6. join-type specific assembly: inner gathers both sides; left/right/full add
      null-extended unmatched rows; semi/anti reduce to per-row match flags.
 Residual (non-equi) conditions evaluate over the joined batch and recompute
 match bookkeeping, mirroring the reference's conditional-join iterators.
@@ -21,7 +30,7 @@ match bookkeeping, mirroring the reference's conditional-join iterators.
 
 from __future__ import annotations
 
-from typing import Iterator, List, Optional, Sequence, Tuple
+from typing import Iterator, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -41,6 +50,11 @@ from .base import (CpuExec, PhysicalPlan, TaskContext, TpuExec, bind_all,
 _HASH_MIX = np.uint64(0x9E3779B97F4A7C15)
 _HASH_INIT = np.uint64(0x243F6A8885A308D3)
 _HASH_SENTINEL = np.uint64(0xFFFFFFFFFFFFFFFF)
+# the bucket directory: 2^_DIR_BITS_A_LANE buckets a build lane, so at most a
+# sixteenth of a false candidate a probe lane; at most 2^_DIR_MAX_BITS rows of
+# two int32 (128 MB), beyond which buckets fill and the equality pass sees more
+_DIR_BITS_A_LANE = 4
+_DIR_MAX_BITS = 24
 
 
 def _mix64(h, v):
@@ -48,15 +62,20 @@ def _mix64(h, v):
     return h ^ (h >> jnp.uint64(29))
 
 
+def encode_fixed_key(bits):
+    """One side's fixed-width key as its cross-side-comparable int64 codes.
+    The eager path and the opjit traced encodes all call exactly this code
+    (they must agree bit-for-bit). BIGINT is exact on every backend and
+    DOUBLE arrives as utils/hw.f64_order_bits' int64, so one width serves
+    all fixed-width keys and a side encodes without seeing the other."""
+    return bits.astype(jnp.int64)
+
+
 def encode_fixed_key_pair(lb, rb, l_validity, r_validity,
                           l_enc: list, r_enc: list) -> None:
-    """Append one fixed-width key pair's cross-side-comparable int64 codes
-    to the per-side encode lists. The eager path and the opjit traced encode
-    both call exactly this code (they must agree bit-for-bit). BIGINT is
-    exact on every backend and DOUBLE arrives as utils/hw.f64_order_bits'
-    int64, so one width serves all fixed-width keys."""
-    l_enc.append((lb.astype(jnp.int64), l_validity))
-    r_enc.append((rb.astype(jnp.int64), r_validity))
+    """Append one fixed-width key pair's codes to the per-side encode lists."""
+    l_enc.append((encode_fixed_key(lb), l_validity))
+    r_enc.append((encode_fixed_key(rb), r_validity))
 
 
 def _encode_sides(left_cols: List[TpuColumnVector], right_cols: List[TpuColumnVector],
@@ -91,35 +110,74 @@ import functools as _functools
 import jax as _jax
 
 
+def _composite_hash(vals, valids, rows):
+    """64-bit mix of a side's int64 key codes, and the lanes that may match
+    (inside `rows`, every key valid)."""
+    cap = vals[0].shape[0]
+    h = jnp.full((cap,), _HASH_INIT, jnp.uint64)
+    ok = jnp.arange(cap) < rows
+    for v, vd in zip(vals, valids):
+        h = _mix64(h, v.view(jnp.uint64))
+        ok = ok & vd
+    return h, ok
+
+
+class PreparedBuild(NamedTuple):
+    """What every probe of one build side needs (_join_prepare_build)."""
+    b_vals: tuple    # the int64 key codes, for the equality pass
+    b_ok: object     # lanes inside the build's rows with every key valid
+    order: object    # int32: build lanes by hash, the invalid ones last
+    directory: object  # int32[2^k, 2]: a bucket's sorted positions [start, end)
+
+
+def dir_bits(b_cap: int) -> int:
+    """k of a build's directory: fixed by its capacity alone, so that programs
+    stay keyed by capacities."""
+    return min(max(b_cap - 1, 1).bit_length() + _DIR_BITS_A_LANE,
+               _DIR_MAX_BITS)
+
+
+@_functools.partial(_jax.jit, static_argnames=("bits",))
+def _join_prepare_build(b_vals, b_valids, b_rows, bits: int) -> PreparedBuild:
+    """The build half of the matcher, ONCE A BUILD: composite hash, a stable
+    sort by hash with the invalid rows last, and the bucket directory — a
+    bincount of the valid rows by their hash's top `bits` bits and a prefix
+    sum: the valid rows below bucket t, which is where its run of the
+    sorted build starts, beside where it ends (callers pass
+    bits=dir_bits(b_cap))."""
+    bh, b_ok = _composite_hash(b_vals, b_valids, b_rows)
+    # invalid rows sort to the end under the max sentinel; a valid hash that
+    # IS the sentinel steps below it, so that a bucket's valid rows stay one
+    # run of sorted positions
+    sort_key = jnp.where(b_ok, jnp.minimum(bh, _HASH_SENTINEL - np.uint64(1)),
+                         _HASH_SENTINEL)
+    order = jnp.argsort(sort_key).astype(jnp.int32)
+    n = 1 << bits
+    bucket = (bh >> jnp.uint64(64 - bits)).astype(jnp.int32)
+    sizes = jnp.zeros((n + 1,), jnp.int32).at[
+        jnp.where(b_ok, bucket + 1, n + 1)].add(1, mode="drop")
+    starts = jnp.cumsum(sizes)
+    # a row a bucket: one gather of a two-word row costs a probe 4.8 ms at
+    # 2^20 lanes on a v5e where two gathers of a word cost 16.8 (PERF.md)
+    return PreparedBuild(tuple(b_vals), b_ok, order,
+                         jnp.stack([starts[:-1], starts[1:]], axis=1))
+
+
 @_jax.jit
-def _join_probe_ranges(b_vals, b_valids, p_vals, p_valids, b_rows, p_rows):
-    """Stage A of the matcher as ONE compiled program: composite hashes,
-    build-side sort, range probe. Run eagerly, every op is its own launch and
-    its own compile (warm q3 once counted 768 XLA compiles / ~3600 op
-    dispatches), so the join core is whole-stage compiled: two programs
-    split at the single candidate-count host sync."""
-    b_cap = b_vals[0].shape[0]
-    p_cap = p_vals[0].shape[0]
-
-    def chash(vals, valids, rows, cap):
-        h = jnp.full((cap,), _HASH_INIT, jnp.uint64)
-        ok = jnp.arange(cap) < rows
-        for v, vd in zip(vals, valids):  # every key code is an int64
-            h = _mix64(h, v.view(jnp.uint64))
-            ok = ok & vd
-        return h, ok
-
-    bh, b_ok = chash(b_vals, b_valids, b_rows, b_cap)
-    ph, p_ok = chash(p_vals, p_valids, p_rows, p_cap)
-    # exclude invalid build rows: sort them to the end under a max sentinel
-    sort_key = jnp.where(b_ok, bh, _HASH_SENTINEL)
-    order = jnp.argsort(sort_key)
-    bh_sorted = jnp.take(sort_key, order)
-    ph_safe = jnp.where(p_ok, ph, jnp.zeros((), bh.dtype))
-    lo = jnp.searchsorted(bh_sorted, ph_safe, side="left")
-    hi = jnp.searchsorted(bh_sorted, ph_safe, side="right")
-    counts = jnp.where(p_ok, hi - lo, 0)
-    return counts, lo, order, b_ok, p_ok, jnp.sum(counts)
+def _join_probe_ranges(directory, p_vals, p_valids, p_rows):
+    """The probe half as ONE compiled program: composite hash, then a lane's
+    candidates are its bucket's run of the sorted build — one gather of a
+    directory row where a binary search took 2 x log2(b_cap) gathers of
+    emulated 64-bit hashes. A bucket holds the lane's equal-hash run and, at
+    a sixteenth of a row on average, neighbours that _join_emit_pairs'
+    equality pass drops like any hash collision."""
+    bits = directory.shape[0].bit_length() - 1
+    ph, p_ok = _composite_hash(p_vals, p_valids, p_rows)
+    bucket = (ph >> jnp.uint64(64 - bits)).astype(jnp.int32)
+    run = jnp.take(directory, bucket, axis=0)
+    lo = run[:, 0]
+    counts = jnp.where(p_ok, run[:, 1] - lo, 0)
+    return counts, lo, p_ok, jnp.sum(counts)
 
 
 @_functools.partial(_jax.jit, static_argnames=("out_cap",))
@@ -160,9 +218,10 @@ def _device_equi_join(build_enc, build_rows: int, probe_enc, probe_rows: int):
 
     b_vals, b_valids = split(build_enc, b_cap)
     p_vals, p_valids = split(probe_enc, p_cap)
-    counts, lo, order, b_ok, p_ok, total_dev = _join_probe_ranges(
-        b_vals, b_valids, p_vals, p_valids,
-        jnp.int32(build_rows), jnp.int32(probe_rows))
+    _, b_ok, order, directory = _join_prepare_build(
+        b_vals, b_valids, jnp.int32(build_rows), bits=dir_bits(b_cap))
+    counts, lo, p_ok, total_dev = _join_probe_ranges(
+        directory, p_vals, p_valids, jnp.int32(probe_rows))
     from ..columnar.vector import audited_sync_int
     # host sync: candidate-pair count (it sizes the static output shape, so
     # it cannot defer); the VERIFIED count below stays a device scalar
@@ -201,6 +260,17 @@ def _compact_pairs(pi, bi, ok, n_ok, deferred: bool):
 def _audited_pairs_int(n_dev) -> int:
     from ..columnar.vector import audited_sync_int
     return audited_sync_int(n_dev, "pairs")
+
+
+def index_counters(metrics):
+    """A join's (or the absorbing segment's) node metrics as query counters:
+    builds prepared (sorted, bucket directory), probe batches that read a
+    directory, and the candidate pairs they handed the equality pass —
+    candidate_pairs / rows_out is the false-candidate factor,
+    probes_indexed / builds_indexed the reuse of a build."""
+    return [("join.builds_indexed", metrics["buildsIndexed"]),
+            ("join.probes_indexed", metrics["probesIndexed"]),
+            ("join.candidate_pairs", metrics["numPairs"])]
 
 
 def _all_null_cols(attrs_or_cols, num_rows: int, capacity: int):
@@ -242,14 +312,16 @@ class TpuShuffledHashJoinExec(TpuExec):
 
     def additional_metrics(self):
         return {"buildTime": "MODERATE", "joinTime": "MODERATE",
-                "numPairs": "DEBUG", "subPartitionedJoins": "DEBUG"}
+                "numPairs": "DEBUG", "subPartitionedJoins": "DEBUG",
+                "buildsIndexed": "DEBUG", "probesIndexed": "DEBUG"}
 
     def query_counters(self):
         rows = [n.metrics["numOutputRows"]
                 for n in (self.children[0], self.children[1], self)]
         return [("join.rows_left", rows[0]), ("join.rows_right", rows[1]),
                 ("join.rows_out", rows[2]),
-                ("join.subpartitioned", self.metrics["subPartitionedJoins"])]
+                ("join.subpartitioned", self.metrics["subPartitionedJoins"])
+                ] + index_counters(self.metrics)
 
     def mesh_counters(self):
         # the probe side a chip's task took in, beside the node's own
@@ -406,6 +478,8 @@ class TpuShuffledHashJoinExec(TpuExec):
         pi, bi, ok, n_ok, total, out_cap = _device_equi_join(
             r_enc, right.num_rows, l_enc, left.num_rows)
         self.metrics["numPairs"].add(total)
+        self.metrics["buildsIndexed"].add(1)  # unfused: a build a pair
+        self.metrics["probesIndexed"].add(1)
         from ..config import DEFERRED_COMPACTION
         deferred = bool(ctx.conf.get(DEFERRED_COMPACTION))
         cpi, cbi, slot_ok, n_pairs = _compact_pairs(pi, bi, ok, n_ok,

@@ -78,8 +78,9 @@ _STATS = {"hits": 0, "misses": 0, "traces": 0, "trace_time_ns": 0}
 #: "project", "filter", "joinenc", "exchsplit", "pids", "aggsort",
 #: "aggreduce", plus the whole-stage/grouped kinds: "segmentg" — one fused
 #: segment over a GROUP of partitions' batches, "exchsplitg" — the hash
-#: encode+split of a whole partition group, "joinprobe"/"joinemit" — a fused
-#: segment's streamed-side join probe and pair-emit+downstream halves,
+#: encode+split of a whole partition group, "joinbuild" — a fused segment's
+#: join build prepared once (sort + bucket directory), "joinprobe"/"joinemit"
+#: — its streamed-side join probe and pair-emit+downstream halves a batch,
 #: "aggstage" — the sort-based aggregate's whole update as one launch). A
 #: fully fused N-operator chain shows ONE "segment" dispatch per batch where
 #: the per-operator path shows N "project"/"filter" dispatches; "exchsplit"
@@ -1158,10 +1159,11 @@ def partition_split_plan_grouped(batches: Sequence[TpuColumnarBatch],
 
 # ---------------------------------------------------------------------------
 # fused join probe (execs/fusion.py): the streamed side of an inner equi-join
-# absorbed into a stage segment. Two programs split at the inherent
-# candidate-count sync: "joinprobe" (upstream chain + key encode + hash-range
-# probe) and "joinemit" (pair expansion + verify + both-side gather +
-# downstream chain + one compaction).
+# absorbed into a stage segment. "joinbuild" once a build (key encode + hash
+# + sort + bucket directory), then two programs a probe batch split at the
+# inherent candidate-count sync: "joinprobe" (upstream chain + key encode +
+# directory probe) and "joinemit" (pair expansion + verify + both-side
+# gather + downstream chain + one compaction).
 # ---------------------------------------------------------------------------
 
 
@@ -1182,46 +1184,79 @@ def _key_cols_sig(cols) -> Tuple:
     return tuple((str(c.data.dtype), c.validity is not None) for c in cols)
 
 
+def join_build_program(build_keys, build_rows, eval_ctx: EvalContext,
+                       metrics=None):
+    """The build half of a fused join in ONE launch, once a build: encode
+    the build's key columns, composite-hash, sort and lay the bucket
+    directory over the sorted hashes (joins._join_prepare_build — the same
+    traced code the unfused join runs a pair). Every probe batch of the
+    build takes the result as operands. Returns a joins.PreparedBuild, or
+    None when pinned eager."""
+    b_cap = build_keys[0].capacity
+    key = ("joinbuild", b_cap, _key_cols_sig(build_keys), _conf_fp(eval_ctx))
+    b_dtypes = [c.dtype for c in build_keys]
+
+    def build():
+        def fn(bkeys, b_rows):
+            from .aggregates import _sortable_bits
+            from .joins import (_join_prepare_build, dir_bits,
+                                encode_fixed_key)
+            b_vals, b_valids = [], []
+            for dt, (data, valid) in zip(b_dtypes, bkeys):
+                bv = TpuColumnVector(dt, data, valid, b_cap)
+                b_vals.append(encode_fixed_key(_sortable_bits(bv)))
+                b_valids.append(valid if valid is not None
+                                else jnp.ones((b_cap,), jnp.bool_))
+            return _join_prepare_build(b_vals, b_valids, jnp.int32(b_rows),
+                                       bits=dir_bits(b_cap))
+        return fn
+
+    out = _cached_call(
+        key, build,
+        (tuple((c.data, c.validity) for c in build_keys), build_rows),
+        eval_ctx, metrics)
+    return None if out is _FAILED else out
+
+
 def join_probe_program(out_exprs, out_dtypes, filters, key_exprs,
-                       batch: TpuColumnarBatch, build_keys, build_rows,
+                       batch: TpuColumnarBatch, prepared,
                        eval_ctx: EvalContext, metrics=None):
     """The probe half of a fused join in ONE launch: apply the flattened
     upstream projection/filter chain to the probe batch, evaluate+encode the
-    probe keys, encode the build keys (passed as device args so both sides
-    make the same cross-width limb decisions, exactly like
-    joins._encode_sides), composite-hash both sides and range-probe the
-    sorted build hashes (joins._join_probe_ranges — the same traced code the
-    unfused join runs, so candidates are bit-identical). Upstream filters do
-    not compact: failing rows are masked out of p_ok, which produces the
-    same candidate set and pair order the compact-then-probe path does.
+    probe keys (joins.encode_fixed_key, the build's own encode),
+    composite-hash them and read each lane's candidate range from the
+    prepared build's directory (joins._join_probe_ranges — the same traced
+    code the unfused join runs, so candidates are bit-identical). Upstream
+    filters do not compact: failing rows are masked out of p_ok, which
+    produces the same candidate set and pair order the compact-then-probe
+    path does.
 
     Returns (state, jit_cols) where state carries everything the emit
-    program needs (counts/lo/order/b_ok/p_ok/encoded values/total), or None
-    when pinned eager."""
+    program needs (counts/lo/p_ok and the probe's encoded values beside the
+    prepared build's order/b_ok/values, and total), or None when pinned
+    eager."""
     cap = batch.capacity
-    b_cap = build_keys[0].capacity
     out_exprs = list(out_exprs)
     out_dtypes = list(out_dtypes)
     filters = list(filters)
     key_exprs = list(key_exprs)
     all_exprs = out_exprs + filters + key_exprs
     sig = _input_sig(all_exprs, batch)
-    bsig = _key_cols_sig(build_keys)
     key = ("joinprobe", tuple(_fp(e) for e in out_exprs),
            tuple(_fp(f) for f in filters),
            tuple(_fp(k) for k in key_exprs),
-           tuple(type(d).__name__ for d in out_dtypes), cap, b_cap,
-           len(batch.columns), sig, bsig, _conf_fp(eval_ctx))
+           tuple(type(d).__name__ for d in out_dtypes), cap,
+           prepared.directory.shape[0], len(batch.columns), sig,
+           _conf_fp(eval_ctx))
     src_dtypes = {o: batch.columns[o].dtype for (o, _, _, _) in sig}
     n_cols = len(batch.columns)
-    b_dtypes = [c.dtype for c in build_keys]
 
     tctx = _trace_ctx(eval_ctx)
 
     def build():
-        def fn(flat, bkeys, b_rows):
+        def fn(flat, directory):
             from .aggregates import _sortable_bits
-            from .joins import _join_probe_ranges, encode_fixed_key_pair
+            from .joins import _join_probe_ranges, encode_fixed_key
             rowmask = jnp.arange(cap) < flat[0]
             tb = _rebuild_batch(flat, sig, src_dtypes, n_cols, cap, rowmask)
             keep = rowmask
@@ -1231,44 +1266,30 @@ def join_probe_program(out_exprs, out_dtypes, filters, key_exprs,
                 if c.validity is not None:
                     m = m & c.validity
                 keep = keep & m
-            p_enc, b_enc = [], []
-            for k, dt, (b_data, b_valid) in zip(key_exprs, b_dtypes, bkeys):
+            p_vals, p_valids = [], []
+            for k in key_exprs:
                 pc = to_column(k.eval_tpu(tb, tctx), tb, k.dtype)
-                bv = TpuColumnVector(dt, b_data, b_valid, b_cap)
-                p_valid = (pc.validity & keep) if pc.validity is not None \
-                    else keep
-                # probe = left, build = right: identical call shape to
-                # joins._encode_sides so the limb decisions agree
-                encode_fixed_key_pair(_sortable_bits(pc), _sortable_bits(bv),
-                                      p_valid, b_valid, p_enc, b_enc)
-            def split(enc, c):
-                vals = [v for v, _ in enc]
-                valids = [vd if vd is not None
-                          else jnp.ones((c,), jnp.bool_) for _, vd in enc]
-                return vals, valids
-            p_vals, p_valids = split(p_enc, cap)
-            b_vals, b_valids = split(b_enc, b_cap)
-            counts, lo, order, b_ok, p_ok, total = _join_probe_ranges(
-                b_vals, b_valids, p_vals, p_valids,
-                jnp.int32(b_rows), jnp.int32(flat[0]))
+                p_vals.append(encode_fixed_key(_sortable_bits(pc)))
+                p_valids.append((pc.validity & keep)
+                                if pc.validity is not None else keep)
+            counts, lo, p_ok, total = _join_probe_ranges(
+                directory, p_vals, p_valids, jnp.int32(flat[0]))
             outs = []
             for e, dt in zip(out_exprs, out_dtypes):
                 c = to_column(e.eval_tpu(tb, tctx), tb, dt)
                 outs.append((c.data, c.validity))
-            return (counts, lo, order, b_ok, p_ok, tuple(b_vals),
-                    tuple(p_vals), total, tuple(outs))
+            return counts, lo, p_ok, tuple(p_vals), total, tuple(outs)
         return fn
 
-    bkey_args = tuple((c.data, c.validity) for c in build_keys)
     out = _cached_call(
-        key, build,
-        (tuple(_flat_args(batch, sig)), bkey_args, build_rows),
+        key, build, (tuple(_flat_args(batch, sig)), prepared.directory),
         eval_ctx, metrics)
     if out is _FAILED:
         return None
-    counts, lo, order, b_ok, p_ok, b_vals, p_vals, total, outs = out
-    state = {"counts": counts, "lo": lo, "order": order, "b_ok": b_ok,
-             "p_ok": p_ok, "b_vals": list(b_vals), "p_vals": list(p_vals),
+    counts, lo, p_ok, p_vals, total, outs = out
+    state = {"counts": counts, "lo": lo, "order": prepared.order,
+             "b_ok": prepared.b_ok, "p_ok": p_ok,
+             "b_vals": list(prepared.b_vals), "p_vals": list(p_vals),
              "total": total}
     jit_cols = [TpuColumnVector(dt, d, v, batch.rows_lazy)
                 for (d, v), dt in zip(outs, out_dtypes)]

@@ -342,3 +342,220 @@ def test_mixed_width_key_join_ground_truth(how):
         got = out.to_arrow().num_rows
         want = want_inner if how != "semi" else 5000
         assert got == want, (enabled, how, got, want)
+
+
+# ---------------------------------------------------------------------------
+# the probe's bucket directory (execs/joins.py: _join_prepare_build,
+# _join_probe_ranges) against a plain sort-merge reference
+# ---------------------------------------------------------------------------
+
+
+def _sort_merge_pairs(b_keys, b_ok, p_keys, p_ok):
+    """Plain reference: for each probe lane in order, the build lanes whose
+    keys all equal its own, in build order (equal keys hash alike and the
+    build's sort is stable, so that is the matcher's order too); a lane
+    with a null key or beyond its side's rows pairs with nothing."""
+    import numpy as np
+    b_rows = np.stack(b_keys, axis=1)
+    p_rows = np.stack(p_keys, axis=1)
+    return [(pi, bi)
+            for pi in np.flatnonzero(p_ok)
+            for bi in np.flatnonzero(b_ok)
+            if (p_rows[pi] == b_rows[bi]).all()]
+
+
+def _directory_pairs(b_enc, b_rows, p_enc, p_rows, bits=None):
+    """The matcher's three programs composed as `_device_equi_join` composes
+    them, with the directory's size open to the test: (verified pairs in
+    emit order, candidate count, the directory)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from spark_rapids_tpu.columnar.vector import bucket_capacity
+    from spark_rapids_tpu.execs import joins
+
+    def split(enc):
+        cap = enc[0][0].shape[0]
+        return ([v for v, _ in enc],
+                [vd if vd is not None else jnp.ones((cap,), jnp.bool_)
+                 for _, vd in enc])
+
+    b_vals, b_valids = split(b_enc)
+    p_vals, p_valids = split(p_enc)
+    prep = joins._join_prepare_build(
+        b_vals, b_valids, jnp.int32(b_rows),
+        bits=bits or joins.dir_bits(b_vals[0].shape[0]))
+    counts, lo, p_ok, total = joins._join_probe_ranges(
+        prep.directory, p_vals, p_valids, jnp.int32(p_rows))
+    total = int(total)
+    pi, bi, ok, n_ok = joins._join_emit_pairs(
+        counts, lo, prep.order, prep.b_ok, p_ok, list(prep.b_vals), p_vals,
+        jnp.int32(total), out_cap=bucket_capacity(max(total, 1)))
+    ok = np.asarray(ok)
+    assert int(n_ok) == ok.sum()
+    pairs = list(zip(np.asarray(pi)[ok].tolist(), np.asarray(bi)[ok].tolist()))
+    return pairs, total, np.asarray(prep.directory)
+
+
+def _int_side(keys, valid, rows, cap):
+    """One side's encoded keys: int64 codes in `cap` lanes, the lanes past
+    `rows` filled with a key that real rows hold too (padding must not pair)."""
+    import jax.numpy as jnp
+    import numpy as np
+    enc = []
+    for col, ok in zip(keys, valid):
+        buf = np.full(cap, col[0] if len(col) else 0, np.int64)
+        buf[:rows] = col
+        v = np.ones(cap, bool)
+        v[:rows] = ok
+        enc.append((jnp.asarray(buf), jnp.asarray(v)))
+    return enc
+
+
+def _directory_case(name):
+    """(build keys, build validity, probe keys, probe validity, bits) as
+    lists a key column; bits None = the size production takes."""
+    import numpy as np
+    rng = np.random.default_rng(sum(map(ord, name)))
+    yes = lambda n: np.ones(n, bool)  # noqa: E731
+    if name == "unique":
+        b = rng.permutation(400)[:150].astype(np.int64)
+        p = rng.integers(0, 400, 300)
+        return [b], [yes(150)], [p], [yes(300)], None
+    if name == "duplicate_build":
+        b = rng.integers(0, 40, 150)
+        p = rng.integers(0, 60, 300)
+        return [b], [yes(150)], [p], [yes(300)], None
+    if name == "shared_bucket":
+        # two buckets for 90 distinct keys: every lane's range holds build
+        # rows of other keys, and only the equality pass tells them apart
+        b = rng.integers(0, 90, 150)
+        p = rng.integers(0, 120, 300)
+        return [b], [yes(150)], [p], [yes(300)], 1
+    if name == "nulls_and_padding":
+        b = rng.integers(0, 30, 100)
+        p = rng.integers(0, 30, 200)
+        return [b], [rng.random(100) > 0.3], [p], [rng.random(200) > 0.3], None
+    if name == "two_columns":
+        b = [rng.integers(0, 8, 150), rng.integers(0, 6, 150)]
+        p = [rng.integers(0, 8, 300), rng.integers(0, 6, 300)]
+        return (b, [yes(150), rng.random(150) > 0.2],
+                p, [yes(300), rng.random(300) > 0.2], None)
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("name", ["unique", "duplicate_build", "shared_bucket",
+                                  "nulls_and_padding", "two_columns"])
+def test_directory_probe_pairs_match_sort_merge(name):
+    import numpy as np
+
+    from spark_rapids_tpu.columnar.vector import bucket_capacity
+    from spark_rapids_tpu.execs import joins
+    b_keys, b_valid, p_keys, p_valid, bits = _directory_case(name)
+    b_rows, p_rows = len(b_keys[0]), len(p_keys[0])
+    b_cap, p_cap = bucket_capacity(b_rows + 1), bucket_capacity(p_rows + 1)
+    assert b_cap > b_rows and p_cap > p_rows  # padding lanes on both sides
+    b_enc = _int_side(b_keys, b_valid, b_rows, b_cap)
+    p_enc = _int_side(p_keys, p_valid, p_rows, p_cap)
+    want = _sort_merge_pairs(b_keys, np.logical_and.reduce(b_valid),
+                             p_keys, np.logical_and.reduce(p_valid))
+    assert want, name
+    got, total, directory = _directory_pairs(b_enc, b_rows, p_enc, p_rows, bits)
+    assert got == want
+    # the directory: a bucket's [start, end), a prefix sum over the valid
+    # build rows alone
+    assert directory.shape == (1 << (bits or joins.dir_bits(b_cap)), 2)
+    assert directory[0, 0] == 0 and (directory[1:, 0] == directory[:-1, 1]).all()
+    assert (directory[:, 1] >= directory[:, 0]).all()
+    assert directory[-1, 1] == np.logical_and.reduce(b_valid).sum()
+    assert total >= len(want)
+    if bits == 1:
+        assert total > 2 * len(want)     # the false candidates were there
+    else:
+        # the join's own entry sizes the directory itself: same pairs
+        pi, bi, ok, _, total2, _ = joins._device_equi_join(
+            b_enc, b_rows, p_enc, p_rows)
+        ok = np.asarray(ok)
+        assert total2 == total
+        assert list(zip(np.asarray(pi)[ok].tolist(),
+                        np.asarray(bi)[ok].tolist())) == want
+
+
+def test_directory_probe_string_key_pairs_match_sort_merge():
+    """A string key reaches the matcher as codes of a dictionary over both
+    sides (`_encode_sides`), nulls among them."""
+    import numpy as np
+    import pyarrow as pa
+
+    from spark_rapids_tpu.columnar.vector import TpuColumnVector
+    from spark_rapids_tpu.execs import joins
+    rng = np.random.default_rng(5)
+    words = np.array(["ab", "abc", "b", "", "ba", "cab", "c"])
+    b = [None if rng.random() < 0.2 else str(w) for w in rng.choice(words, 60)]
+    p = [None if rng.random() < 0.2 else str(w) for w in rng.choice(words, 90)]
+    bc = TpuColumnVector.from_arrow(pa.array(b, pa.string()))
+    pc = TpuColumnVector.from_arrow(pa.array(p, pa.string()))
+    p_enc, b_enc = joins._encode_sides([pc], [bc], 90, 60,
+                                       pc.capacity, bc.capacity)
+    want = [(pi, bi) for pi, pw in enumerate(p) for bi, bw in enumerate(b)
+            if pw is not None and pw == bw]
+    got, total, _ = _directory_pairs(b_enc, 60, p_enc, 90)
+    assert got == want and total >= len(want)
+
+
+#: every equi-join outside a segment: no broadcast, join fusion off
+_UNFUSED = {"spark.sql.autoBroadcastJoinThreshold": "-1",
+            "spark.rapids.tpu.opjit.fuseJoins": "false"}
+
+
+@pytest.mark.parametrize("join_type", ALL_JOIN_TYPES)
+def test_directory_probe_every_join_type(join_type):
+    """`TpuShuffledHashJoinExec._join` reads verified pairs only, whatever
+    the type: the CPU's rows, and the three counters of the directory."""
+    from spark_rapids_tpu.session import TpuSession
+
+    def fn(s):
+        l, r = _sides(s, n_left=300, n_right=120, key_hi=60)
+        return l.join(r, on="k", how=join_type)
+    assert_tpu_and_cpu_are_equal_collect(fn, conf=_UNFUSED, ignore_order=True)
+    s = TpuSession({**_UNFUSED, "spark.rapids.sql.enabled": "true"})
+    df = fn(s)
+    assert "TpuShuffledHashJoin" in df.explain() \
+        or "TpuShuffledSymmetricHashJoin" in df.explain()
+    rows = df.collect()
+    c = s.last_query_phases()["counters"]
+    assert c["join.builds_indexed"] == c["join.probes_indexed"] == 1
+    if join_type == "inner":
+        assert c["join.candidate_pairs"] >= len(rows) > 0
+    assert c["join.candidate_pairs"] > 0
+
+
+def test_a_build_probed_by_two_batches_is_prepared_once():
+    """A broadcast build inside a segment: the probe side's two partitions
+    share one prepared build (sort + directory), as they share the build."""
+    import numpy as np
+    import pyarrow as pa
+
+    from spark_rapids_tpu.execs import opjit
+    from spark_rapids_tpu.session import TpuSession
+    s = TpuSession({"spark.rapids.sql.enabled": "true"})
+    rng = np.random.default_rng(3)
+    fk = rng.integers(0, 400, 4000)
+    fact = s.createDataFrame(pa.table({"fk": fk, "v": np.arange(4000)}),
+                             num_partitions=2)
+    dim = s.createDataFrame(pa.table({"pk": np.arange(0, 400, 2),
+                                      "w": np.arange(200) * 1.5}))
+    df = fact.join(dim, on=fact["fk"] == dim["pk"]) \
+        .select((F.col("v") + 1).alias("v1"), "w")
+    assert "TpuFusedSegment[BroadcastHashJoin" in df.explain()
+    before = dict(opjit.cache_stats()["calls_by_kind"])
+    rows = df.collect()
+    after = opjit.cache_stats()["calls_by_kind"]
+    assert len(rows) == int((fk % 2 == 0).sum())
+    c = s.last_query_phases()["counters"]
+    assert (c["join.builds_indexed"], c["join.probes_indexed"]) == (1, 2)
+    assert len(rows) <= c["join.candidate_pairs"] < 2 * len(rows)
+    assert c["join.rows_out"] == len(rows)
+    launched = {k: after.get(k, 0) - before.get(k, 0)
+                for k in ("joinbuild", "joinprobe", "joinemit")}
+    assert launched == {"joinbuild": 1, "joinprobe": 2, "joinemit": 2}

@@ -274,6 +274,12 @@ def test_the_chips_task_rows_add_up_to_the_one_chip_runs(mesh_run,
     assert sum(per_chip) == one["join.rows_left"] + one_chip_run.scanned
     assert mesh_run.counters["join.rows_left"] == one["join.rows_left"]
     assert mesh_run.counters["join.rows_out"] == one["join.rows_out"]
+    # a chip prepares (sorts, lays the directory of) its top-join partition's
+    # build and its own copy of the broadcast build, each once, and probes
+    # each with one batch
+    c = mesh_run.counters
+    assert c["join.builds_indexed"] == c["join.probes_indexed"] == 2 * N
+    assert c["join.candidate_pairs"] >= c["join.rows_out"]
     # every chip took its share in: within what the hash gives
     assert max(per_chip) / (sum(per_chip) / N) < 1.1, per_chip
 
@@ -416,7 +422,9 @@ def test_the_cell_runs_and_its_metrics_read():
     from spark_rapids_tpu.io import device_decode
     device_decode.reset_for_tests()
     pm.MeshContext.reset_for_tests()
-    r = run.run_cell(CELL, SEED, 0.3, trace=False, rehearsal_rows=1 << 14)
+    # 1.5 s: on a loaded machine a window of 0.3 s once closed before the
+    # closed loop had sent its first query (tier-1 under six workers)
+    r = run.run_cell(CELL, SEED, 1.5, trace=False, rehearsal_rows=1 << 14)
     assert r["correct"] is True and r["attempted"] >= 1, r["checks"]
     assert r["metrics"]["rows_per_s"]["value"] > 0
     ctx = type("Ctx", (), {})()

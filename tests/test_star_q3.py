@@ -155,6 +155,10 @@ def test_q3_phases_cover_the_query_and_count_the_joins():
     late = li["l_shipdate"] > q3.DATE
     joined = int(open_order.sum()) + int((late & open_order[li["l_orderkey"]]).sum())
     assert counters["join.rows_out"] == joined
+    # each join's build is sorted and given its bucket directory once, each
+    # probe batch reads one; the candidates hold every true pair and few more
+    assert counters["join.builds_indexed"] == counters["join.probes_indexed"] == 2
+    assert joined <= counters["join.candidate_pairs"] < 2.5 * joined
     # what the two joins' children put out, once
     assert counters["join.rows_left"] == \
         int((orders["o_orderdate"] < q3.DATE).sum()) + int(late.sum())
@@ -375,7 +379,7 @@ def test_the_cell_runs_and_its_metrics_read():
     # a run is a process of its own; here the worker has run other files, and
     # the harness reads the scan's fallback counts as they stand
     device_decode.reset_for_tests()
-    r = run.run_cell(CELL, 2**31 + 29, 0.3, trace=False, rehearsal_rows=1 << 14)
+    r = run.run_cell(CELL, 2**31 + 29, 1.5, trace=False, rehearsal_rows=1 << 14)
     assert r["correct"] is True and r["attempted"] >= 1, r["checks"]
     assert r["metrics"]["rows_per_s"]["value"] > 0
     summaries = obs.metrics.recent_queries(r["attempted"])

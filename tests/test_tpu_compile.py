@@ -242,6 +242,40 @@ def test_join_key_encode_with_double_key_compiles_for_v5e(monkeypatch,
 
 
 # ---------------------------------------------------------------------------
+# the fused join's three programs: the build prepared once (hash, sort, the
+# bucket directory's scatter-add and prefix sum), the probe's one gather of a
+# directory row a lane, the emit
+# ---------------------------------------------------------------------------
+
+def test_fused_join_programs_compile_for_v5e(monkeypatch, one_chip):
+    from spark_rapids_tpu.execs import joins
+    rng = np.random.default_rng(5)
+    s = TpuSession({})
+    fact = s.createDataFrame(pa.table({"fk": rng.integers(0, 4000, 1 << 14),
+                                       "v": np.arange(1 << 14)}))
+    dim = s.createDataFrame(pa.table({"pk": np.arange(0, 4000, 2),
+                                      "w": np.arange(2000) * 0.5}))
+    df = fact.join(dim, on=fact["fk"] == dim["pk"]) \
+        .select((F.col("v") + 1).alias("v1"), "w")
+    assert "HashJoin+Project]" in df.explain()      # the join in a segment
+    with _recording_jit(monkeypatch) as log:
+        assert df.count() > 0
+        programs = _calls(log, "execs.opjit")
+    # the build's program is the one that hands a directory back, the
+    # probe's the one that takes it
+    dir_shape = (1 << joins.dir_bits(2048), 2)
+    takes = [c for c in programs if any(
+        np.shape(a) == dir_shape for a in jax.tree.leaves(c[1]))]
+    assert len(takes) == 1, "no program took the build's directory"
+    assert len(programs) >= 3           # joinbuild, joinprobe, joinemit
+    for jitted, args, kwargs in programs:
+        compiled = _compile_for(jitted, args, kwargs, one_chip)
+        if (jitted, args, kwargs) == takes[0]:
+            # a lane's range costs gathers, no loop: the binary search is gone
+            assert "while" not in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
 # the four-chip collective exchange
 # ---------------------------------------------------------------------------
 
