@@ -1719,9 +1719,10 @@ class DeviceFileDecoder:
                     _bump("dense_values",
                           sum(st.dense_values for st in staged))
                     _bump("device_columns", len(kept))
-                    for st in staged:
-                        if st.general is not None:
-                            _bump("general_" + st.general)
+                    general = [st.general for st in staged
+                               if st.general is not None]
+                    for reason in general:
+                        _bump("general_" + reason)
                     opjit.record_external_dispatch("parquet_decode")
                     outs = fn(np.int64(num_rows), *uploaded)
 
@@ -1771,6 +1772,12 @@ class DeviceFileDecoder:
                 _verify_against_host(self.pf, rgi, batch, list(dev_cols),
                                      self.attrs_by_name)
             if metrics is not None:
+                # the scan node's own counts of what _STATS counted above:
+                # its query's `scan.*` counters (a row group that raised
+                # before here is re-read on the host and counts as fallback)
                 metrics["decodeDispatches"].add(1)
                 metrics["decodeFallbackColumns"].add(len(host_names))
+                metrics["rowsDecoded"].add(num_rows)
+                metrics["columnsDecoded"].add(len(kept))
+                metrics["columnsGeneral"].add(len(general))
             return batch

@@ -585,7 +585,19 @@ class TpuFileScanExec(FileScanBase, TpuExec):
         return {"scanTime": "ESSENTIAL", "uploadTime": "MODERATE",
                 "filesRead": "DEBUG", "decodeTime": "MODERATE",
                 "hostDecodeTime": "MODERATE", "decodeDispatches": "DEBUG",
-                "decodeFallbackColumns": "DEBUG"}
+                "decodeFallbackColumns": "DEBUG",
+                "rowsDecoded": "DEBUG", "columnsDecoded": "DEBUG",
+                "columnsGeneral": "DEBUG"}
+
+    def query_counters(self):
+        # what the device decoder read for this query; a file, row group or
+        # column that fell back to the host counts under decode_stats()'
+        # fallback_* and not here
+        return [("scan.files", self.metrics["filesRead"]),
+                ("scan.row_groups", self.metrics["decodeDispatches"]),
+                ("scan.rows", self.metrics["rowsDecoded"]),
+                ("scan.columns_decoded", self.metrics["columnsDecoded"]),
+                ("scan.columns_general", self.metrics["columnsGeneral"])]
 
     def _device_decode_applies(self, ctx: TaskContext) -> bool:
         """Whole-scan eligibility for the device parquet decode path;

@@ -172,12 +172,12 @@ def row_group_file(tmp_path_factory):
     return path
 
 
-def _decode_programs(monkeypatch, path, columns):
+def _decode_programs(monkeypatch, path, columns, rows=ROW_GROUP):
     s = TpuSession({})
     with _recording_jit(monkeypatch) as log:
         out = s.read.parquet(path).select(*columns).agg(
             *[F.count(F.col(c)).alias(c) for c in columns]).collect()
-        assert list(out[0].values()) == [ROW_GROUP] * len(columns)
+        assert list(out[0].values()) == [rows] * len(columns)
         calls = _calls(log, "io.device_decode")
     assert len(calls) == 1, "one decode program per row group"
     return calls[0]
@@ -194,6 +194,45 @@ def test_parquet_decode_program_compiles_for_v5e(monkeypatch, one_chip,
     jitted, args, kwargs = _decode_programs(monkeypatch, row_group_file,
                                             columns)
     _compile_for(jitted, args, kwargs, one_chip)
+
+
+@pytest.fixture(scope="module")
+def star_files(tmp_path_factory):
+    """ORDERS' and CUSTOMER's columns as `tpch-sf1-star-parquet` writes
+    them, ROW_GROUP rows a group, with the writer's dictionary page cut so
+    that the BIGINT keys outgrow it inside the row group as they do at 2^20
+    rows: dictionary pages, then PLAIN pages."""
+    from chipbench import datagen, manifest
+    conf = manifest.Cell("parquet-q3-stream").config
+    schema = datagen.tables(conf, ROW_GROUP * 4)        # ORDERS: 2^16 rows
+    out = {}
+    for name in ("orders", "customer"):
+        t = schema[name]
+        n = min(t.rows, ROW_GROUP)
+        path = str(tmp_path_factory.mktemp("tpu_compile") / f"{name}.parquet")
+        pq.write_table(t.to_arrow(t.generate(7, list(t.columns), 0, n)), path,
+                       row_group_size=ROW_GROUP, compression="snappy",
+                       use_dictionary=True, dictionary_pagesize_limit=64 << 10)
+        out[name] = (path, n)
+    return out
+
+
+@pytest.mark.parametrize("table,columns", [
+    ("orders", ("o_orderkey", "o_custkey", "o_orderdate", "o_shippriority")),
+    ("customer", ("c_custkey", "c_mktsegment")),
+], ids=["bigint_keys_that_change_encoding", "ragged_dictionary_strings"])
+def test_star_scan_decode_programs_compile_for_v5e(monkeypatch, one_chip,
+                                                   star_files, table, columns):
+    """The decoder's sides that only Q3's files reach (ISSUE 33): a BIGINT
+    chunk of dictionary pages then PLAIN pages beside an int64 dictionary of
+    few entries, and a REQUIRED dictionary of strings of different lengths."""
+    from test_parquet_q3 import data_page_encodings
+    path, n = star_files[table]
+    if table == "orders":
+        pages = data_page_encodings(path)               # o_orderkey
+        assert pages[0] == 8 and pages[-1] == 0, pages
+        assert set(data_page_encodings(path, column=1)) == {8}      # o_custkey
+    _compile_for(*_decode_programs(monkeypatch, path, columns, rows=n), one_chip)
 
 
 def test_compiled_agg_stage_q1_shape_compiles_for_v5e(monkeypatch, one_chip,
