@@ -787,11 +787,33 @@ def _decompress(codec: Optional[str], body, usize: int) -> bytes:
     return data
 
 
+def _page_laps(codec: Optional[str], laps):
+    """The page walk's steps as laps of `laps` (an `obs.PhaseLaps`):
+    `scan.decompress` around each page's decompression, `scan.walk` around
+    the level and index run walk (and, at the call sites, the page headers
+    and the index histogram). Returns (decompress, walk, count_valid, lap)."""
+    lap = laps.lap
+
+    def decompress(body, usize: int) -> bytes:
+        with lap("scan.decompress"):
+            return _decompress(codec, body, usize)
+
+    def walk(*args) -> List[List[int]]:
+        with lap("scan.walk"):
+            return _walk_runs(*args)
+
+    def count_valid(*args) -> int:
+        with lap("scan.walk"):
+            return _count_valid(*args)
+
+    return decompress, walk, count_valid, lap
+
+
 _STRING_CHAR_LIMIT = 1 << 31  # int32 offsets: > 2^31 chars cannot address
 
 
 def _stage_string_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
-                         cap: int) -> _Staged:
+                         cap: int, laps) -> _Staged:
     """BYTE_ARRAY staging: def-level runs exactly like the fixed path;
     value regions stage as either an index run table + raw dictionary
     bytes (RLE_DICTIONARY pages) or per-value (start, length) tables into
@@ -800,6 +822,7 @@ def _stage_string_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
     host-side (index histogram · dictionary lengths, or the sum of PLAIN
     lengths) so the one decode dispatch keeps a static char capacity."""
     codec = _CODECS[cc.compression]
+    decompress, walk, count_valid, lap = _page_laps(codec, laps)
     obs_on = _obs._ACTIVE
     lv_runs: List[List[int]] = []
     lv_parts: List[bytes] = []
@@ -822,7 +845,8 @@ def _stage_string_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
         pos = 0
         end = len(chunk)
         while pos < end and rows_seen < num_rows:
-            hdr, dpos = _read_struct(chunk, pos)
+            with lap("scan.walk"):
+                hdr, dpos = _read_struct(chunk, pos)
             ptype, usize, csize = hdr[1], hdr[2], hdr[3]
             if usize < 0 or csize < 0 or dpos + csize > end:
                 raise ValueError("page body out of bounds")
@@ -836,7 +860,7 @@ def _stage_string_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
                 dph = hdr[7]
                 if dph[2] not in (_ENC_PLAIN, _ENC_PLAIN_DICT):
                     raise ValueError(f"dictionary encoding {dph[2]}")
-                data = _decompress(codec, body, usize)
+                data = decompress(body, usize)
                 n_dict = dph[1]
                 region = np.frombuffer(data, np.uint8)
                 starts, lens = _byte_array_starts(region, n_dict)
@@ -847,7 +871,7 @@ def _stage_string_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
             if ptype not in (_PAGE_DATA_V1, _PAGE_DATA_V2):
                 continue  # index pages etc.: metadata only
             if ptype == _PAGE_DATA_V1:
-                data = _decompress(codec, body, usize)
+                data = decompress(body, usize)
                 dph = hdr[5]
                 nv, enc, denc = dph[1], dph[2], dph[3]
                 p = 0
@@ -858,11 +882,11 @@ def _stage_string_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
                     p = 4 + dlen
                     if dlen < 0 or p > len(data):
                         raise ValueError("def levels out of bounds")
-                    lv_runs += _walk_runs(data, 4, p, 1, nv,
-                                          rows_seen, lv_bits)
+                    lv_runs += walk(data, 4, p, 1, nv,
+                                    rows_seen, lv_bits)
                     lv_parts.append(data[4:p])
                     lv_bits += dlen * 8
-                    nnn = _count_valid(data, 4, p, nv)
+                    nnn = count_valid(data, 4, p, nv)
                 else:
                     nnn = nv
                 region = data[p:]
@@ -877,12 +901,12 @@ def _stage_string_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
                 levels = bytes(body[:dl_len])
                 region = body[dl_len:]
                 if codec is not None and v2.get(7, True):
-                    region = _decompress(codec, region, usize - dl_len)
+                    region = decompress(region, usize - dl_len)
                 else:
                     region = bytes(region)
                 if plan.nullable:
-                    lv_runs += _walk_runs(levels, 0, dl_len, 1, nv,
-                                          rows_seen, lv_bits)
+                    lv_runs += walk(levels, 0, dl_len, 1, nv,
+                                    rows_seen, lv_bits)
                     lv_parts.append(levels)
                     lv_bits += dl_len * 8
                 elif nnulls:
@@ -900,12 +924,13 @@ def _stage_string_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
                     bw = region[0]
                     if bw > 32:
                         raise ValueError(f"index bit width {bw}")
-                    val_runs += _walk_runs(region, 1, len(region), bw, nnn,
-                                           dense_seen, val_bits)
+                    val_runs += walk(region, 1, len(region), bw, nnn,
+                                     dense_seen, val_bits)
                     val_parts.append(region[1:])
                     val_bits += (len(region) - 1) * 8
-                    _accum_index_counts(region, 1, len(region), bw, nnn,
-                                        idx_counts)
+                    with lap("scan.walk"):
+                        _accum_index_counts(region, 1, len(region), bw, nnn,
+                                            idx_counts)
                 elif enc == _ENC_PLAIN:
                     saw_plain = True
                     rb = np.frombuffer(bytes(region), np.uint8)
@@ -1043,13 +1068,14 @@ def _stage_string_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
 
 
 def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
-                  cap: int) -> _Staged:
+                  cap: int, laps) -> _Staged:
     """Walk one column chunk's pages: parse headers, decompress, walk run
     headers, and build the staged uint8/run-table buffers the device program
     consumes. Raises DeviceDecodeError on anything structurally off."""
     if plan.vkind == "s":
-        return _stage_string_column(chunk, cc, plan, num_rows, cap)
+        return _stage_string_column(chunk, cc, plan, num_rows, cap, laps)
     codec = _CODECS[cc.compression]
+    decompress, walk, count_valid, lap = _page_laps(codec, laps)
     obs_on = _obs._ACTIVE
     lv_runs: List[List[int]] = []
     lv_parts: List[bytes] = []
@@ -1071,7 +1097,8 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
         pos = 0
         end = len(chunk)
         while pos < end and rows_seen < num_rows:
-            hdr, dpos = _read_struct(chunk, pos)
+            with lap("scan.walk"):
+                hdr, dpos = _read_struct(chunk, pos)
             ptype, usize, csize = hdr[1], hdr[2], hdr[3]
             if usize < 0 or csize < 0 or dpos + csize > end:
                 raise ValueError("page body out of bounds")
@@ -1085,13 +1112,13 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
                 dph = hdr[7]
                 if dph[2] not in (_ENC_PLAIN, _ENC_PLAIN_DICT):
                     raise ValueError(f"dictionary encoding {dph[2]}")
-                data = _decompress(codec, body, usize)
+                data = decompress(body, usize)
                 if len(data) < dph[1] * plan.itemsize:
                     raise ValueError("dictionary page too short")
                 dict_bytes = data
                 continue
             if ptype == _PAGE_DATA_V1:
-                data = _decompress(codec, body, usize)
+                data = decompress(body, usize)
                 dph = hdr[5]
                 nv, enc, denc = dph[1], dph[2], dph[3]
                 p = 0
@@ -1102,11 +1129,11 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
                     p = 4 + dlen
                     if dlen < 0 or p > len(data):
                         raise ValueError("def levels out of bounds")
-                    lv_runs += _walk_runs(data, 4, p, 1, nv,
-                                          rows_seen, lv_bits)
+                    lv_runs += walk(data, 4, p, 1, nv,
+                                    rows_seen, lv_bits)
                     lv_parts.append(data[4:p])
                     lv_bits += dlen * 8
-                    nnn = _count_valid(data, 4, p, nv)
+                    nnn = count_valid(data, 4, p, nv)
                 else:
                     nnn = nv
                 rows_seen += nv
@@ -1119,8 +1146,8 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
                     bw = region[0]
                     if bw > 32:
                         raise ValueError(f"index bit width {bw}")
-                    val_runs += _walk_runs(region, 1, len(region), bw, nnn,
-                                           dense_seen, val_bits)
+                    val_runs += walk(region, 1, len(region), bw, nnn,
+                                     dense_seen, val_bits)
                     val_parts.append(region[1:])
                     val_bits += (len(region) - 1) * 8
                 elif enc == _ENC_PLAIN:
@@ -1142,8 +1169,8 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
                     (blen,) = struct.unpack_from("<i", region, 0)
                     if blen < 0 or 4 + blen > len(region):
                         raise ValueError("RLE boolean region out of bounds")
-                    val_runs += _walk_runs(region, 4, 4 + blen, 1, nnn,
-                                           dense_seen, val_bits)
+                    val_runs += walk(region, 4, 4 + blen, 1, nnn,
+                                     dense_seen, val_bits)
                     val_parts.append(region[4:4 + blen])
                     val_bits += blen * 8
                 else:
@@ -1161,12 +1188,12 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
                 levels = bytes(body[:dl_len])
                 vregion = body[dl_len:]
                 if codec is not None and v2.get(7, True):
-                    vregion = _decompress(codec, vregion, usize - dl_len)
+                    vregion = decompress(vregion, usize - dl_len)
                 else:
                     vregion = bytes(vregion)
                 if plan.nullable:
-                    lv_runs += _walk_runs(levels, 0, dl_len, 1, nv,
-                                          rows_seen, lv_bits)
+                    lv_runs += walk(levels, 0, dl_len, 1, nv,
+                                    rows_seen, lv_bits)
                     lv_parts.append(levels)
                     lv_bits += dl_len * 8
                 elif nnulls:
@@ -1181,8 +1208,8 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
                     bw = vregion[0]
                     if bw > 32:
                         raise ValueError(f"index bit width {bw}")
-                    val_runs += _walk_runs(vregion, 1, len(vregion), bw,
-                                           nnn, dense_seen, val_bits)
+                    val_runs += walk(vregion, 1, len(vregion), bw,
+                                     nnn, dense_seen, val_bits)
                     val_parts.append(vregion[1:])
                     val_bits += (len(vregion) - 1) * 8
                 elif enc == _ENC_PLAIN:
@@ -1204,8 +1231,8 @@ def _stage_column(chunk: bytes, cc, plan: _ColPlan, num_rows: int,
                     (blen,) = struct.unpack_from("<i", vregion, 0)
                     if blen < 0 or 4 + blen > len(vregion):
                         raise ValueError("RLE boolean region out of bounds")
-                    val_runs += _walk_runs(vregion, 4, 4 + blen, 1, nnn,
-                                           dense_seen, val_bits)
+                    val_runs += walk(vregion, 4, 4 + blen, 1, nnn,
+                                     dense_seen, val_bits)
                     val_parts.append(vregion[4:4 + blen])
                     val_bits += blen * 8
                 else:
@@ -1680,7 +1707,7 @@ class DeviceFileDecoder:
                         try:
                             chunk = self.reader.read(start, length)
                             staged.append(_stage_column(chunk, cc, plan,
-                                                        num_rows, cap))
+                                                        num_rows, cap, laps))
                             kept.append(plan)
                         except (DeviceDecodeError, OSError) as e:
                             # per-column demotion (bad bytes, failed range

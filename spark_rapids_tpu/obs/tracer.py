@@ -576,27 +576,34 @@ def phase_add(name: str, count: int, wall_ns: int, cpu_ns: Optional[int],
     per-batch loop reads the clock around its body and emits the sums once
     per query (see :class:`PhaseLaps`). The wall also counts as child wall
     of the phase open on this thread."""
+    _add_phase(name, cat, count, wall_ns, cpu_ns, child_wall_ns, wall_ns)
+
+
+def _add_phase(name, cat, count, wall_ns, cpu_ns, child_wall_ns,
+               parent_wall_ns) -> None:
     q = _current_query()
     if q is None:
         return
     q.add_phase(name, cat, count, wall_ns, cpu_ns, child_wall_ns)
     parent = _ptls.open
     if parent is not None:
-        parent.child_ns += wall_ns
+        parent.child_ns += parent_wall_ns
 
 
 class _Lap:
     """One phase's running sums inside a :class:`PhaseLaps`; re-entered
     once per batch (laps of one name do not nest). While open it is the
     thread's innermost phase, so what a nested ``phase_add`` reports (an
-    XLA compile inside a launch) becomes its child wall."""
+    XLA compile inside a launch) becomes its child wall, and so does a
+    lap opened inside it (`scan.walk` in `scan.page_walk`): that wall is
+    the enclosing lap's child at once, and not the flush-time phase's."""
 
-    __slots__ = ("cat", "count", "wall_ns", "cpu_ns", "child_ns", "_parent",
-                 "_t0", "_c0")
+    __slots__ = ("cat", "count", "wall_ns", "cpu_ns", "child_ns",
+                 "nested_ns", "_parent", "_t0", "_c0")
 
     def __init__(self, cat: str):
         self.cat = cat
-        self.count = self.wall_ns = self.child_ns = 0
+        self.count = self.wall_ns = self.child_ns = self.nested_ns = 0
         # per batch the CPU clock is read for a wait only (see _Phase)
         self.cpu_ns = 0 if cat == "wait" else None
 
@@ -608,11 +615,16 @@ class _Lap:
         self._t0 = time.perf_counter_ns()
 
     def __exit__(self, *exc) -> bool:
-        self.wall_ns += time.perf_counter_ns() - self._t0
+        wall = time.perf_counter_ns() - self._t0
+        self.wall_ns += wall
         if self.cpu_ns is not None:
             self.cpu_ns += time.thread_time_ns() - self._c0
         self.count += 1
-        _ptls.open = self._parent
+        parent = self._parent
+        if type(parent) is _Lap:
+            parent.child_ns += wall
+            self.nested_ns += wall
+        _ptls.open = parent
         return False
 
 
@@ -631,8 +643,12 @@ class PhaseLaps:
         self._laps: Dict[str, _Lap] = {}
 
     def lap(self, name: str, cat: str = "phase"):
+        """One batch's lap of phase `name`. With no bound context and
+        annotations off: the shared null context manager."""
         if _profiling._PROFILING_ACTIVE or thread_traced():
             return phase(name, cat)
+        if _current_query() is None:
+            return _NULL_SPAN
         lap = self._laps.get(name)
         if lap is None:
             lap = self._laps[name] = _Lap(cat)
@@ -641,8 +657,8 @@ class PhaseLaps:
     def flush(self) -> None:
         for name, lap in self._laps.items():
             if lap.count:
-                phase_add(name, lap.count, lap.wall_ns, lap.cpu_ns, lap.cat,
-                          lap.child_ns)
+                _add_phase(name, lap.cat, lap.count, lap.wall_ns, lap.cpu_ns,
+                           lap.child_ns, lap.wall_ns - lap.nested_ns)
         self._laps.clear()
 
 

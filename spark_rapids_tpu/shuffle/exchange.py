@@ -878,26 +878,33 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
         """Device partition-split of each input batch (shared by both
         shuffle modes; reference prepareBatchShuffleDependency:277)."""
         n = self._n_out
-        for batch in self.children[0].execute_partition(map_id, ctx):
-            # a deferred-compaction batch skips the empty check rather than
-            # force its count: the split plan handles empty inputs (all
-            # bounds equal) and its bounds readback IS the chain's one sync
-            if not batch.has_pending_rows and batch.num_rows == 0:
-                continue
-            with self.metrics["partitionTime"].timed():
-                if self.partitioning == "hash":
-                    # encode+split as ONE cached executable when the keys
-                    # trace (opjit.partition_split_plan)
-                    parts = hash_split_parts(batch, self.keys, n, ctx,
-                                             metrics=self.metrics)
-                elif self.partitioning in ("roundrobin", "coalesce"):
-                    pids = round_robin_partition_ids(batch, n, map_id)
-                    parts = split_by_partition(batch, pids, n)
-                elif self.partitioning == "single":
-                    parts = [batch] + [None] * (n - 1)
-                else:
-                    raise NotImplementedError(self.partitioning)
-            yield parts
+        # `exchange.split`: one lap a split, the child's pull outside it
+        laps = obs.PhaseLaps()
+        try:
+            for batch in self.children[0].execute_partition(map_id, ctx):
+                # a deferred-compaction batch skips the empty check rather
+                # than force its count: the split plan handles empty inputs
+                # (all bounds equal) and its bounds readback IS the chain's
+                # one sync
+                if not batch.has_pending_rows and batch.num_rows == 0:
+                    continue
+                with self.metrics["partitionTime"].timed(), \
+                        laps.lap("exchange.split"):
+                    if self.partitioning == "hash":
+                        # encode+split as ONE cached executable when the
+                        # keys trace (opjit.partition_split_plan)
+                        parts = hash_split_parts(batch, self.keys, n, ctx,
+                                                 metrics=self.metrics)
+                    elif self.partitioning in ("roundrobin", "coalesce"):
+                        pids = round_robin_partition_ids(batch, n, map_id)
+                        parts = split_by_partition(batch, pids, n)
+                    elif self.partitioning == "single":
+                        parts = [batch] + [None] * (n - 1)
+                    else:
+                        raise NotImplementedError(self.partitioning)
+                yield parts
+        finally:
+            laps.flush()
 
     def _partition_map_task(self, map_id: int, ctx: TaskContext) -> List:
         """MULTITHREADED mode map task: split on device, serialize to host."""
@@ -1046,11 +1053,14 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
                     if sub is not None and sub.num_rows:
                         acc[i][p].append(sub.to_arrow())
 
+        laps = obs.PhaseLaps()    # `exchange.split`: one lap a split launch
+
         def flush() -> None:
             if not pending:
                 return
             lanes, pending[:] = list(pending), []
-            with self.metrics["partitionTime"].timed():
+            with self.metrics["partitionTime"].timed(), \
+                    laps.lap("exchange.split"):
                 parts_per_lane = None
                 if len(lanes) > 1:
                     # N lanes' encode+split in ONE launch, ONE bounds
@@ -1066,25 +1076,29 @@ class TpuShuffleExchangeExec(_ExchangeBase, TpuExec):
             for (i, _), parts in zip(lanes, parts_per_lane):
                 sink(i, parts)
 
-        for i, batch in self.children[0].execute_partitions(list(ids),
-                                                            ctx_of):
-            if not batch.has_pending_rows and batch.num_rows == 0:
-                continue
-            if self.partitioning == "hash":
-                pending.append((i, batch))
-                if len(pending) >= group:
-                    flush()
-                continue
-            with self.metrics["partitionTime"].timed():
-                if self.partitioning in ("roundrobin", "coalesce"):
-                    pids = round_robin_partition_ids(batch, n, i)
-                    parts = split_by_partition(batch, pids, n)
-                elif self.partitioning == "single":
-                    parts = [batch] + [None] * (n - 1)
-                else:
-                    raise NotImplementedError(self.partitioning)
-            sink(i, parts)
-        flush()
+        try:
+            for i, batch in self.children[0].execute_partitions(list(ids),
+                                                                ctx_of):
+                if not batch.has_pending_rows and batch.num_rows == 0:
+                    continue
+                if self.partitioning == "hash":
+                    pending.append((i, batch))
+                    if len(pending) >= group:
+                        flush()
+                    continue
+                with self.metrics["partitionTime"].timed(), \
+                        laps.lap("exchange.split"):
+                    if self.partitioning in ("roundrobin", "coalesce"):
+                        pids = round_robin_partition_ids(batch, n, i)
+                        parts = split_by_partition(batch, pids, n)
+                    elif self.partitioning == "single":
+                        parts = [batch] + [None] * (n - 1)
+                    else:
+                        raise NotImplementedError(self.partitioning)
+                sink(i, parts)
+            flush()
+        finally:
+            laps.flush()
         if ici:
             from ..columnar.batch import concat_batches
             for i in ids:

@@ -121,6 +121,32 @@ def test_phase_arithmetic(inner):
     assert t["outer"]["wall_ns"] - t["outer"]["child_wall_ns"] >= 0
 
 
+@pytest.mark.parametrize("annotated", [False, True])
+def test_a_lap_inside_a_lap_is_its_child_not_the_flush_phases(annotated):
+    """`scan.walk` inside `scan.page_walk` (ISSUE 35): the inner lap's wall
+    is the outer lap's child at once; the phase open at the flush gets the
+    outer lap's wall alone, so every wall is some phase's child once."""
+    profiling.set_trace_annotations(annotated)
+    q = QueryContext("t", session_id="s")
+    laps = obs.PhaseLaps()
+    with bind(q):
+        with obs.phase("outer"):
+            for _ in range(3):
+                with laps.lap("walk"):
+                    with laps.lap("step"):
+                        time.sleep(0.002)
+                    with laps.lap("other"):
+                        time.sleep(0.001)
+                    time.sleep(0.001)
+            laps.flush()
+    t = q.phase_table()
+    assert t["walk"]["count"] == 3 and t["step"]["count"] == 3
+    assert t["walk"]["child_wall_ns"] == t["step"]["wall_ns"] + t["other"]["wall_ns"]
+    assert t["outer"]["child_wall_ns"] == t["walk"]["wall_ns"]
+    assert sum(c["wall_ns"] - c["child_wall_ns"] for c in t.values()) \
+        == t["outer"]["wall_ns"]
+
+
 # ---------------------------------------------------------------------------
 # (e) off path: no bound context, annotations off
 # ---------------------------------------------------------------------------
@@ -128,6 +154,11 @@ def test_phase_arithmetic(inner):
 
 def test_off_path_is_the_shared_null_span():
     assert obs.phase("anything") is obs_tracer._NULL_SPAN
+    laps = obs.PhaseLaps()
+    for name in ("exchange.split", "scan.decompress", "scan.walk"):
+        assert laps.lap(name) is obs_tracer._NULL_SPAN
+    with bind(QueryContext("t", session_id="s")):
+        assert laps.lap("scan.walk") is not obs_tracer._NULL_SPAN
     assert obs.phase("other", cat="wait", k=1) is obs_tracer._NULL_SPAN
     obs.phase_add("anything", 1, 10, 10)        # nothing bound: dropped
     with obs.phase("anything") as ph:
@@ -326,6 +357,40 @@ def test_scan_upload_time_nests_in_decode_time(tmp_path):
     # the scan's phases are children of the stage's pass 1 here
     assert ph["stage.collect"]["child_wall_ns"] >= sum(
         ph[n_]["wall_ns"] for n_ in groups)
+
+
+@pytest.mark.parametrize("annotated", [False, True])
+def test_page_walk_steps_nest_in_page_walk(tmp_path, annotated):
+    """`scan.decompress` (each page's decompression) and `scan.walk` (page
+    headers, level and index run walks, the index histogram) are laps inside
+    `scan.page_walk` (ISSUE 35): their walls add up to no more than it, and
+    on the query's one thread every wall is some phase's child once."""
+    n = 6000
+    path = str(tmp_path / "t.parquet")
+    pq.write_table(pa.table({
+        "k": pa.array([i % 3 for i in range(n)], type=pa.int32()),
+        "x": pa.array([float(i) for i in range(n)]),
+        "y": pa.array([None if i % 7 == 0 else float(i) for i in range(n)]),
+        "s": pa.array([("ab", "cde", "f")[i % 3] for i in range(n)]),
+    }), path, row_group_size=2048, compression="snappy", data_page_size=4096)
+    s = TpuSession({})
+    df = (s.read.parquet(path).filter(F.col("x") >= 0.0).groupBy("k", "s")
+          .agg(F.sum("x").alias("sx"), F.count("y").alias("cy")))
+    df.collect()                                    # compiles
+    profiling.set_trace_annotations(annotated)
+    rows = df.collect()
+    profiling.set_trace_annotations(False)
+    assert sum(r["cy"] for r in rows) == n - len(range(0, n, 7))
+    ph = s.last_query_phases()["phases"]
+    walk, dec, sub = (ph[k] for k in ("scan.page_walk", "scan.decompress",
+                                      "scan.walk"))
+    assert walk["count"] == 3
+    # a dictionary page and a data page or more: 4 columns x 3 row groups
+    assert dec["count"] >= 4 * 3 * 2 and sub["count"] > dec["count"]
+    assert walk["child_wall_ns"] == dec["wall_ns"] + sub["wall_ns"] <= walk["wall_ns"]
+    inside = [k for k in ph if k != "sched.admit_wait"]
+    assert sum(ph[k]["wall_ns"] - ph[k]["child_wall_ns"] for k in inside) \
+        == ph["query"]["wall_ns"]
 
 
 # ---------------------------------------------------------------------------

@@ -166,6 +166,34 @@ def test_q3_phases_cover_the_query_and_count_the_joins():
         int(building.sum()) + int(open_order.sum())
 
 
+@pytest.mark.parametrize("mode", ["ICI", "MULTITHREADED"])
+@pytest.mark.parametrize("group", ["1", "8"])
+def test_exchange_split_nests_in_exchange_map_once_a_split_launch(mode, group):
+    """`exchange.split` (ISSUE 35) is the hash split inside `exchange.map`:
+    one lap a split launch, the per-batch split of a map task
+    (`partitionBatch` 1, on the exchange's pool threads) or a partition
+    group's grouped one, in either shuffle mode; the child's pull lies
+    outside it. Its count is the launches of the split plan."""
+    from spark_rapids_tpu.execs import opjit
+    rows = 1 << 12
+    cols = _columns(7, rows)
+    s = _session({**SHUFFLED, "spark.rapids.shuffle.mode": mode,
+                  "spark.rapids.tpu.dispatch.partitionBatch": group})
+    df = _q3(s, cols, rows, parts=3)
+    assert "TpuShuffleExchange[hash" in engine.plan_text(df)
+    df.collect()                                    # compiles
+    before = opjit.cache_stats()["calls_by_kind"]
+    _same(df.collect(), _reference(cols, rows))
+    after = opjit.cache_stats()["calls_by_kind"]
+    launched = sum(after.get(k, 0) - before.get(k, 0)
+                   for k in ("exchsplit", "exchsplitg"))
+    ph = s.last_query_phases()["phases"]
+    split, mapped = ph["exchange.split"], ph["exchange.map"]
+    assert split["count"] == launched > 0, (split, launched)
+    assert 0 < split["wall_ns"] <= mapped["child_wall_ns"] <= mapped["wall_ns"]
+    assert split["child_wall_ns"] <= split["wall_ns"]
+
+
 def test_q3_hand_off_pulls_the_lower_joins_build_side_once(monkeypatch):
     """At 2^12 rows over 3 partitions the stage's child is the top join's
     segment and its fallback the aggregate over that same segment
