@@ -51,8 +51,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..columnar.batch import TpuColumnarBatch, _repad, compact
-from ..columnar.vector import TpuColumnVector, bucket_capacity, row_mask
+from ..columnar.batch import TpuColumnarBatch
+from ..columnar.vector import TpuColumnVector
 from ..expressions.aggregates import (AggregateFunction, Average, Count, Max,
                                       Min, Sum)
 from ..expressions.base import (Alias, AttributeReference, Expression,
@@ -306,6 +306,13 @@ class _KeyDomain:
         return int(self.hi - self.lo) + 2
 
 
+def _memo(col: TpuColumnVector, name: str, value) -> None:
+    try:
+        object.__setattr__(col, name, value)
+    except Exception:
+        pass
+
+
 def _int_stats(col: TpuColumnVector) -> Tuple[Optional[int], Optional[int]]:
     """min/max of valid rows (one sync; memoized on the column object)."""
     memo = getattr(col, "_gb_range", None)
@@ -318,36 +325,35 @@ def _int_stats(col: TpuColumnVector) -> Tuple[Optional[int], Optional[int]]:
     hi = jnp.max(jnp.where(mask, data, -big - 1))
     n = int(jnp.sum(mask))
     stats = (None, None) if n == 0 else (int(lo), int(hi))
-    try:
-        object.__setattr__(col, "_gb_range", stats)
-    except Exception:
-        pass
+    _memo(col, "_gb_range", stats)
     return stats
 
 
-def _string_codes(col: TpuColumnVector, domain: _KeyDomain) -> jnp.ndarray:
-    """Global dictionary codes for a string key column (device int32; nulls
-    and padding carry -1). The local encode is memoized per column object;
-    the local→global remap is a cheap host lookup over the small dict."""
+def _local_dictionary(col: TpuColumnVector) -> Tuple[List, np.ndarray]:
+    """A string key column's own dictionary and its host int32 codes into
+    it (nulls -1), one per logical row: one fetch and encode, memoized per
+    column object."""
     memo = getattr(col, "_gb_dict", None)
     if memo is None:
         import pyarrow as pa
         import pyarrow.compute as pc
-        arr = col.to_arrow()
-        enc = pc.dictionary_encode(arr)
+        enc = pc.dictionary_encode(col.to_arrow())
         if isinstance(enc, pa.ChunkedArray):
             enc = enc.combine_chunks()
-        values = enc.dictionary.to_pylist()
-        codes = np.asarray(enc.indices.fill_null(-1)
-                           .to_numpy(zero_copy_only=False)).astype(np.int32)
-        buf = np.full(col.capacity, -1, np.int32)
-        buf[: len(codes)] = codes
-        memo = (values, jnp.asarray(buf))
-        try:
-            object.__setattr__(col, "_gb_dict", memo)
-        except Exception:
-            pass
-    values, local_codes = memo
+        memo = (enc.dictionary.to_pylist(),
+                np.asarray(enc.indices.fill_null(-1)
+                           .to_numpy(zero_copy_only=False)).astype(np.int32))
+        _memo(col, "_gb_dict", memo)
+    return memo
+
+
+def _grow_dictionary(col: TpuColumnVector, domain: _KeyDomain) -> np.ndarray:
+    """Add the column's values to the global dictionary; returns the
+    local→global remap (its last slot, -1, is what a null code reads). Codes
+    are handed out in order of first sight, so a column's remap is the same
+    in pass 1 and pass 2, and in every query that pulls the same batches in
+    the same order."""
+    values, _ = _local_dictionary(col)
     remap = np.empty(len(values) + 1, np.int32)
     remap[-1] = -1
     for i, v in enumerate(values):
@@ -355,9 +361,26 @@ def _string_codes(col: TpuColumnVector, domain: _KeyDomain) -> jnp.ndarray:
             domain.value_code[v] = len(domain.values)
             domain.values.append(v)
         remap[i] = domain.value_code[v]
-    if np.array_equal(remap[:-1], np.arange(len(values), dtype=np.int32)):
-        return local_codes  # local == global: no remap dispatch
-    return jnp.take(jnp.asarray(remap), local_codes)
+    return remap
+
+
+def _string_codes(col: TpuColumnVector,
+                  domain: _KeyDomain) -> Tuple[jax.Array, bool]:
+    """Global dictionary codes for a string key column (device int32; nulls
+    and padding carry -1), and whether they had to be made: they are
+    memoized on the column object keyed by the remap, so a cached column
+    pays once, and a hit costs no device work."""
+    remap = _grow_dictionary(col, domain)
+    key = remap.tobytes()
+    memo = getattr(col, "_gb_codes", None)
+    if memo is not None and memo[0] == key:
+        return memo[1], False
+    _, local = _local_dictionary(col)
+    buf = np.full(col.capacity, -1, np.int32)
+    buf[: len(local)] = remap[local]
+    codes = jnp.asarray(buf)
+    _memo(col, "_gb_codes", (key, codes))
+    return codes, True
 
 
 # ---------------------------------------------------------------------------
@@ -372,6 +395,29 @@ _STAGE_FN_CACHE: Dict[Tuple, "jax.stages.Wrapped"] = {}
 _STAGE_FN_LOCK = threading.Lock()
 
 
+#: an all-valid mask a (capacity, chip): the validity operand of a nullable
+#: column that has no validity in this batch. Made once, never per batch
+_ALL_VALID: Dict[Tuple, jax.Array] = {}
+
+
+def _validity_arg(col: TpuColumnVector, nullable: bool, cap: int, chip):
+    """A column's validity operand. Which slots are None follows the
+    source's schema, not a batch's data, so batches with and without nulls
+    run one program: a column without validity passes None (the program
+    reads its row mask there) unless the schema lets it hold nulls, where it
+    passes the shared all-valid mask (the program masks the padding)."""
+    if col.validity is not None:
+        return col.validity
+    if not nullable:
+        return None
+    with _STAGE_FN_LOCK:
+        v = _ALL_VALID.get((cap, chip))
+        if v is None:
+            v = _ALL_VALID[(cap, chip)] = jax.device_put(
+                np.ones(cap, np.bool_), chip)
+    return v
+
+
 def _is_fp(dtype: DataType) -> bool:
     return isinstance(dtype, (FloatType, DoubleType))
 
@@ -379,7 +425,9 @@ def _is_fp(dtype: DataType) -> bool:
 def _build_stage_fn(spec: _StageSpec, cap: int,
                     domains: List["_KeyDomain"], eval_ctx):
     """Build + jit the stage program (cached process-wide). Returns
-    fn(rowmask, *flat) -> (oob, rowcount, *carry)."""
+    fn(num_rows, *flat) -> (oob, rowcount, *carry): the row count is a
+    traced operand, and `flat` holds each needed column's data and validity,
+    a validity None where the column has none (`_validity_arg`)."""
     from .opjit import _conf_fp, _trace_ctx
     domain_sizes = tuple(d.size for d in domains)
     domain_los = tuple(getattr(d, "lo", None) for d in domains)
@@ -416,7 +464,8 @@ def _build_stage_fn(spec: _StageSpec, cap: int,
     sizes = domain_sizes
     los = domain_los
 
-    def stage(rowmask, *flat):
+    def stage(num_rows, *flat):
+        rowmask = jnp.arange(cap) < num_rows
         cols: List[Optional[TpuColumnVector]] = [None] * len(source_attrs)
         key_cols: List[Optional[TpuColumnVector]] = [None] * len(domains)
         for j, o in enumerate(needed):
@@ -426,8 +475,9 @@ def _build_stage_fn(spec: _StageSpec, cap: int,
                 key_cols[key_set[o]] = TpuColumnVector(
                     attr.dtype, data, valid, cap)
             if not isinstance(attr.dtype, StringType):
-                cols[o] = TpuColumnVector(attr.dtype, data,
-                                          valid & rowmask, cap)
+                cols[o] = TpuColumnVector(
+                    attr.dtype, data,
+                    rowmask if valid is None else valid & rowmask, cap)
         for o in range(len(source_attrs)):
             if cols[o] is None:
                 cols[o] = TpuColumnVector(
@@ -787,11 +837,13 @@ class TpuCompiledAggStageExec(TpuExec):
 
     def additional_metrics(self):
         return {"stageTime": "MODERATE", "numGroups": "DEBUG",
-                "fallbackHandoffs": "DEBUG", "fallbackReruns": "DEBUG"}
+                "fallbackHandoffs": "DEBUG", "fallbackReruns": "DEBUG",
+                "keyRemaps": "DEBUG"}
 
     def query_counters(self):
         return [("stage.fallback_handoffs", self.metrics["fallbackHandoffs"]),
-                ("stage.fallback_reruns", self.metrics["fallbackReruns"])]
+                ("stage.fallback_reruns", self.metrics["fallbackReruns"]),
+                ("stage.key_remaps", self.metrics["keyRemaps"])]
 
     def _fallback_source(self) -> TpuStageSourceExec:
         # found, not kept: the rewriting passes copy the nodes they change,
@@ -890,10 +942,11 @@ class TpuCompiledAggStageExec(TpuExec):
                         _close_all(empty)
                     if not fits:
                         raise _StageFallback()
-            # pass 2: one fused program per batch shape. Dispatches are
-            # async; the ONLY sync is a single device_get of every carry +
-            # the oob flags at the end (high-latency links pay one round
-            # trip per query, like the hand-fused kernel)
+            # pass 2: one fused program per batch shape, and one launch per
+            # batch. Dispatches are async; the ONLY sync is a single
+            # device_get of every carry + the oob flags at the end
+            # (high-latency links pay one round trip per query, like the
+            # hand-fused kernel)
             with self.metrics["stageTime"].timed():
                 laps = _obs.PhaseLaps()  # per batch: clock reads only
                 try:
@@ -935,7 +988,7 @@ class TpuCompiledAggStageExec(TpuExec):
             d = domains[k]
             col = b.columns[o]
             if isinstance(d.dtype, StringType):
-                _string_codes(col, d)  # grows the global dictionary
+                _grow_dictionary(col, d)
             elif not isinstance(d.dtype, BooleanType):
                 if col.offsets is not None or col.host_data is not None \
                         or col.children is not None:
@@ -951,24 +1004,36 @@ class TpuCompiledAggStageExec(TpuExec):
 
     def _run_batch(self, b: TpuColumnarBatch, domains: List[_KeyDomain],
                    ctx: TaskContext):
+        """One batch of pass 2: ONE device program, whose operands are the
+        batch's own buffers and its row count (a pending count stays on the
+        device)."""
+        from ..parallel.mesh import current_chip
         spec = self.spec
         cap = b.capacity
+        chip = current_chip()
         key_ord = {o: k for k, o in enumerate(spec.key_source_ordinals)}
+        attrs = spec.source.output
         flat = []
         for o in spec.needed_source_ordinals:
             col = b.columns[o]
             if o in key_ord and isinstance(domains[key_ord[o]].dtype,
                                            StringType):
-                codes = _string_codes(col, domains[key_ord[o]])
-                flat.append(codes)
-                flat.append(codes >= 0)
+                # a null code is -1, which the program reads as null
+                codes, made = _string_codes(col, domains[key_ord[o]])
+                if made:
+                    self.metrics["keyRemaps"].add(1)
+                flat.extend((codes, None))
             else:
                 if col.offsets is not None or col.host_data is not None \
                         or col.children is not None:
                     raise _StageFallback()
-                flat.append(col.data)
-                flat.append(col.validity if col.validity is not None
-                            else row_mask(b.num_rows, cap))
+                flat.extend((col.data,
+                             _validity_arg(col, attrs[o].nullable, cap, chip)))
+        rows = b.rows_arg
+        if isinstance(rows, (int, np.integer)):
+            # the dtype of a pending count (jnp.sum under x64): one program
+            # for both
+            rows = np.int64(rows)
         fn = _build_stage_fn(spec, cap, domains, ctx.eval_ctx)
         # compiled-stage launch = one device dispatch: chaos site + bounded
         # transient retry (the stage fn is pure over its device inputs)
@@ -980,7 +1045,7 @@ class TpuCompiledAggStageExec(TpuExec):
 
         def dispatch():
             inject("device.dispatch", detail="compiled_stage")
-            return fn(row_mask(b.num_rows, cap), *flat)
+            return fn(rows, *flat)
 
         return with_device_retry(dispatch, ctx.conf)
 

@@ -391,3 +391,209 @@ def test_stage_hands_its_input_to_the_fallback(case, make, conf, parts,
         assert len(grew) == 3 + 2, grew     # partition 0's three, then (1, 1)
     elif case == "map_tasks_on_pool_threads":
         assert len(grew) == 2 + 1, grew     # partition 0's two, then (1, 0)
+
+
+# ---------------------------------------------------------------------------
+# one device program a batch in pass 2: the row mask, the all-valid masks
+# and a string key's null test are built inside the stage program, and a
+# string key's global codes are memoized on its column
+# ---------------------------------------------------------------------------
+
+_EXECUTE = "PjRtCpuExecutable::Execute"   # one event a program launched
+
+
+def _launches_per_batch(run, trace_dir):
+    """`run()` under a CPU profiler trace with the engine's annotations on:
+    the device programs launched inside each `srt.stage.launch` span (a
+    batch of pass 2), and what `run()` returned."""
+    import jax.profiler as jp
+
+    from chipbench import trace as tm
+    from spark_rapids_tpu import profiling
+    opts = jp.ProfileOptions()
+    opts.host_tracer_level = 2
+    opts.python_tracer_level = 0
+    jp.start_trace(str(trace_dir), profiler_options=opts)
+    profiling.set_trace_annotations(True)
+    try:
+        out = run()
+    finally:
+        profiling.set_trace_annotations(False)
+        jp.stop_trace()
+    per_batch = []
+    for ln in tm.load(tm.find_xplane(str(trace_dir))):
+        names = np.asarray(ln.names, dtype=object)
+        launches = ln.start[names == _EXECUTE]
+        for lo, hi in zip(ln.start[names == "srt.stage.launch"],
+                          ln.end[names == "srt.stage.launch"]):
+            per_batch.append(int(((launches >= lo) & (launches < hi)).sum()))
+    return per_batch, out
+
+
+def _cached(s, t, parts, non_nullable=False):
+    """`t` cached in device memory, `parts` partitions; with `non_nullable`
+    the relation's schema says no column holds a null."""
+    from spark_rapids_tpu.expressions.base import AttributeReference
+    from spark_rapids_tpu.io.cache import DeviceCachedRelation
+    from spark_rapids_tpu.session import DataFrame
+    df = s.createDataFrame(t, num_partitions=parts).device_cache()
+    if non_nullable:
+        rel = df._plan
+        df = DataFrame(DeviceCachedRelation(
+            rel.batches(), [AttributeReference(a.name, a.dtype, False)
+                            for a in rel.output]), s)
+    return df
+
+
+def _measures(rng, n, nulls_every=0):
+    def col(values, dtype):
+        if not nulls_every:
+            return pa.array(values, dtype)
+        return pa.array([None if i % nulls_every == 0 else v
+                         for i, v in enumerate(values.tolist())], dtype)
+    return {"v": col(rng.normal(size=n) * 100, pa.float64()),
+            "w": col(rng.integers(0, 50, n), pa.int64())}
+
+
+def _int_keyed(n, nulls_every=0):
+    rng = np.random.default_rng(41)
+    keys = rng.integers(0, 6, n)
+    k = pa.array([None if nulls_every and i % (nulls_every + 1) == 0
+                  else int(x) for i, x in enumerate(keys)], pa.int32())
+    return pa.table({"k": k, **_measures(rng, n, nulls_every)})
+
+
+def _string_keyed(n):
+    """Two string keys; each batch of 500 rows opens with the values in
+    another order, so the batches' own dictionaries differ in order."""
+    rng = np.random.default_rng(42)
+    flags, orders = ["A", "N", "R"], [(0, 1, 2), (2, 0, 1), (1, 2, 0)]
+    f, st = [], []
+    for i in range(n):
+        order = orders[(i // 500) % 3]
+        j = i % 500
+        f.append(flags[order[j]] if j < 3 else flags[int(rng.integers(3))])
+        st.append(None if i % 17 == 5 else ("O" if (i + j) % 3 else "F"))
+    return pa.table({"flag": pa.array(f), "status": pa.array(st),
+                     **_measures(rng, n)})
+
+
+def _agg(df, keys):
+    return (df.filter(F.col("w") < 45)
+            .withColumn("vw", F.col("v") * (1 + F.col("w")))
+            .groupBy(*keys)
+            .agg(F.sum(F.col("v")).alias("sv"), F.avg(F.col("vw")).alias("a"),
+                 F.min(F.col("w")).alias("mn"), F.max(F.col("v")).alias("mx"),
+                 F.count(F.col("w")).alias("c"), F.count(F.lit(1)).alias("n")))
+
+
+def _deferred_source(monkeypatch, served):
+    """The cache serves each batch with its row count still on the device,
+    as a compaction below the stage leaves it; `served` notes each one."""
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.columnar.batch import compact
+    from spark_rapids_tpu.execs.transitions import TpuDeviceScanExec
+    real = TpuDeviceScanExec.internal_do_execute_columnar
+
+    def deferred(self, idx, ctx):
+        for b in real(self, idx, ctx):
+            out = compact(b, jnp.ones((b.capacity,), jnp.bool_), deferred=True)
+            served.append(out.has_pending_rows)
+            yield out
+    monkeypatch.setattr(TpuDeviceScanExec, "internal_do_execute_columnar",
+                        deferred)
+
+
+#: input kind -> (table, partitions, batchSizeRows, keys, schema non-nullable)
+_ONE_LAUNCH_CASES = {
+    "columns_without_validity": (lambda: _int_keyed(3000), 3, None, ["k"],
+                                 True),
+    "nullable_columns_with_nulls": (lambda: _int_keyed(3000, 7), 3, None,
+                                    ["k"], False),
+    # two partitions of 1600 rows: batches of 1000 and 600, one capacity
+    "last_batch_shorter_than_its_capacity": (lambda: _int_keyed(3200), 2,
+                                             1000, ["k"], False),
+    "string_keys_in_different_orders": (lambda: _string_keyed(3000), 2, 500,
+                                        ["flag", "status"], False),
+    "deferred_row_counts": (lambda: _int_keyed(3000), 3, None, ["k"], False),
+    "global_aggregate_no_keys": (lambda: _int_keyed(3000, 11), 3, None, [],
+                                 False),
+}
+
+
+@pytest.mark.parametrize("kind", list(_ONE_LAUNCH_CASES))
+def test_stage_launches_one_program_a_batch(kind, tmp_path, monkeypatch):
+    from spark_rapids_tpu.execs import compiled as C
+    make, parts, batch_rows, keys, non_nullable = _ONE_LAUNCH_CASES[kind]
+    t = make()
+    conf = {} if batch_rows is None else \
+        {"spark.rapids.sql.batchSizeRows": str(batch_rows)}
+    served = []
+    if kind == "deferred_row_counts":
+        _deferred_source(monkeypatch, served)
+    launched = []
+    real_run = C.TpuCompiledAggStageExec._run_batch
+
+    def run_batch(self, b, domains, ctx):
+        launched.append(b)
+        return real_run(self, b, domains, ctx)
+    monkeypatch.setattr(C.TpuCompiledAggStageExec, "_run_batch", run_batch)
+
+    s = TpuSession(conf)
+    df = _agg(_cached(s, t, parts, non_nullable), keys)
+    assert _uses_stage(df)
+    first = df.collect()
+    remaps = s.last_query_phases()["counters"]["stage.key_remaps"]
+    batches = len(launched)
+    per_batch, second = _launches_per_batch(df.collect, tmp_path)
+    counters = s.last_query_phases()["counters"]
+    assert per_batch == [1] * batches, per_batch
+    assert counters["stage.key_remaps"] == 0
+    assert counters["stage.fallback_handoffs"] == 0
+    if kind == "string_keys_in_different_orders":
+        assert batches == 6 and remaps == 2 * batches
+    elif kind == "last_batch_shorter_than_its_capacity":
+        assert batches == 4
+    elif kind == "deferred_row_counts":
+        assert served == [True] * (2 * batches)
+    want = _agg(_cpu().createDataFrame(t), keys).collect()
+    for got in (first, second):
+        assert sorted(map(repr, _rounded(got))) == \
+            sorted(map(repr, _rounded(want)))
+
+
+def _rounded(rows):
+    return [{k: round(v, 6) if isinstance(v, float) else v
+             for k, v in r.items()} for r in rows]
+
+
+def test_stage_row_count_and_missing_validity_compile_nothing_new():
+    """Batches of one capacity and different row counts, nulls in some of
+    them and none in the others, and the query run a second time: the stage
+    is one program, compiled once."""
+    from spark_rapids_tpu.execs import compiled as C
+    rng = np.random.default_rng(43)
+    n = 3200
+    v = [None if i < 1000 and i % 5 == 0 else float(x)
+         for i, x in enumerate(rng.normal(size=n))]
+    t = pa.table({"k": pa.array(rng.integers(0, 4, n), pa.int32()),
+                  "v": pa.array(v, pa.float64())})
+    s = TpuSession({"spark.rapids.sql.batchSizeRows": "1000"})
+    df = (_cached(s, t, 2).groupBy("k")
+          .agg(F.sum(F.col("v")).alias("sv"), F.count(F.col("v")).alias("c")))
+    caps = {b.capacity for b in s.createDataFrame(t, num_partitions=2)
+            .to_device_batches()}
+    assert caps == {1024}
+    before = set(C._STAGE_FN_CACHE)
+    first = df.collect()
+    new = [C._STAGE_FN_CACHE[k] for k in set(C._STAGE_FN_CACHE) - before]
+    assert len(new) == 1 and new[0]._cache_size() == 1
+    second = df.collect()
+    assert len(set(C._STAGE_FN_CACHE) - before) == 1
+    assert new[0]._cache_size() == 1
+    want = (_cpu().createDataFrame(t).groupBy("k")
+            .agg(F.sum(F.col("v")).alias("sv"), F.count(F.col("v")).alias("c"))
+            .collect())
+    assert sorted(map(repr, _rounded(first))) == \
+        sorted(map(repr, _rounded(want))) == sorted(map(repr, _rounded(second)))

@@ -375,7 +375,12 @@ def test_q1_over_one_table_holds_no_join_phase():
     s = TpuSession(dict(conf["session_conf"]))
     df = s.createDataFrame(table.to_arrow(
         table.generate(7, table.cached, 0, table.rows))).device_cache()
-    assert len(q1.build(F, {"lineitem": df}).collect()) >= 1
+    q = q1.build(F, {"lineitem": df})
+    assert len(q.collect()) >= 1
+    # the first query over the cache makes its two string keys' global codes
+    # (one batch); the next finds them on the columns
+    assert s.last_query_phases()["counters"]["stage.key_remaps"] == 2
+    assert len(q.collect()) >= 1
     summ = s.last_query_phases()
     assert "stage.launch" in summ["phases"]
     assert not set(JOIN_PHASES) & set(summ["phases"]), summ["phases"]
