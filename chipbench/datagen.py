@@ -31,11 +31,27 @@ Column specs, as JSON lists (`[kind, arguments...]`):
   ["after", "fk column", "table.column", lo, hi]
                         the DATE of the row that this table's fk column points
                         at, plus lo..hi days (dbgen ships 1..121 days after the order)
+  ["text", "<prefix>%0<N>d<suffix>", "column", {"plus": k}]
+                        the formatted names dbgen writes: prefix, the value of
+                        an integer column of the same row (seq, key, int or fk)
+                        plus k (default 0), zero-padded to N digits, suffix;
+                        CHAR(len(prefix) + N + len(suffix)). TPC-H's c_name is
+                        ["text", "Customer#%09d", "c_custkey", {"plus": 1}]: a
+                        seq key is dense from 0 where dbgen counts from 1. It
+                        draws nothing of its own, so it is the same whichever
+                        other columns are drawn; a value that prints wider than
+                        N digits is an error when the tables are made.
+
+The other kinds key their draws by the column's place in its table's column
+order. A text column takes a place too, so one put anywhere but last into a
+table that a cell already measures shifts the draws of the columns after it;
+a new configuration may place it where it likes.
 """
 
 from __future__ import annotations
 
 import os
+import re
 import threading
 import zlib
 from concurrent.futures import ThreadPoolExecutor
@@ -71,6 +87,8 @@ LINEITEM = {
 #: the engine's layout (its strings also carry 4-byte offsets)
 DEVICE_WIDTH = {"key": 4, "suppkey": 8, "int": 4, "double": 8, "char1": 1,
                 "date": 4, "seq": 8, "fk": 8, "after": 4}
+#: a text column's format: one zero-padded decimal field between literal text
+TEXT_FORMAT = re.compile(r"^([^%]*)%0([1-9][0-9]*)d([^%]*)$")
 
 
 class Table:
@@ -96,6 +114,8 @@ class Table:
         spec = self.columns[column]
         if spec[0] == "choice":
             return max(len(v) for v in spec[1])
+        if spec[0] == "text":
+            return len(spec[1]) + spec[2] + len(spec[3])
         return DEVICE_WIDTH[spec[0]]
 
     def _column(self, seed: int, index: int, name: str, n: int):
@@ -135,6 +155,17 @@ class Table:
         if kind == "choice":
             # kept as the index into its value list
             return rng.integers(0, len(spec[1]), n).astype(np.int8)
+        if kind == "text":
+            # spec: ("text", prefix, digits, suffix, source column, plus)
+            head, digits, tail = spec[1], spec[2], spec[3]
+            v = self._column(seed, index, spec[4], n).astype(np.int64) + spec[5]
+            out = np.empty((n, len(head) + digits + len(tail)), np.uint8)
+            out[:, :len(head)] = np.frombuffer(head, np.uint8)
+            for at in range(len(head) + digits - 1, len(head) - 1, -1):
+                out[:, at] = v % 10 + ord("0")
+                v //= 10
+            out[:, len(head) + digits:] = np.frombuffer(tail, np.uint8)
+            return out.view(f"S{out.shape[1]}")[:, 0]
         raise ValueError(f"table {self.name}: column {name}: unknown kind {kind!r}")
 
     def whole(self, seed: int, column: str):
@@ -168,14 +199,15 @@ class Table:
 
     def kept(self, cols: dict, keep) -> dict:
         """What the reference is handed of generated columns: the `keep`
-        columns, a choice as its CHAR(n) values."""
+        columns, a choice as its CHAR(n) values (a text column is drawn as
+        its CHAR(n) bytes)."""
         return {k: (np.array(self.columns[k][1], "S")[v]
                     if self.columns[k][0] == "choice" else v)
                 for k, v in cols.items() if k in keep}
 
     def to_arrow(self, cols: dict) -> pa.Table:
         """The generated columns as the Arrow table the program is handed:
-        DOUBLE, INT, BIGINT, DATE32, CHAR(1) as string, choices as strings,
+        DOUBLE, INT, BIGINT, DATE32, CHAR(1), choices and text as strings,
         every column REQUIRED as the TPC-H schema declares it."""
         arrays, fields = [], []
         for name, v in cols.items():
@@ -184,6 +216,14 @@ class Table:
                 n = len(v)
                 a = pa.StringArray.from_buffers(
                     n, pa.py_buffer(np.arange(n + 1, dtype=np.int32)),
+                    pa.py_buffer(np.ascontiguousarray(v).view(np.uint8)))
+            elif spec[0] == "text":
+                n, w = len(v), v.dtype.itemsize
+                if n * w >= 2 ** 31:
+                    raise ValueError(f"{self.name}.{name}: {n * w} bytes overflow "
+                                     "a string column's int32 offsets")
+                a = pa.StringArray.from_buffers(
+                    n, pa.py_buffer(np.arange(n + 1, dtype=np.int32) * w),
                     pa.py_buffer(np.ascontiguousarray(v).view(np.uint8)))
             elif spec[0] == "date":
                 a = pa.array(v, pa.int32()).cast(pa.date32())
@@ -229,6 +269,39 @@ def _rows(spec, sizes: dict) -> int:
     return int(spec)
 
 
+def _text(where: str, spec, columns: dict, sizes: dict, rows: int) -> tuple:
+    """A text spec resolved against its table (`columns` as the configuration
+    gives them, `rows` the table's): ("text", prefix, digits, suffix, source,
+    plus). Every value the source can take, plus k, has to print in the
+    field's digits."""
+    opts = spec[3] if len(spec) == 4 else {}
+    m = TEXT_FORMAT.match(spec[1]) if len(spec) in (3, 4) and isinstance(spec[1], str) else None
+    if m is None or not isinstance(opts, dict) or set(opts) - {"plus"}:
+        raise ValueError(f"{where}: {list(spec)} is not [text, format, column(, "
+                         "{plus: k})] with one %0Nd field between literal text")
+    source = spec[2]
+    if source not in columns:
+        raise ValueError(f"{where}: its source column {source!r} is not in the table")
+    src = columns[source]
+    if src[0] == "seq":
+        lo, hi = 0, rows - 1
+    elif src[0] == "key":
+        lo, hi = 0, max(rows // src[1], 1) - 1
+    elif src[0] == "int":
+        lo, hi = src[1], src[2]
+    elif src[0] == "fk":
+        lo, hi = 0, sizes[src[1].split(".")[0]] - 1
+    else:
+        raise ValueError(f"{where}: its source {source} is of kind {src[0]!r}, "
+                         "not an integer kind (seq, key, int, fk)")
+    plus = int(opts.get("plus", 0))
+    digits = int(m.group(2))
+    if lo + plus < 0 or hi + plus >= 10 ** digits:
+        raise ValueError(f"{where}: {source} plus {plus} spans [{lo + plus}, {hi + plus}], "
+                         f"which does not print in {digits} digits")
+    return ("text", m.group(1).encode(), digits, m.group(3).encode(), source, plus)
+
+
 def tables(config: dict, rows: int = 0) -> dict:
     """{name: Table} of a configuration; `rows`, if given, replaces the rows of
     the configuration's own `table` (a rehearsal). Without a `tables` key: the
@@ -261,6 +334,8 @@ def tables(config: dict, rows: int = 0) -> dict:
                 spec = ("fk", sizes[target], float(opts.get("unreferenced", 0.0)))
             elif spec[0] == "char1":
                 spec = ("char1", spec[1].encode())
+            elif spec[0] == "text":
+                spec = _text(f"{name}.{c}", spec, d["columns"], sizes, sizes[name])
             cols[c] = spec
         out[name] = Table(name, sizes[name], cols, d["storage"],
                           stream=(zlib.crc32(name.encode()),))

@@ -11,7 +11,9 @@ Configuration file keys the harness reads (everything else is for the reader):
   storage, columns   without `tables`: PR 23's one LINEITEM, "parquet" (all 14
                  columns, PR 23's writer) or "resident" (the `columns` cached)
   tables         {name: {"rows": n | {"of": table, "ratio": [a, b]},
-                         "columns": {column: generator spec (datagen.py)},
+                         "columns": {column: generator spec (datagen.py: key,
+                                     suppkey, int, double, char1, date, choice,
+                                     seq, fk, after, text)},
                          "storage": "parquet" | "resident"}}
                  A Parquet table is written as PR 23's file is (snappy, REQUIRED,
                  dictionary on, 2^20-row groups); a resident one goes through

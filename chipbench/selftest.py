@@ -8,8 +8,13 @@ reference against a hand-checked table, the manifest against the allowed names,
 a dummy configuration / mix / metric / reader / template and the three-table star
 rehearsal (testdata/tpch-star-resident.json, Q3) added in a temporary copy without
 editing a file, the generated data and the schedules of the accepted cells against
-hashes recorded from PR 25's tree, the 80 Q6 parameter sets (traffic/q6-params.json,
-a cell added in a temporary copy as well) against the reference,
+hashes recorded from the tree that made them first, the tables of both one-chip
+star configurations against hashes recorded before the generator learnt the
+`text` kind, that kind's
+formatting, streaming, Arrow and Parquet forms and refusals, a two-table
+configuration with formatted names (testdata/tpch-names.json, a join grouped by
+`c_name`) added in a temporary copy and run whole, the 80 Q6 parameter sets
+(traffic/q6-params.json, a cell added in a temporary copy as well) against the reference,
 the lower-precision control failing the limit, and whole runs (the look for a chip
 skipped) with the timed path broken underneath, each of which must come out not
 correct.
@@ -25,6 +30,7 @@ import shutil
 import subprocess
 import sys
 import tempfile
+import textwrap
 
 os.environ["JAX_PLATFORMS"] = "cpu"          # before anything imports jax
 
@@ -153,6 +159,43 @@ def test_accepted_data_equals_the_parents():
         assert got == want["resident"][str(seed)]
 
 
+def _table_digests(config: dict, rows: int, seed: int) -> dict:
+    """{table: {"columns": {column: sha}, "stored": sha}} of a configuration's
+    tables at `rows`: every column drawn whole, and what the program is handed,
+    the Parquet file's bytes or the Arrow stream of a resident cache."""
+    import pyarrow as pa
+    out = {}
+    for name, table in datagen.tables(config, rows).items():
+        whole = table.generate(seed, list(table.columns))
+        d = {"columns": {k: _sha([v]) for k, v in whole.items()}}
+        if table.storage == "parquet":
+            with tempfile.TemporaryDirectory() as tmp:
+                path = os.path.join(tmp, "t.parquet")
+                table.write_parquet(path, seed)
+                with open(path, "rb") as f:
+                    d["stored"] = hashlib.sha256(f.read()).hexdigest()
+        else:
+            arrow = table.to_arrow(table.generate(seed, table.cached))
+            sink = pa.BufferOutputStream()
+            with pa.ipc.new_stream(sink, arrow.schema) as w:
+                w.write_table(arrow)
+            d["stored"] = hashlib.sha256(sink.getvalue().to_pybytes()).hexdigest()
+        out[name] = d
+    return out
+
+
+def test_accepted_star_tables_equal_the_parents():
+    """Every table of both one-chip star configurations as the generator drew
+    them before it learnt the `text` kind, at 2.5 M lineitem rows, two seeds
+    (testdata/parent_hashes.json `star`): each column whole, the Parquet files
+    byte for byte, the resident caches' Arrow streams."""
+    want = manifest._json(os.path.join(HERE, "testdata", "parent_hashes.json"))["star"]
+    for name in want["configs"]:
+        for seed in want["seeds"]:
+            got = _table_digests(_config(name), want["rows"], seed)
+            assert got == want["configs"][name][str(seed)], (name, seed)
+
+
 def test_accepted_schedules_equal_the_parents():
     want = manifest._json(os.path.join(HERE, "testdata", "parent_hashes.json"))
     for key, digest in want["traffic"].items():
@@ -164,6 +207,115 @@ def test_accepted_schedules_equal_the_parents():
             seq = [(r.tenant, r.template, round(r.due, 9))
                    for r in loadgen.open_schedule(t, 40.0, int(seed))]
         assert hashlib.sha256(json.dumps(seq).encode()).hexdigest() == digest, key
+
+
+# --- the text kind ------------------------------------------------------------
+
+C_NAME = ["text", "Customer#%09d", "c_custkey", {"plus": 1}]
+
+
+def _one_table(columns: dict, rows: int) -> datagen.Table:
+    return datagen.tables({"table": "t", "rows": rows, "tables": {
+        "t": {"rows": rows, "storage": "parquet", "columns": columns}}})["t"]
+
+
+def test_text_prints_as_dbgen():
+    """dbgen's numbers at both ends of nine digits, and a prefix, a field and a
+    suffix against Python's own formatting on every row."""
+    t = _one_table({"c_custkey": ["seq"], "c_name": C_NAME}, 3)
+    assert t.generate(5, ["c_name"])["c_name"].tolist() == [
+        b"Customer#000000001", b"Customer#000000002", b"Customer#000000003"]
+    assert t.width("c_name") == 18 and t.generate(5, ["c_name"])["c_name"].dtype == "S18"
+    t = _one_table({"c_custkey": ["int", 999_999_998, 999_999_998], "c_name": C_NAME}, 2)
+    assert t.generate(5, ["c_name"])["c_name"].tolist() == [b"Customer#999999999"] * 2
+    t = _one_table({"k": ["int", 0, 99_999], "s": ["text", "x%05d-y", "k"]}, 50_000)
+    g = t.generate(6, ["k", "s"])
+    assert g["s"].tolist() == [b"x%05d-y" % k for k in g["k"].tolist()] and t.width("s") == 8
+
+
+def test_text_whole_sliced_streamed_and_read_back():
+    """A unique c_name over two row groups: the same whole, in two slices that
+    cut a chunk and as the streamed file; a REQUIRED Arrow string; the file read
+    back through pyarrow equal to what the reference keeps. The writer is the
+    harness's (snappy, REQUIRED, dictionary on), so the chunk starts as a dictionary and goes on in PLAIN once the 1 MiB
+    dictionary page is full, as dbgen's c_name does under Spark's writer."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    rows = datagen.CHUNK + 100_000
+    t = _one_table({"c_custkey": ["seq"], "c_name": C_NAME}, rows)
+    whole = t.generate(2**31 + 3, ["c_name"])["c_name"]
+    cut = rows // 3
+    parts = [t.generate(2**31 + 3, ["c_name"], lo, hi)["c_name"]
+             for lo, hi in ((0, cut), (cut, rows))]
+    assert np.array_equal(np.concatenate(parts), whole)
+    arrow = t.to_arrow({"c_name": whole[:4]})
+    assert arrow.schema.field("c_name").type == pa.string()
+    assert not arrow.schema.field("c_name").nullable
+    assert arrow.column("c_name").to_pylist() == [f"Customer#{k:09d}" for k in range(1, 5)]
+    with tempfile.TemporaryDirectory() as d:
+        path = os.path.join(d, "t.parquet")
+        kept = t.write_parquet(path, 2**31 + 3, keep=("c_name",))["c_name"]
+        assert np.array_equal(kept, whole) and kept.dtype == "S18"
+        back = pq.read_table(path).column("c_name").to_pylist()
+        assert back == [v.decode() for v in kept.tolist()]
+        md = pq.ParquetFile(path).metadata
+        assert md.num_row_groups == 2 and md.schema.column(1).max_definition_level == 0
+        chunk = md.row_group(0).column(1)
+        assert chunk.has_dictionary_page and \
+            sorted(chunk.encodings) == ["PLAIN", "RLE", "RLE_DICTIONARY"], chunk.encodings
+
+
+def test_appended_text_leaves_the_other_draws():
+    """c_name appended to CUSTOMER and o_clerk (with the INT it prints) to
+    ORDERS of the star configuration from files: every column that was there
+    draws what the parent drew."""
+    want = manifest._json(os.path.join(HERE, "testdata", "parent_hashes.json"))["star"]
+    conf = _config("tpch-sf1-star-parquet")
+    conf["tables"]["customer"]["columns"]["c_name"] = C_NAME
+    orders = conf["tables"]["orders"]["columns"]
+    orders["o_clerkkey"] = ["int", 1, 1000]
+    orders["o_clerk"] = ["text", "Clerk#%09d", "o_clerkkey"]
+    seed = want["seeds"][-1]
+    parent = want["configs"]["tpch-sf1-star-parquet"][str(seed)]
+    for name, table in datagen.tables(conf, want["rows"]).items():
+        old = list(parent[name]["columns"])
+        assert list(table.columns)[:len(old)] == old
+        got = table.generate(seed, old)
+        assert {k: _sha([got[k]]) for k in old} == parent[name]["columns"], name
+    names = datagen.tables(conf, want["rows"])["orders"].generate(seed, ["o_clerk"])["o_clerk"]
+    assert np.sort(names)[[0, -1]].tolist() == [b"Clerk#000000001", b"Clerk#000001000"]
+
+
+def test_text_specs_that_are_refused():
+    """Each refused at load, with the column named: a source not in the
+    table, a source that is no integer, a second % field, a field without its
+    zero, keys that print wider than the field (150,000 customers), and a
+    fourth element that is not {"plus": k}."""
+    cases = {
+        "missing source": ["text", "Customer#%09d", "c_nokey"],
+        "not an integer": ["text", "Customer#%09d", "c_acctbal"],
+        "a string source": ["text", "Customer#%09d", "c_mktsegment"],
+        "second field": ["text", "Customer#%09d%d", "c_custkey"],
+        "two padded fields": ["text", "%05d-%05d", "c_custkey"],
+        "no zero": ["text", "Customer#%9d", "c_custkey"],
+        "too wide": ["text", "Customer#%05d", "c_custkey"],
+        "too wide by plus": ["text", "Customer#%06d", "c_custkey", {"plus": 850_001}],
+        "below zero": ["text", "Customer#%09d", "c_custkey", {"plus": -1}],
+        "unknown option": ["text", "Customer#%09d", "c_custkey", {"pluss": 1}],
+        "options not a map": ["text", "Customer#%09d", "c_custkey", 1],
+    }
+    for case, spec in cases.items():
+        conf = _config("tpch-sf1-star-parquet")
+        conf["tables"]["customer"]["columns"]["c_name"] = spec
+        try:
+            datagen.tables(conf)
+        except ValueError as e:
+            assert "customer.c_name" in str(e), (case, e)
+            continue
+        raise AssertionError(f"{case}: {spec} was taken")
+    conf["tables"]["customer"]["columns"]["c_name"] = \
+        ["text", "Customer#%06d", "c_custkey", {"plus": 850_000}]
+    assert datagen.tables(conf)["customer"].width("c_name") == 15
 
 
 def _star_config() -> dict:
@@ -511,6 +663,76 @@ def test_star_join_by_files_alone():
                                                    "o_shippriority"]
         assert [x["revenue"] for x in ref] == sorted((x["revenue"] for x in ref), reverse=True)
         assert sum(datagen.tables(cell.config, 1 << 16)[t].rows for t in q3.COLUMNS) == rows
+
+
+def _with_text(d: str) -> None:
+    """The names rehearsal as two cells: CUSTOMER with `c_name` resident and
+    ORDERS with `o_clerk` from Parquet (testdata/tpch-names.json; cell
+    `names-stream`), and the same with CUSTOMER from Parquet too
+    (`names-files-stream`); a template that joins them and groups by `c_name`
+    (testdata/names_q.py), a traffic file and entries in BENCHMARK.json."""
+    def add(bench, b):
+        conf = manifest._json(os.path.join(HERE, "testdata", "tpch-names.json"))
+        shutil.copy(os.path.join(HERE, "testdata", "names_q.py"),
+                    os.path.join(bench, "queries"))
+        with open(os.path.join(bench, "traffic", "names-stream.json"), "w") as f:
+            json.dump({"loop": "closed", "clients": 1, "tenants": 1, "trace_seconds": 10,
+                       "templates": [{"query": "names_q", "share": 1.0, "class": "batch"}]}, f)
+        for name, cell, storage in (("tpch-names", "names-stream", "resident"),
+                                    ("tpch-names-files", "names-files-stream", "parquet")):
+            conf["name"] = name
+            conf["tables"]["customer"]["storage"] = storage
+            with open(os.path.join(bench, "configs", name + ".json"), "w") as f:
+                json.dump(conf, f)
+            b["configs"].append({"name": name, "source": conf["source"], "reduced": [],
+                                 "file": f"chipbench/configs/{name}.json",
+                                 "why": "a formatted name column through a join and a group-by"})
+            _add_cell(b, cell, name, "names-stream", "parquet-q3-stream")
+    _copy_and_add(d, add)
+
+
+def test_text_column_by_files_alone():
+    """A text column through a configuration, a join, a group-by and the
+    reference, added as files and entries alone: whole runs at 2^16 orders on
+    the CPU from the copy's root (where the new template is found by name), with
+    CUSTOMER resident and from Parquet, read `correct`; one byte of one name
+    altered where the answer is produced does not."""
+    code = textwrap.dedent("""\
+        import json
+        from chipbench import manifest, run, selftest
+        assert manifest.ROOT == %r, manifest.ROOT
+        assert manifest.validate() == [], manifest.validate()
+        out = {}
+        def alter(rows):
+            row = rows[len(rows) // 2]
+            row["c_name"] = row["c_name"][:-1] + chr(ord(row["c_name"][-1]) ^ 1)
+            return rows
+        for case, cell in (("sound", "names-stream"), ("files", "names-files-stream"),
+                           ("altered", "names-stream")):
+            cls, real, broken = selftest._broken_collect(alter)
+            cls.collect = broken if case == "altered" else real
+            out[case] = run.run_cell(cell, 2**31 + 37, 0.3, trace=False,
+                                     rehearsal_rows=1 << 16)
+        print("RESULT " + json.dumps(out, default=str))
+    """)
+    with tempfile.TemporaryDirectory() as d:
+        d = os.path.realpath(d)
+        _with_text(d)
+        # the copy's chipbench comes first (the working directory), the program from this tree
+        env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+            filter(None, [manifest.ROOT, os.environ.get("PYTHONPATH")])))
+        p = subprocess.run([sys.executable, "-c", code % d], cwd=d, env=env,
+                           capture_output=True, text=True, timeout=900)
+        assert p.returncode == 0, p.stderr[-3000:]
+        out = json.loads(p.stdout.rsplit("RESULT ", 1)[1])
+        for sound in (out["sound"], out["files"]):
+            assert sound["correct"] is True and sound["failed"] == 0, sound["checks"]
+            assert sound["checks"]["answers_compared"]["value"] >= 1
+        altered = out["altered"]
+        assert altered["correct"] is False, altered["checks"]
+        assert altered["checks"]["inexact_values"]["value"] >= 1
+        c = altered["checks"]["double_max_rel_err"]
+        assert c["value"] <= c["limit"], c
 
 
 def test_tenants_over_several_tables_are_refused():
